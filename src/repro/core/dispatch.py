@@ -37,7 +37,7 @@ from repro.kernels import ops
 from repro.quant.qtypes import qmax_for_bits
 from repro.quant.scales import compute_scale
 
-BACKENDS = ("auto", "ref", "pallas", "interpret")
+BACKENDS = ("auto", "ref", "pallas", "interpret")   # == kernels.ops.IMPLS
 
 _METHOD_FLAGS = {
     "squant":    (True, True),
@@ -49,18 +49,32 @@ _METHOD_FLAGS = {
 
 def resolve_backend(backend: str) -> str:
     """Validate and resolve ``"auto"`` to a concrete backend."""
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; options {BACKENDS}")
-    if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    return backend
+    return ops.resolve(backend)
+
+
+def bucket_impl(method: str, backend: str, group_size: Optional[int]) -> str:
+    """The implementation one bucket runs on (``QuantReport`` records it):
+    ``ops.squant_impl`` for the SQuant methods, ``"ref"`` for RTN (a plain
+    jitted round, no kernel). ``backend`` is already resolved."""
+    if method == "rtn":
+        return "ref"
+    enable_k, enable_c = _METHOD_FLAGS[method]
+    return ops.squant_impl(backend, group_size, enable_k, enable_c)
 
 
 @functools.lru_cache(maxsize=None)
 def _scales_fn(bits: int, scale_method: str):
-    """jit(vmap(compute_scale)): the single scale source for all backends."""
+    """jit(vmap(compute_scale)): the single scale source for all backends.
+
+    Scales are computed in f32 whatever the weight dtype (they are stored
+    as f32). A bf16 scale would be rounded where it is returned, but XLA
+    may skip that rounding where it fuses the scale into the ``w / s`` of
+    the same program (allowed excess precision) — the sharded path does,
+    the unsharded one does not — and codes would then depend on the
+    program they were computed in."""
     return jax.jit(jax.vmap(
-        lambda w2d: compute_scale(w2d, bits, scale_method)))
+        lambda w2d: compute_scale(w2d.astype(jnp.float32), bits,
+                                  scale_method)))
 
 
 @functools.lru_cache(maxsize=None)

@@ -47,7 +47,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.dispatch import (quantize_codes_batched,
+from repro.core.dispatch import (bucket_impl, quantize_codes_batched,
                                  quantize_codes_sharded, resolve_backend,
                                  shard_rows)
 from repro.quant.qtypes import (BucketReport, LayerReport, QuantReport,
@@ -78,9 +78,10 @@ def is_quantizable(path: Tuple[str, ...], leaf: Any) -> bool:
 # Leaf planning: every quantizable leaf maps to a 2-D (out, in)-major view
 # ---------------------------------------------------------------------------
 
-def _plan_leaf(leaf: jnp.ndarray, method: str, group_size: Optional[int]
-               ) -> Tuple[jnp.ndarray, Tuple[int, ...], Optional[int]]:
-    """Return ``(w2d, qt_shape, eff_group)`` for one kernel leaf.
+def _plan_leaf(leaf, method: str, group_size: Optional[int]
+               ) -> Tuple[Tuple[int, int], Tuple[int, ...], Optional[int]]:
+    """Return ``(w2d_shape, qt_shape, eff_group)`` for one kernel leaf,
+    from its shape alone (:func:`_to_2d` builds the 2-D view itself).
 
     ``eff_group`` mirrors the clamping in ``core.squant.squant`` exactly
     (group >= row length degenerates to the whole-row FC path; conv kernels
@@ -88,26 +89,42 @@ def _plan_leaf(leaf: jnp.ndarray, method: str, group_size: Optional[int]
     the per-layer path.
     """
     if leaf.ndim == 2:                       # (in, out) -> (out, in)
-        w2d = leaf.T
-        qt_shape = (leaf.shape[1], leaf.shape[0])
+        w2d_shape = qt_shape = (leaf.shape[1], leaf.shape[0])
     elif leaf.ndim == 3:                     # (E, in, out) -> (E*out, in)
         e, i, o = leaf.shape
-        w2d = jnp.transpose(leaf, (0, 2, 1)).reshape(e * o, i)
-        qt_shape = (e * o, i)
+        w2d_shape = qt_shape = (e * o, i)
     elif leaf.ndim == 4:                     # conv (KH,KW,in,out) -> (out, in*K)
         kh, kw, ci, co = leaf.shape
         k = kh * kw
-        w2d = jnp.transpose(leaf, (3, 2, 0, 1)).reshape(co, ci * k)
         if method == "rtn":
-            return w2d, (co, ci * k), None
-        return w2d, (co, ci, k), (None if k == 1 else k)
+            return (co, ci * k), (co, ci * k), None
+        return (co, ci * k), (co, ci, k), (None if k == 1 else k)
     else:
         raise ValueError(f"unsupported kernel rank {leaf.ndim}")
     if method == "rtn":
-        return w2d, qt_shape, None
-    n = w2d.shape[1]
+        return w2d_shape, qt_shape, None
+    n = w2d_shape[1]
     eff = None if (group_size is None or group_size >= n) else group_size
-    return w2d, qt_shape, eff
+    return w2d_shape, qt_shape, eff
+
+
+def _to_2d(leaf: jnp.ndarray) -> jnp.ndarray:
+    """The (out, in)-major 2-D view :func:`_plan_leaf` describes."""
+    if leaf.ndim == 2:
+        return leaf.T
+    if leaf.ndim == 3:
+        e, i, o = leaf.shape
+        return jnp.transpose(leaf, (0, 2, 1)).reshape(e * o, i)
+    kh, kw, ci, co = leaf.shape
+    return jnp.transpose(leaf, (3, 2, 0, 1)).reshape(co, ci * kh * kw)
+
+
+@jax.jit
+def _stack_2d(leaves) -> jnp.ndarray:
+    """(B, M, N) stack of the leaves' 2-D views in ONE device op: a bucket
+    chunk is transposed and stacked together, so no transposed copy of
+    the whole tree ever exists beside the source params."""
+    return jnp.stack([_to_2d(leaf) for leaf in leaves])
 
 
 def _restore_dense(wq: jnp.ndarray, leaf_shape: Tuple[int, ...]
@@ -141,9 +158,9 @@ def _quantize_tree_serial(flat, treedef, pred, method, bits, group_size,
             out_leaves.append(leaf)
             continue
         t0 = time.perf_counter()
-        w2d, qt_shape, eff = _plan_leaf(leaf, method, group_size)
+        _, qt_shape, eff = _plan_leaf(leaf, method, group_size)
         codes, scales = quantize_codes_batched(
-            w2d[None], method=method, bits=bits, group_size=eff,
+            _to_2d(leaf)[None], method=method, bits=bits, group_size=eff,
             scale_method=scale_method, backend="ref")
         qt = from_codes(codes[0].reshape(qt_shape), scales[0], bits)
         _sync(qt.data)
@@ -165,10 +182,14 @@ def _quantize_tree_serial(flat, treedef, pred, method, bits, group_size,
 # ---------------------------------------------------------------------------
 
 # Cap on the transient stacked-bucket buffer: buckets whose stack would
-# exceed this many bytes are dispatched in chunks, bounding peak memory at
-# params + one chunk instead of params + the largest bucket. Still one device
-# sync per tree.
-_MAX_STACK_BYTES = 1 << 30
+# exceed this many bytes are dispatched in chunks, bounding the kernel's
+# working set by one chunk instead of the largest bucket. Still one device
+# sync per tree. The compiled Pallas SQuant pass needs about 6.6x a bf16
+# chunk's bytes of temporaries on a TPU (f32 scaled weights, int32 codes
+# and C-stage copies: 7.1 GB for a 1 GiB chunk of 2048x2048 layers, from
+# the v5e compiler's memory analysis). 256 MiB keeps that under 2 GB, so a
+# live reload fits beside the serving tree on a 16 GB chip.
+_MAX_STACK_BYTES = 256 << 20
 
 
 def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
@@ -179,7 +200,7 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
 
     t_begin = time.perf_counter()
     out_leaves: List[Any] = [None] * len(flat)
-    # bucket key -> list of (leaf index, path, leaf, w2d, qt_shape)
+    # bucket key -> list of (leaf index, path, leaf, qt_shape)
     buckets: Dict[Tuple, List] = {}
     for idx, (keypath, leaf) in enumerate(flat):
         path = tuple(str(getattr(k, "key", getattr(k, "idx", str(k))))
@@ -187,10 +208,9 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
         if not pred(path, leaf):
             out_leaves[idx] = leaf
             continue
-        w2d, qt_shape, eff = _plan_leaf(leaf, method, group_size)
-        key = (tuple(w2d.shape), str(w2d.dtype), eff)
-        buckets.setdefault(key, []).append(
-            (idx, path, leaf, w2d, qt_shape))
+        w2d_shape, qt_shape, eff = _plan_leaf(leaf, method, group_size)
+        key = (w2d_shape, str(leaf.dtype), eff)
+        buckets.setdefault(key, []).append((idx, path, leaf, qt_shape))
 
     layer_reports: List[LayerReport] = []
     bucket_reports: List[BucketReport] = []
@@ -200,14 +220,12 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
         (m, n), dtype, eff = key[0], key[1], key[2]
         layer_bytes = m * n * jnp.dtype(dtype).itemsize
         chunk = max(1, min(len(all_entries), _MAX_STACK_BYTES // layer_bytes))
+        impl = bucket_impl(method, backend, eff)
         for c0 in range(0, len(all_entries), chunk):
             entries = all_entries[c0:c0 + chunk]
             tag = f"({m},{n})x{len(entries)} {dtype} g{eff}"
             tb0 = time.perf_counter()
-            if len(entries) == 1:                        # singleton: no copy
-                ws = entries[0][3][None]
-            else:
-                ws = jnp.stack([e[3] for e in entries])  # (B, M, N)
+            ws = _stack_2d(tuple(e[2] for e in entries))   # (B, M, N)
             if mesh is None:
                 codes, scales = quantize_codes_batched(
                     ws, method=method, bits=bits, group_size=eff,
@@ -221,7 +239,7 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
                         shard_rows(len(entries) * m, ndev)):
                     shard_acc[d][0] += r
                     shard_acc[d][1] += p
-            for bi, (idx, path, leaf, _, qt_shape) in enumerate(entries):
+            for bi, (idx, path, leaf, qt_shape) in enumerate(entries):
                 qt = from_codes(codes[bi].reshape(qt_shape), scales[bi], bits)
                 if dequantize:
                     out = _restore_dense(qt.dequantize(leaf.dtype),
@@ -237,8 +255,9 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
                 out_leaves[idx] = out
                 quantized.append(out)
             bucket_ms = (time.perf_counter() - tb0) * 1e3
-            bucket_reports.append(BucketReport(tag, len(entries), bucket_ms))
-            for idx, path, leaf, _, _ in entries:
+            bucket_reports.append(BucketReport(tag, len(entries), bucket_ms,
+                                               impl))
+            for idx, path, leaf, _ in entries:
                 layer_reports.append(LayerReport("/".join(path),
                                                  tuple(leaf.shape),
                                                  bucket_ms / len(entries),

@@ -1,138 +1,55 @@
-"""Version-portable wrappers around the jax.sharding surface.
+"""The jax.sharding surface the repo builds meshes and shard_maps with.
 
-The distributed layer targets two generations of the jax API:
-
-* **new** (jax >= ~0.6): ``jax.shard_map`` with ``axis_names``/``check_vma``,
-  ``jax.sharding.AxisType`` + ``axis_types=`` on ``jax.make_mesh``,
-  ``jax.sharding.set_mesh`` / ``get_abstract_mesh``.
-* **old** (jax 0.4.x, what this container ships): ``shard_map`` lives in
-  ``jax.experimental.shard_map`` with ``auto=``/``check_rep=``, meshes have
-  no axis types, and the ambient mesh is the ``Mesh`` context manager backed
-  by ``thread_resources``.
-
-Everything in the repo that builds meshes or shard_maps goes through this
-module so the same code (and the same tests) runs on either generation.
-All shims are feature-detected, never version-parsed.
+Thin wrappers over ``jax.make_mesh`` (explicitly Auto axis types),
+``jax.shard_map`` (a named set of manual axes) and the ambient mesh
+(``jax.sharding.set_mesh`` / ``get_abstract_mesh``), so every caller
+states the same defaults once.
 """
 from __future__ import annotations
 
-import threading
-from typing import Callable, FrozenSet, Iterable, Optional, Sequence
+from typing import Callable, Iterable, Optional, Sequence
 
 import jax
-
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_NEW_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_NEW_SET_MESH = hasattr(jax.sharding, "set_mesh")
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` with explicitly-Auto axis types where supported.
-
-    Auto axis types are the GSPMD default this codebase assumes everywhere;
-    on old jax the concept does not exist and every axis is implicitly auto
-    outside a shard_map.
-    """
+    """``jax.make_mesh`` with every axis Auto: the GSPMD default this
+    codebase assumes everywhere outside a shard_map."""
     kwargs = {} if devices is None else {"devices": devices}
-    if _NEW_AXIS_TYPES:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-                **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-# Old jax has no Manual axis types on the mesh, so code inside a partial-auto
-# shard_map body cannot ask the mesh which axes are manual (sharding.
-# _mesh_axis_sizes needs to know: manual axes must not appear in sharding
-# constraints). We track the manual set in a thread-local that the wrapped
-# body pushes during tracing.
-
-_SCOPE = threading.local()
-
-
-def manual_axes_in_scope() -> FrozenSet[str]:
-    """Mesh axes manually mapped by an enclosing ``shard_map`` (old jax only;
-    new jax exposes the same information via ``mesh.axis_types``)."""
-    return getattr(_SCOPE, "axes", frozenset())
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs)
 
 
 def shard_map(f: Callable, mesh, in_specs, out_specs,
               manual_axes: Optional[Iterable[str]] = None,
               check: bool = False) -> Callable:
-    """Portable shard_map.
-
-    ``manual_axes`` names the mesh axes the body is manually mapped over
-    (None → all of them); the remaining axes stay auto (GSPMD partitions the
-    per-shard program as usual). ``check`` maps to ``check_vma``/``check_rep``.
-    """
+    """``jax.shard_map`` over ``manual_axes`` (None → every mesh axis); the
+    remaining axes stay auto (GSPMD partitions the per-shard program as
+    usual). ``check`` maps to ``check_vma``."""
     all_axes = frozenset(mesh.axis_names)
     manual = frozenset(manual_axes) if manual_axes is not None else all_axes
     unknown = manual - all_axes
     if unknown:
         raise ValueError(f"manual axes {sorted(unknown)} not in mesh axes "
                          f"{sorted(all_axes)}")
-    if _NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check)
 
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def body(*args):
-        prev = manual_axes_in_scope()
-        _SCOPE.axes = prev | manual
-        try:
-            return f(*args)
-        finally:
-            _SCOPE.axes = prev
-
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check,
-                      auto=all_axes - manual)
-
-
-# ---------------------------------------------------------------------------
-# Ambient mesh
-# ---------------------------------------------------------------------------
 
 def activate_mesh(mesh):
-    """Install ``mesh`` as the ambient mesh for the rest of the process.
-
-    Launcher-style (dryrun/train/quantize CLIs call this once after building
-    the production mesh): on new jax it is ``jax.sharding.set_mesh``; on old
-    jax the ``Mesh`` context manager is entered and intentionally never
-    exited — the process owns exactly one mesh for its lifetime.
-    """
-    if _NEW_SET_MESH:
-        jax.sharding.set_mesh(mesh)
-    else:
-        mesh.__enter__()
+    """Install ``mesh`` as the ambient mesh for the rest of the process
+    (launchers call this once after building the production mesh)."""
+    jax.sharding.set_mesh(mesh)
     return mesh
 
 
 def current_mesh():
-    """The ambient mesh, or None. Works inside and outside jit tracing."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    except Exception:
+    """The ambient mesh, or None. Works inside and outside jit tracing;
+    inside a shard_map its manual axes carry the Manual axis type."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
         return None
-    try:
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+    return mesh

@@ -77,20 +77,9 @@ def _mesh_axis_sizes(mesh) -> Dict[str, int]:
     step) must not appear in sharding constraints — the per-shard program
     only sees the remaining auto axes.
     """
-    sizes = dict(mesh.shape)
-    try:
-        types = dict(zip(mesh.axis_names, mesh.axis_types))
-        manual = {n for n, t in types.items()
-                  if str(t).endswith("Manual")}
-        for n in manual:
-            sizes.pop(n, None)
-    except Exception:
-        pass
-    # Old jax meshes carry no axis types; an enclosing compat.shard_map
-    # records its manual axes in a thread-local instead.
-    for n in compat.manual_axes_in_scope():
-        sizes.pop(n, None)
-    return sizes
+    manual = jax.sharding.AxisType.Manual
+    return {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types) if t != manual}
 
 
 def logical_to_mesh(logical: Sequence[Optional[str]], shape: Sequence[int],

@@ -1,17 +1,17 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Dispatch policy:
-* On TPU backends the compiled Pallas kernels run natively.
-* On CPU (this container) ``interpret=True`` executes the kernel body for
-  correctness validation; the pure-jnp oracle is the default production
-  fallback because interpret mode is slow for large tensors.
-
-``use_pallas='auto'`` picks TPU→pallas, CPU→reference. Tests force
-``use_pallas='interpret'`` to exercise the kernel bodies.
+Dispatch policy (:func:`resolve`, shared by every kernel module):
+* On TPU backends ``"auto"`` runs the compiled Pallas kernels.
+* Elsewhere ``"auto"`` runs the pure-jnp reference; the CPU tests force
+  ``use_pallas='interpret'`` to execute the kernel bodies (interpret mode
+  is too slow for production-size tensors).
+* An explicit ``"pallas"`` is always the compiled kernel: if it cannot
+  lower, the error surfaces; nothing falls back to the reference.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -23,8 +23,33 @@ from repro.kernels.squant_flip import squant_pallas
 from repro.quant.qtypes import QuantizedTensor
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+IMPLS = ("auto", "ref", "pallas", "interpret")
+
+
+def resolve(use_pallas: str) -> str:
+    """The one kernel-dispatch rule: ``"auto"`` is the compiled kernel on a
+    TPU backend and the jnp reference anywhere else; an explicit choice is
+    honoured as given (``"pallas"`` that cannot lower raises, it never
+    falls back to the reference)."""
+    if use_pallas not in IMPLS:
+        raise ValueError(f"unknown kernel backend {use_pallas!r}; "
+                         f"options {IMPLS}")
+    if use_pallas == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return use_pallas
+
+
+def squant_impl(use_pallas: str, group_size: Optional[int],
+                enable_k: bool = True, enable_c: bool = True) -> str:
+    """The implementation a SQuant call runs on: :func:`resolve`, except
+    that whole-row groups (``group_size=None``, the paper's FC path) and
+    the E&C-without-K ablation have no kernel specialization and run the
+    jnp reference by design, whatever was asked for. ``QuantReport``
+    records this per bucket so such buckets are visible, not hidden."""
+    impl = resolve(use_pallas)
+    if group_size is None or (enable_c and not enable_k):
+        return "ref"
+    return impl
 
 
 def squant_flip(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
@@ -35,14 +60,13 @@ def squant_flip(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
     The Pallas path implements the standard E, E&K and E&K&C configurations;
     the E&C-without-K ablation (row-level flip) is reference-only.
     """
-    if use_pallas == "auto":
-        use_pallas = "pallas" if _on_tpu() else "ref"
-    if use_pallas in ("pallas", "interpret") and (enable_k or not enable_c):
-        return squant_pallas(w2d, scale, bits=bits, group_size=group_size,
-                             enable_k=enable_k, enable_c=enable_c, tm=tm,
-                             interpret=(use_pallas == "interpret"))
-    return _ref.squant_ref(w2d, scale, bits=bits, group_size=group_size,
-                           enable_k=enable_k, enable_c=enable_c)
+    impl = squant_impl(use_pallas, group_size, enable_k, enable_c)
+    if impl == "ref":
+        return _ref.squant_ref(w2d, scale, bits=bits, group_size=group_size,
+                               enable_k=enable_k, enable_c=enable_c)
+    return squant_pallas(w2d, scale, bits=bits, group_size=group_size,
+                         enable_k=enable_k, enable_c=enable_c, tm=tm,
+                         interpret=(impl == "interpret"))
 
 
 def squant_flip_batched(w3: jnp.ndarray, scale3: jnp.ndarray, *, bits: int,
@@ -59,22 +83,18 @@ def squant_flip_batched(w3: jnp.ndarray, scale3: jnp.ndarray, *, bits: int,
     channel flips — operates within a single output channel), so the kernel
     backends flatten the batch into rows and launch the Pallas kernel once
     over ``(B*M, N)``; that is exact, not an approximation. The reference
-    backend vmaps the jnp core instead. ``group_size=None`` (whole-row FC
-    path) and the E&C-without-K ablation have no kernel specialization and
-    always take the reference path.
+    backend vmaps the jnp core instead. Which one runs is
+    :func:`squant_impl`.
     """
-    if use_pallas == "auto":
-        use_pallas = "pallas" if _on_tpu() else "ref"
+    impl = squant_impl(use_pallas, group_size, enable_k, enable_c)
+    if impl == "ref":
+        return _vmapped_ref(bits, group_size, enable_k, enable_c)(w3, scale3)
     b, m, n = w3.shape
-    if (use_pallas in ("pallas", "interpret") and group_size is not None
-            and (enable_k or not enable_c)):
-        codes = squant_pallas(w3.reshape(b * m, n),
-                              scale3.reshape(b * m, 1), bits=bits,
-                              group_size=group_size, enable_k=enable_k,
-                              enable_c=enable_c, tm=tm,
-                              interpret=(use_pallas == "interpret"))
-        return codes.reshape(b, m, n)
-    return _vmapped_ref(bits, group_size, enable_k, enable_c)(w3, scale3)
+    codes = squant_pallas(w3.reshape(b * m, n), scale3.reshape(b * m, 1),
+                          bits=bits, group_size=group_size, enable_k=enable_k,
+                          enable_c=enable_c, tm=tm,
+                          interpret=(impl == "interpret"))
+    return codes.reshape(b, m, n)
 
 
 @functools.lru_cache(maxsize=None)
@@ -89,28 +109,27 @@ def _vmapped_ref(bits: int, group_size: Optional[int], enable_k: bool,
 
 
 def dequant_matmul(x: jnp.ndarray, qt: QuantizedTensor, *,
-                   group_size: int = 128, use_pallas: str = "auto",
-                   tb: int = 128, tm: int = 128) -> jnp.ndarray:
-    """y = x @ dequant(qt).T for a (out, in)-major QuantizedTensor."""
-    if use_pallas == "auto":
-        use_pallas = "pallas" if _on_tpu() else "ref"
-    import math
+                   use_pallas: str = "auto", tb: int = 128, tm: int = 128
+                   ) -> jnp.ndarray:
+    """y = x @ dequant(qt).T for a (out, in)-major QuantizedTensor.
+
+    Per-group scales carry their group size in their shape: ``qt.scale``
+    reshaped to (M, NG) covers N in NG equal groups (NG == 1: per channel).
+    The kernel path pads the batch up to a whole tile (a multiple of the
+    activation dtype's sublane count) instead of shrinking the tile.
+    """
+    use_pallas = resolve(use_pallas)
     m = qt.shape[0]
     n = math.prod(qt.shape[1:])
     scale = qt.scale.reshape(m, -1)
-    if use_pallas in ("pallas", "interpret"):
-        b = x.shape[0]
-        # tile sizes must divide; shrink for small operands
-        tb_eff = max(1, min(tb, b))
-        while b % tb_eff:
-            tb_eff -= 1
-        tm_eff = max(1, min(tm, m))
-        while m % tm_eff:
-            tm_eff -= 1
-        gs = group_size if n % group_size == 0 else n
-        return dequant_matmul_pallas(
-            x, qt.data, scale, bits=qt.bits, group_size=gs, tb=tb_eff,
-            tm=tm_eff, interpret=(use_pallas == "interpret"))
-    return _ref.dequant_matmul_ref(x, qt.data, scale, bits=qt.bits,
-                                   group_size=group_size
-                                   if n % group_size == 0 else n)
+    if use_pallas == "ref":
+        return _ref.dequant_matmul_ref(x, qt.data, scale, bits=qt.bits,
+                                       group_size=n // scale.shape[1])
+    b = x.shape[0]
+    sub = 32 // x.dtype.itemsize                 # 8 rows f32, 16 bf16
+    tb_eff = min(tb, -(-b // sub) * sub)
+    b_pad = -(-b // tb_eff) * tb_eff
+    xp = jnp.pad(x, ((0, b_pad - b), (0, 0))) if b_pad != b else x
+    y = dequant_matmul_pallas(xp, qt.data, scale, bits=qt.bits, tb=tb_eff,
+                              tm=tm, interpret=(use_pallas == "interpret"))
+    return y[:b]

@@ -8,35 +8,33 @@ Dequantization happens *inside VMEM* after the scalar-prefetched
 block-table gather — the ``kernels/dequant_matmul.py`` idiom applied to
 attention:
 
-* K codes are upcast in VMEM and hit the MXU as-is; the per-column
-  ``k_scale`` is folded into the scores *after* the QK dot (one
-  (rep, bs) multiply instead of materializing a dequantized (bs, D)
-  tile);
-* ``v_scale`` is folded into the softmax weights *before* the PV dot
-  (``(p * v_scale) @ v_codes``), so V codes also reach the MXU raw.
+* each grid step loads a physical block's ``(bs, KV, D)`` int8 codes and
+  its ``(bs, KV)`` scales, and dequantizes head ``h``'s ``(bs, D)`` code
+  tiles in VMEM (``codes * scale`` per row) before the QK and PV dots.
+  The kernel is :func:`repro.kernels.paged_attention.paged_decode_pallas`
+  with ``scales`` given: one gather and one online softmax for both pool
+  dtypes.
 
 HBM traffic per decode token drops to ``2*D + 8`` bytes per (position,
 kv-head) from ``2*D*itemsize`` for the fp pool — ~3.8x vs fp32, ~1.9x
 vs bf16 — with no separate dequant materialization pass.
 
-The jnp reference backend mirrors the kernel's op order (codes dot →
-k-scale fold → mask → softmax → v-scale fold → codes dot) and is the CPU
-serving oracle; interpret-mode parity is asserted in
-``tests/test_kernels_paged_quant.py``. Greedy tokens from this path are
-NOT bit-identical to the fp paged oracle — the tolerance-equivalence
-harness (:mod:`repro.serving.equivalence`) budgets the divergence
-instead (greedy-token agreement >= 0.98 per config).
+The jnp reference backend folds the scales into the scores and the
+softmax weights instead (codes dot → k-scale fold → mask → softmax →
+v-scale fold → codes dot): the same products in another order, so the
+two agree to float rounding, not bitwise. It is the CPU serving oracle;
+interpret-mode parity is asserted in ``tests/test_kernels_paged_quant.py``.
+Greedy tokens from this path are NOT bit-identical to the fp paged
+oracle — the tolerance-equivalence harness
+(:mod:`repro.serving.equivalence`) budgets the divergence instead
+(greedy-token agreement >= 0.98 per config).
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_attention import _LANES, NEG_INF
+from repro.kernels.ops import resolve
+from repro.kernels.paged_attention import NEG_INF, paged_decode_pallas
 
 __all__ = ["paged_attention_quant", "paged_attention_quant_ref"]
 
@@ -52,10 +50,11 @@ def paged_attention_quant_ref(q, k_pool, v_pool, k_scale, v_scale,
     (N, bs, KV) fp32; block_tables: (B, nb); lengths: (B,).
     Returns (B, H, D).
 
-    Same gather as :func:`paged_attention_ref`, with the kernel's exact
-    dequant order: scores = (q · codes) * softmax_scale * k_scale, then
-    out = (softmax(scores) * v_scale) · v_codes. Never-written pool
-    positions carry zero scales AND sit past ``lengths`` — the finite
+    Same gather as :func:`paged_attention_ref`, with the scales folded
+    into the scores and weights: scores = (q · codes) * softmax_scale *
+    k_scale, then out = (softmax(scores) * v_scale) · v_codes (the kernel
+    dequantizes the tiles first; equal up to float rounding). Never-written
+    pool positions carry zero scales AND sit past ``lengths`` — the finite
     ``NEG_INF`` mask pushes them to exact-zero softmax weight.
     """
     b, h, d = q.shape
@@ -82,103 +81,6 @@ def paged_attention_quant_ref(q, k_pool, v_pool, k_scale, v_scale,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel
-# ---------------------------------------------------------------------------
-
-def _paged_quant_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref,
-                               ks_ref, vs_ref, o_ref, m_ref, l_ref,
-                               acc_ref, *, block_size: int, scale: float):
-    """Grid (B, KV, nb); one int8 (block_size, D) K/V code tile plus its
-    (block_size,) scale vectors per step, online softmax across the nb
-    (innermost, sequential) axis. Codes are upcast in VMEM; scales fold
-    into the scores / softmax weights, never into materialized tiles."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                # (rep, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bs, D) int8 upcast
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bs, D) int8 upcast
-    ks = ks_ref[0, :, 0].reshape(1, block_size)        # (1, bs)
-    vs = vs_ref[0, :, 0].reshape(1, block_size)        # (1, bs)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = s * ks                                         # per-column k_scale
-    cols = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)                         # (rep, bs)
-    s = jnp.where(cols <= len_ref[b], s, NEG_INF)
-
-    m_prev = m_ref[...]                                # (rep, LANES)
-    m_blk = jnp.max(s, axis=1, keepdims=True)          # (rep, 1)
-    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_blk, m_prev.shape))
-    alpha = jnp.exp(m_prev - m_new)                    # lane-replicated
-    p = jnp.exp(s - m_new[:, :1])                      # (rep, bs)
-    l_new = alpha * l_ref[...] + jnp.broadcast_to(
-        jnp.sum(p, axis=1, keepdims=True), m_prev.shape)
-    pv = jax.lax.dot_general(p * vs, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-
-    @pl.when(j == nb - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _paged_attention_quant_pallas(q, k_pool, v_pool, k_scale, v_scale,
-                                  block_tables, lengths, *, scale: float,
-                                  interpret: bool):
-    b, h, d = q.shape
-    n, bs, kv, _ = k_pool.shape
-    nb = block_tables.shape[1]
-    rep = h // kv
-    qg = q.reshape(b, kv, rep, d)
-
-    def _tile(bi, hi, ji, bt, ln):
-        return (bt[bi, ji], 0, hi, 0)
-
-    def _stile(bi, hi, ji, bt, ln):
-        return (bt[bi, ji], 0, hi)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, d),
-                         lambda bi, hi, ji, bt, ln: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), _tile),     # K codes
-            pl.BlockSpec((1, bs, 1, d), _tile),     # V codes
-            pl.BlockSpec((1, bs, 1), _stile),       # k_scale
-            pl.BlockSpec((1, bs, 1), _stile),       # v_scale
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, d),
-                               lambda bi, hi, ji, bt, ln: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, _LANES), jnp.float32),
-            pltpu.VMEM((rep, _LANES), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_quant_decode_kernel, block_size=bs,
-                               scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, rep, d), q.dtype),
-        interpret=interpret,
-    )(block_tables, lengths, qg, k_pool, v_pool, k_scale, v_scale)
-    return out.reshape(b, h, d)
-
-
-# ---------------------------------------------------------------------------
 # public dispatch (same policy as kernels.paged_attention)
 # ---------------------------------------------------------------------------
 
@@ -187,11 +89,10 @@ def paged_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
                           use_pallas: str = "auto") -> jnp.ndarray:
     """Fused int8-dequant block-table decode attention. ``use_pallas``:
     'auto' (TPU→pallas, CPU→ref), 'ref', 'pallas', or 'interpret'."""
-    if use_pallas == "auto":
-        use_pallas = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if use_pallas in ("pallas", "interpret"):
-        return _paged_attention_quant_pallas(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
+    use_pallas = resolve(use_pallas)
+    if use_pallas != "ref":
+        return paged_decode_pallas(
+            q, k_pool, v_pool, block_tables, lengths, (k_scale, v_scale),
             scale=scale, interpret=(use_pallas == "interpret"))
     return paged_attention_quant_ref(q, k_pool, v_pool, k_scale, v_scale,
                                      block_tables, lengths, scale=scale)

@@ -12,7 +12,8 @@ onto the 8×128 VPU lanes. Two passes:
 
 * ``squant_ek_kernel`` — grid (M/TM, N/G), block (TM, G): fused
   round (SQuant-E) + group flip (SQuant-K) + the Algorithm-4 candidate
-  (index+value) for the C stage.
+  (index+value) for the C stage, written as lane ``j`` of (TM, NG)
+  summary blocks that stay resident across the group axis.
 * ``squant_c_kernel``  — grid (M/TM_C,), block (TM_C, NG): ranks groups by
   |candidate| and emits the per-group flip decision (SQuant-C). The ±1
   application is a cheap one-hot select done by the wrapper (no scatter —
@@ -20,7 +21,8 @@ onto the 8×128 VPU lanes. Two passes:
 
 Both are validated in interpret mode against ``kernels/ref.py`` (which
 delegates to the vectorized core, itself bit-exact against the sequential
-NumPy reference of Algorithms 1-4).
+NumPy reference of Algorithms 1-4), and compiled on a TPU against the same
+reference by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -63,12 +65,19 @@ def _flip_body(q, delta, qmax):
     return q, delta
 
 
-def squant_ek_kernel(w_ref, inv_s_ref, q_ref, d_ref, e1_ref, cidx_ref,
-                     cval_ref, *, qmax: float, enable_k: bool):
-    """Fused SQuant-E (+K) + Algorithm-4 candidate for one (TM, G) block."""
-    w = w_ref[...].astype(jnp.float32) * inv_s_ref[...]
-    q = jnp.clip(jnp.round(w), -qmax, qmax)
-    delta = q - w
+def squant_ek_kernel(ws_ref, q_ref, e1_ref, cidx_ref, cval_ref, *,
+                     qmax: float, enable_k: bool):
+    """Fused SQuant-E (+K) + Algorithm-4 candidate for one (TM, G) block.
+
+    ``ws_ref`` holds the already-scaled weights ``w / s``. The three
+    per-group summaries live in (TM, NG) blocks that stay resident while
+    the group axis ``j`` sweeps (innermost grid axis): group ``j`` writes
+    lane ``j``, so every output block is lane-dense and spans all NG
+    columns (the TPU tiling rule) instead of a (TM, 1) sliver.
+    """
+    ws = ws_ref[...]
+    q = jnp.clip(jnp.round(ws), -qmax, qmax)
+    delta = q - ws
 
     if enable_k:
         q, delta = _flip_body(q, delta, qmax)
@@ -76,7 +85,9 @@ def squant_ek_kernel(w_ref, inv_s_ref, q_ref, d_ref, e1_ref, cidx_ref,
     # Post-K group sum and the single C-stage candidate (Algorithm 4).
     e1 = jnp.sum(delta, axis=1, keepdims=True)          # (TM, 1)
     sgn1 = jnp.sign(e1)
-    match = jnp.where(sgn1 == 0.0, delta != 0.0, delta * sgn1 > 0.0)
+    # e1 == 0 admits either sign; written with logical ops, not a
+    # bool-valued select (Mosaic cannot lower a select between i1 vectors)
+    match = ((sgn1 == 0.0) & (delta != 0.0)) | (delta * sgn1 > 0.0)
     tgt = q - jnp.sign(delta)
     match = match & (tgt >= -qmax) & (tgt <= qmax)
     cscore = jnp.where(match, jnp.abs(delta), -1.0)
@@ -88,10 +99,14 @@ def squant_ek_kernel(w_ref, inv_s_ref, q_ref, d_ref, e1_ref, cidx_ref,
                        keepdims=True)
     has = cmax > 0.0
     q_ref[...] = q.astype(jnp.int32)
-    d_ref[...] = delta
-    e1_ref[...] = e1
-    cidx_ref[...] = jnp.where(has, first, -1).astype(jnp.int32)
-    cval_ref[...] = jnp.where(has, cand_val, 0.0)
+
+    j = pl.program_id(1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, e1_ref.shape, 1)
+    mine = lane == j
+    e1_ref[...] = jnp.where(mine, e1, e1_ref[...])
+    cidx_ref[...] = jnp.where(mine, jnp.where(has, first, -1), cidx_ref[...])
+    cval_ref[...] = jnp.where(mine, jnp.where(has, cand_val, 0.0),
+                              cval_ref[...])
 
 
 def squant_c_kernel(e1_ref, cval_ref, gflip_ref):
@@ -128,41 +143,35 @@ def squant_pallas(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
     w2d: (M, N) float; scale: (M, 1). N is padded to a multiple of
     ``group_size``, M to a multiple of ``tm`` (zero rows/cols are inert:
     δ=0 elements are never flip-eligible and contribute nothing to sums).
+    On a TPU ``group_size`` is a multiple of 128 (or the padded row) and
+    ``tm`` a multiple of 8: the block tiling rule.
     """
     qmax = float(2 ** (bits - 1) - 1)
     m0, n0 = w2d.shape
     g = group_size
-    w = _pad_to(_pad_to(w2d.astype(jnp.float32), g, 1), tm, 0)
-    inv_s = _pad_to(1.0 / scale.astype(jnp.float32).reshape(m0, 1), tm, 0,
-                    value=1.0)
-    m, n = w.shape
+    # scale exactly as the jnp reference does (one XLA divide), so the
+    # E-stage inputs of both implementations are the same bits
+    ws = w2d.astype(jnp.float32) / scale.astype(jnp.float32).reshape(m0, 1)
+    ws = _pad_to(_pad_to(ws, g, 1), tm, 0)
+    m, n = ws.shape
     ng = n // g
 
-    grid = (m // tm, ng)
     kern = functools.partial(squant_ek_kernel, qmax=qmax, enable_k=enable_k)
-    q, delta, e1, cidx, cval = pl.pallas_call(
+    summary = pl.BlockSpec((tm, ng), lambda i, j: (i, 0))
+    q, e1, cidx, cval = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, g), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tm, g), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, g), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, j)),
-        ],
+        grid=(m // tm, ng),
+        in_specs=[pl.BlockSpec((tm, g), lambda i, j: (i, j))],
+        out_specs=[pl.BlockSpec((tm, g), lambda i, j: (i, j)),
+                   summary, summary, summary],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.int32),
-            jax.ShapeDtypeStruct((m, n), jnp.float32),
             jax.ShapeDtypeStruct((m, ng), jnp.float32),
             jax.ShapeDtypeStruct((m, ng), jnp.int32),
             jax.ShapeDtypeStruct((m, ng), jnp.float32),
         ],
         interpret=interpret,
-    )(w, inv_s)
+    )(ws)
 
     if enable_c:
         # keep the (TM_C, NG, NG) comparison tensor under ~2 MiB of VMEM;

@@ -14,19 +14,22 @@ import dataclasses
 import json
 import os
 
-import jax
-
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config, list_archs
 from repro.core.dispatch import BACKENDS
 from repro.core.pipeline import quantize_tree
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import init_params
 from repro.models.model import build_model
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b", choices=list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="toy widths in f32 (default); --no-reduced runs "
+                         "the published config in its own dtype")
     ap.add_argument("--ckpt", default=None,
                     help="checkpoint dir (default: fresh init)")
     ap.add_argument("--method", default="squant",
@@ -59,6 +62,7 @@ def main():
                          "steps in increasing order)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.reduced:
         cfg = dataclasses.replace(cfg, dtype="float32")
@@ -68,7 +72,7 @@ def main():
         params, _, step = ck.restore_latest()
         print(f"[quantize] loaded step {step} from {args.ckpt}")
     else:
-        params = model.init(jax.random.PRNGKey(0))
+        params = init_params(model, seed=0)
 
     mesh = None
     if args.mesh != "off":

@@ -63,19 +63,74 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
+from typing import Sequence
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs
 from repro.data.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServeConfig, ServeEngine
+from repro.serving.weights import make_weight_pipeline
+
+
+def build_arch_model(arch: str, reduced: bool):
+    """The model the serve CLI runs: the published config, or its
+    ``reduced()`` toy variant in f32 with room for the byte tokenizer's
+    260 ids (the CPU smoke size). Published configs keep their dtype."""
+    cfg = get_config(arch, reduced=reduced)
+    if reduced:
+        cfg = dataclasses.replace(cfg, dtype="float32",
+                                  vocab=max(cfg.vocab, 260))
+    return build_model(cfg)
+
+
+def init_params(model, seed: int = 0):
+    """Seeded weights for ``model`` with every float leaf in the config's
+    dtype, initialised in one jitted program (no f32 copy of the tree is
+    left on the device)."""
+    dtype = jnp.dtype(model.cfg.dtype)
+
+    def init(key):
+        return jax.tree_util.tree_map(
+            lambda a: a.astype(dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a,
+            model.init(key))
+
+    return jax.jit(init)(jax.random.PRNGKey(seed))
+
+
+def build_engine(model, cfg: ServeConfig, seed: int = 0) -> ServeEngine:
+    """A :class:`ServeEngine` over seed-initialised weights — how the serve
+    CLI and ``chip_smoke.py`` both construct it.
+
+    The fp tree is initialised in the layout the engine serves (its layer
+    stack unrolled when real integer codes are served), so staging makes
+    no unstacked copy of it, and no reference to it outlives this call:
+    the engine's weight store keeps only the serving tree.
+    """
+    serving_model = make_weight_pipeline(model, cfg)[0]
+    return ServeEngine(serving_model, init_params(serving_model, seed), cfg)
+
+
+def max_len_for(prompts: Sequence[Sequence[int]], max_new: int,
+                block_size: int) -> int:
+    """Cache length that fits the longest prompt plus its new tokens,
+    rounded up to whole KV blocks."""
+    need = max(len(p) for p in prompts) + max_new
+    return -(-need // block_size) * block_size
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b", choices=list_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="toy widths in f32 (default); --no-reduced runs "
+                         "the published config in its own dtype")
     ap.add_argument("--quantize", default=None,
                     choices=[None, "rtn", "squant", "squant_e", "squant_ek",
                              "squant_ec"])
@@ -147,36 +202,35 @@ def main():
                          "--reload-from to observe live swaps)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, reduced=args.reduced)
-    cfg = dataclasses.replace(cfg, dtype="float32",
-                              vocab=max(cfg.vocab, 260))
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    enable_compile_cache()
+    model = build_arch_model(args.arch, args.reduced)
     tok = ByteTokenizer()
+    prompts = [tok.encode(p) for p in args.prompts]
     deadline = None if args.swap_deadline_ms < 0 else args.swap_deadline_ms
-    eng = ServeEngine(model, params,
-                      ServeConfig(max_batch=args.batch, max_len=256,
-                                  quantize_weights=args.quantize,
-                                  weight_bits=args.bits,
-                                  quantize_kv=args.quant_kv,
-                                  scheduler=args.scheduler,
-                                  max_slots=args.max_slots,
-                                  swap_deadline_ms=deadline,
-                                  prefill_chunk=args.prefill_chunk,
-                                  kv_backend=args.kv_backend,
-                                  block_size=args.block_size,
-                                  kv_blocks=args.kv_blocks,
-                                  speculative=args.speculative,
-                                  draft_bits=args.draft_bits,
-                                  draft_k=args.draft_k))
+    max_len = max_len_for(prompts, args.max_new, args.block_size)
+    eng = build_engine(model,
+                       ServeConfig(max_batch=args.batch, max_len=max_len,
+                                   quantize_weights=args.quantize,
+                                   weight_bits=args.bits,
+                                   quantize_kv=args.quant_kv,
+                                   scheduler=args.scheduler,
+                                   max_slots=args.max_slots,
+                                   swap_deadline_ms=deadline,
+                                   prefill_chunk=args.prefill_chunk,
+                                   kv_backend=args.kv_backend,
+                                   block_size=args.block_size,
+                                   kv_blocks=args.kv_blocks,
+                                   speculative=args.speculative,
+                                   draft_bits=args.draft_bits,
+                                   draft_k=args.draft_k))
     if eng.quant_report:
         print("[serve]", eng.quant_report.summary())
     if args.reload_from:
         eng.watch_checkpoints(args.reload_from, poll_s=args.reload_poll)
         print(f"[serve] watching {args.reload_from} "
               f"(poll {args.reload_poll}s)")
-    reqs = [Request(prompt=tok.encode(p), max_new_tokens=args.max_new,
-                    request_id=i) for i, p in enumerate(args.prompts)]
+    reqs = [Request(prompt=p, max_new_tokens=args.max_new, request_id=i)
+            for i, p in enumerate(prompts)]
     for rnd in range(args.rounds):
         for c in eng.generate(reqs):
             print(f"[serve] round {rnd} req {c.request_id} "
@@ -228,9 +282,8 @@ def main():
             from repro.serving.equivalence import (agreement_budget,
                                                    greedy_token_agreement,
                                                    oracle_tokens)
-            oracle_eng = ServeEngine(
-                model, params,
-                dataclasses.replace(eng.cfg, quantize_kv=False))
+            oracle_eng = build_engine(
+                model, dataclasses.replace(eng.cfg, quantize_kv=False))
             oracle = oracle_tokens(oracle_eng.generate(reqs))
             oracle_eng.close()
             rep = greedy_token_agreement(eng, reqs, oracle)
@@ -241,6 +294,8 @@ def main():
     for err in w["errors"]:
         print(f"[serve] reload error: {err}")
     eng.close()
+    if w["errors"]:
+        sys.exit(f"[serve] {len(w['errors'])} weight reload(s) failed")
 
 
 if __name__ == "__main__":

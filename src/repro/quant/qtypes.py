@@ -139,6 +139,7 @@ class BucketReport:
     key: str                   # "(M, N)xB dtype gG"
     num_layers: int
     dispatch_millis: float     # host time to stack + dispatch this bucket
+    impl: str = ""             # what ran: "pallas" | "interpret" | "ref"
 
 
 @dataclasses.dataclass

@@ -2,8 +2,8 @@
 the main test process keeps a single device): sharding rules, int8 cross-pod
 gradient all-reduce, pod-compressed training, elastic checkpoint resharding.
 
-All mesh/shard_map construction goes through ``repro.distributed.compat`` so
-the same scripts run on jax 0.4.x and on the newer axis-typed API.
+All mesh/shard_map construction goes through ``repro.distributed.compat``
+(``jax.make_mesh`` with Auto axis types, ``jax.shard_map``).
 """
 import textwrap
 

@@ -34,7 +34,7 @@ def _quant(rng, m, n, bits, group_scales=False, group_size=32):
 def test_matches_ref(rng, b, m, n, g, bits, group_scales):
     data, scale, codes = _quant(rng, m, n, bits, group_scales, g)
     x = jnp.asarray(rng.normal(size=(b, n)).astype(np.float32))
-    got = dequant_matmul_pallas(x, data, scale, bits=bits, group_size=g,
+    got = dequant_matmul_pallas(x, data, scale, bits=bits,
                                 tb=min(8, b), tm=min(8, m), interpret=True)
     want = ref.dequant_matmul_ref(x, data, scale, bits=bits, group_size=g)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -47,8 +47,8 @@ def test_matches_dense_matmul(rng):
     qt, _ = squant(jnp.asarray(w), SQuantConfig(bits=4, group_size=32))
     x = jnp.asarray(rng.normal(size=(8, 128)).astype(np.float32))
     dense = np.asarray(x) @ np.asarray(qt.dequantize()).T
-    via_ops = ops.dequant_matmul(x, qt, group_size=32, use_pallas="interpret")
-    via_ref = ops.dequant_matmul(x, qt, group_size=32, use_pallas="ref")
+    via_ops = ops.dequant_matmul(x, qt, use_pallas="interpret")
+    via_ref = ops.dequant_matmul(x, qt, use_pallas="ref")
     np.testing.assert_allclose(np.asarray(via_ops), dense, rtol=1e-4,
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(via_ref), dense, rtol=1e-4,
@@ -59,8 +59,8 @@ def test_bf16_activations(rng):
     data, scale, _ = _quant(rng, 16, 64, 8)
     x = jnp.asarray(rng.normal(size=(8, 64)).astype(np.float32)
                     ).astype(jnp.bfloat16)
-    got = dequant_matmul_pallas(x, data, scale, bits=8, group_size=32,
-                                tb=8, tm=8, interpret=True)
+    got = dequant_matmul_pallas(x, data, scale, bits=8, tb=8, tm=8,
+                                interpret=True)
     want = ref.dequant_matmul_ref(x, data, scale, bits=8, group_size=32)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -69,10 +69,31 @@ def test_bf16_activations(rng):
 
 
 def test_int4_packing_consistency(rng):
-    """The kernel's in-VMEM nibble unpack matches qtypes.unpack_int4."""
-    from repro.kernels.dequant_matmul import _unpack_nibbles
+    """The kernel's in-VMEM nibble planes are the even and odd columns of
+    qtypes.unpack_int4."""
+    from repro.kernels.dequant_matmul import _split_nibbles
     from repro.quant.qtypes import unpack_int4
     codes = rng.integers(-8, 8, size=(4, 32)).astype(np.int8)
     packed = pack_int4(jnp.asarray(codes))
-    np.testing.assert_array_equal(np.asarray(_unpack_nibbles(packed)),
-                                  np.asarray(unpack_int4(packed)))
+    even, odd = _split_nibbles(packed)
+    full = np.asarray(unpack_int4(packed))
+    np.testing.assert_array_equal(np.asarray(even), full[:, 0::2])
+    np.testing.assert_array_equal(np.asarray(odd), full[:, 1::2])
+    np.testing.assert_array_equal(full, codes)
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+def test_ops_pads_batch_and_partial_m_tile(rng, bits):
+    """A batch that is not a multiple of the sublane tile is padded (not
+    tile-shrunk), and an M the row tile does not divide leaves a partial
+    last tile whose out-of-range rows are dropped."""
+    from repro.quant.qtypes import QuantizedTensor
+    m, n, b = 20, 512, 11
+    data, scale, _ = _quant(rng, m, n, bits)
+    qt = QuantizedTensor(data=data, scale=scale, bits=bits, shape=(m, n))
+    x = jnp.asarray(rng.normal(size=(b, n)).astype(np.float32))
+    got = ops.dequant_matmul(x, qt, use_pallas="interpret", tm=8)
+    want = ops.dequant_matmul(x, qt, use_pallas="ref")
+    assert got.shape == (b, m)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)

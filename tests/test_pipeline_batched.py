@@ -111,6 +111,40 @@ def test_backend_resolution():
         quantize_tree({"w": jnp.ones((4, 4))}, backend="cuda")
 
 
+@pytest.mark.parametrize("entry", ["squant_flip", "quantize_tree",
+                                   "dequant_matmul", "paged_attention",
+                                   "paged_attention_quant"])
+def test_explicit_pallas_never_falls_back(entry):
+    """An explicit ``"pallas"`` always compiles the kernel: where it cannot
+    lower (here, the CPU backend) the call raises instead of quietly
+    running the jnp reference."""
+    from repro.kernels import ops
+    from repro.kernels.paged_attention import paged_attention
+    from repro.kernels.paged_attention_quant import paged_attention_quant
+    from repro.quant.qtypes import from_codes
+    w = jnp.ones((8, 256), jnp.float32)
+    s = jnp.ones((8, 1), jnp.float32)
+    q = jnp.ones((1, 2, 128), jnp.float32)
+    pool = jnp.ones((2, 16, 1, 128), jnp.float32)
+    tables, lengths = jnp.ones((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32)
+    calls = {
+        "squant_flip": lambda: ops.squant_flip(
+            w, s, bits=4, group_size=128, use_pallas="pallas"),
+        "quantize_tree": lambda: quantize_tree(
+            {"w": w.T}, bits=4, group_size=128, backend="pallas"),
+        "dequant_matmul": lambda: ops.dequant_matmul(
+            w, from_codes(w.astype(jnp.int8), s, 8), use_pallas="pallas"),
+        "paged_attention": lambda: paged_attention(
+            q, pool, pool, tables, lengths, scale=1.0, use_pallas="pallas"),
+        "paged_attention_quant": lambda: paged_attention_quant(
+            q, pool.astype(jnp.int8), pool.astype(jnp.int8), pool[..., 0],
+            pool[..., 0], tables, lengths, scale=1.0, use_pallas="pallas"),
+    }
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(Exception, match="interpret"):
+        jax.block_until_ready(calls[entry]())
+
+
 def test_bucket_chunking_bit_exact(rng, monkeypatch):
     """A bucket whose stack exceeds the byte cap splits into chunks; results
     stay bit-exact and the tree still syncs once."""
@@ -141,3 +175,23 @@ def test_report_breakdown(rng):
     assert "buckets" in rep.summary()
     for lr in rep.layers:
         assert lr.bucket            # every layer names its bucket
+
+
+@pytest.mark.parametrize("backend,grouped", [
+    ("interpret", "interpret"), ("ref", "ref"), ("auto", "ref")])
+def test_report_bucket_impl(rng, backend, grouped):
+    """Each bucket records what it ran on: grouped SQuant buckets take the
+    requested kernel backend; whole-row (``gNone``) buckets, the E&C-only
+    ablation and RTN run the jnp reference by design, on every backend
+    (on the CPU ``auto`` is the reference)."""
+    def w(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    src = {"grouped": {"w": w(32, 8)}, "whole_row": {"w": w(8, 8)}}
+    _, rep = quantize_tree(src, method="squant", bits=4, group_size=16,
+                           backend=backend)
+    impl = {b.key.split(" ")[0]: b.impl for b in rep.buckets}
+    assert impl == {"(8,32)x1": grouped, "(8,8)x1": "ref"}
+    for method in ("squant_ec", "rtn"):
+        _, rep = quantize_tree(src, method=method, bits=4, group_size=16,
+                               backend=backend)
+        assert {b.impl for b in rep.buckets} == {"ref"}

@@ -137,6 +137,25 @@ def test_sharded_inprocess_bit_exact(rng):
         assert "sharded data=" in rep.summary()
 
 
+def test_sharded_bf16_tree_bit_exact(rng):
+    """bf16 weights (the published configs' dtype): scales are computed in
+    f32, so the sharded program — which fuses the scale into the divide —
+    and the unsharded one quantize against the same scale bits (a bf16
+    scale let XLA skip its rounding in one program and not the other)."""
+    mesh = make_quantize_mesh()
+    src = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                 {"a": {"w": jnp.asarray(
+                                     rng.normal(size=(256, 384)),
+                                     jnp.float32)}})
+    q_sh, _ = quantize_tree(src, bits=4, group_size=128, mesh=mesh,
+                            backend="ref")
+    q_un, _ = quantize_tree(src, bits=4, group_size=128, backend="ref")
+    np.testing.assert_array_equal(np.asarray(q_sh["a"]["w"].codes()),
+                                  np.asarray(q_un["a"]["w"].codes()))
+    np.testing.assert_array_equal(np.asarray(q_sh["a"]["w"].scale),
+                                  np.asarray(q_un["a"]["w"].scale))
+
+
 def test_sharded_dequantize_matches_unsharded(rng):
     mesh = make_quantize_mesh()
     src = _tree(rng)

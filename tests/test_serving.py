@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.models.model import build_model
@@ -108,3 +109,74 @@ def test_quantized_expert_serving():
                                   dequantize_for_compute=False))
     outs = eng.generate([Request(prompt=[3, 1, 4], max_new_tokens=3)])
     assert len(outs[0].tokens) == 3
+
+
+def test_build_engine_real_codes_layout_and_dtype():
+    """``launch.serve.build_engine`` — the construction the serve CLI and
+    the chip smoke test share — initialises the fp tree in the layout the
+    engine serves (unrolled for real int codes, so staging makes no
+    unstacked copy) and in the config's dtype, and serves from it."""
+    from repro.launch.serve import build_arch_model, build_engine, \
+        init_params, max_len_for
+    from repro.quant.qtypes import QuantizedTensor
+    model = build_arch_model("granite-3-8b", reduced=True)
+    prompts = [[1, 2, 3], list(range(1, 21))]
+    assert max_len_for(prompts, 4, 16) == 32
+    eng = build_engine(model, ServeConfig(
+        max_batch=2, max_len=max_len_for(prompts, 4, 16),
+        quantize_weights="squant", weight_bits=8,
+        dequantize_for_compute=False))
+    assert "list" in eng.params["stack"]
+    leaves = jax.tree_util.tree_leaves(
+        eng.params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+    assert any(isinstance(x, QuantizedTensor) for x in leaves)
+    outs = eng.generate([Request(prompt=p, max_new_tokens=4, request_id=i)
+                         for i, p in enumerate(prompts)])
+    assert [len(o.tokens) for o in outs] == [4, 4]
+    eng.close()
+    bf16 = build_model(dataclasses.replace(model.cfg, dtype="bfloat16"))
+    dtypes = {a.dtype for a in jax.tree_util.tree_leaves(init_params(bf16))}
+    assert dtypes == {jnp.dtype(jnp.bfloat16)}
+
+
+def test_serve_cli_fails_on_reload_error(tmp_path, monkeypatch, capsys):
+    """A reload the weight store rejects (here a w4 checkpoint under a w8
+    serve) makes the serve CLI exit non-zero, after printing the error."""
+    import sys
+    from repro.checkpoint.checkpointer import Checkpointer
+    from repro.launch import serve
+    from repro.quant.apply import quantize_params_serving
+    model = serve.build_arch_model("granite-3-8b", reduced=True)
+    qtree, meta = quantize_params_serving(serve.init_params(model), 4,
+                                          "squant")
+    Checkpointer(str(tmp_path), async_save=False).save_serving(
+        1, qtree, quant_meta=meta)
+    # keeps main() from pointing this process's compile cache at the repo
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--quantize", "squant", "--bits", "8", "--batch", "1",
+        "--max-new", "2", "--prompts", "hi", "--rounds", "2",
+        "--reload-from", str(tmp_path), "--reload-poll", "0.01"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code not in (0, None)
+    assert "reload error" in capsys.readouterr().out
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' cache helper defers to JAX_COMPILATION_CACHE_DIR
+    when it is set and otherwise fixes the cache at <repo>/.jax_cache."""
+    import os
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,0 +1,108 @@
+"""The four Pallas kernels compile for a TPU v5e at published widths.
+
+Nothing runs: each kernel is lowered and compiled against a *described*
+``v5e:2x2`` topology, so the TPU compiler's own checks (block tiling,
+VMEM, Mosaic lowering) guard every change without a chip. Interpret-mode
+tests cannot see these failures. Shapes are granite-3-8b's and
+minitron-4b's, plus the two the kernels once got wrong: a prefill batch
+that is not a multiple of 8 and SQuant with one group per row.
+
+The topology is described inside a module fixture, never at import: only
+the worker that runs this file loads the TPU compiler library.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.paged_attention import paged_decode_pallas
+from repro.kernels.squant_flip import squant_pallas
+from repro.quant.qtypes import QuantizedTensor
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology compile cannot be read back from the
+    # persistent cache without a chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compiles_with_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m,n,bits,enable_c", [
+    (4096, 4096, 4, True),          # granite attention, E&K&C
+    (4096, 4096, 8, True),
+    (12800, 4096, 4, True),         # granite FFN rows
+    (4096, 4096, 4, False),         # E&K only
+    (4096, 128, 8, True),           # one group per row
+])
+def test_squant_flip_compiles(spec, m, n, bits, enable_c):
+    fn = functools.partial(squant_pallas, bits=bits, group_size=128,
+                           enable_c=enable_c)
+    _compiles_with_kernel(fn, spec((m, n), jnp.bfloat16),
+                          spec((m, 1), jnp.float32))
+
+
+@pytest.mark.parametrize("bits,groups", [
+    (8, 1), (4, 1),                 # per-channel scales (what SQuant makes)
+    (8, 32),                        # per-group scales, g128
+])
+@pytest.mark.parametrize("batch", [8, 148])     # decode; prefill, not 8k
+def test_dequant_matmul_compiles(spec, bits, groups, batch):
+    m, n = 12800, 4096                          # granite FFN up-projection
+    packed = bits <= 4
+
+    def fn(x, codes, scale):
+        qt = QuantizedTensor(codes, scale, bits=bits, shape=(m, n))
+        return ops.dequant_matmul(x, qt, use_pallas="pallas")
+
+    _compiles_with_kernel(fn, spec((batch, n), jnp.bfloat16),
+                          spec((m, n // 2 if packed else n), jnp.int8),
+                          spec((m, groups), jnp.float32))
+
+
+@pytest.mark.parametrize("heads", [24, 32])     # minitron-4b, granite-3-8b
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_attention_compiles(spec, heads, quantized):
+    slots, kv, d, bs, context = 8, 8, 128, 16, 2048
+    nb = context // bs
+    n = slots * nb + 1
+    pool = jnp.int8 if quantized else jnp.bfloat16
+    args = [spec((slots, heads, d), jnp.bfloat16),
+            spec((n, bs, kv, d), pool), spec((n, bs, kv, d), pool),
+            spec((slots, nb), jnp.int32), spec((slots,), jnp.int32)]
+    if quantized:
+        args += [spec((n, bs, kv), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, lengths, *scales):
+        return paged_decode_pallas(q, k, v, tables, lengths,
+                                   scales if scales else None,
+                                   scale=d ** -0.5, interpret=False)
+
+    _compiles_with_kernel(fn, *args)
