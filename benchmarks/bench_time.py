@@ -72,10 +72,7 @@ def bench_pipeline(report=print) -> Dict:
                 ms = (time.perf_counter() - t0) * 1e3
                 if ms < times[mode]:
                     times[mode] = ms
-                    if mode == "batched":   # breakdown from the min rep, so
-                        # dispatch+sync stay consistent with the reported total
-                        out[f"pipeline_{name}_dispatch_ms"] = rep.dispatch_millis
-                        out[f"pipeline_{name}_sync_ms"] = rep.sync_millis
+                    if mode == "batched":
                         out[f"pipeline_{name}_buckets"] = len(rep.buckets)
                         out[f"pipeline_{name}_layers"] = len(rep.layers)
         for mode in modes:

@@ -203,8 +203,7 @@ def _peak_bytes() -> int:
 def _report_buckets(tag: str, report) -> None:
     log(f"{tag}: {report.summary()}")
     for b in report.buckets:
-        log(f"  bucket {b.key}: impl={b.impl} {b.num_layers} layers "
-            f"{b.dispatch_millis:.1f} ms")
+        log(f"  bucket {b.key}: impl={b.impl} {b.num_layers} layers")
 
 
 def serve_phase(checks: Checks, *, arch: str = SERVE_ARCH,

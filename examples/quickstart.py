@@ -51,8 +51,7 @@ def main():
     print(f"\nwhole {cfg.name}: {report.summary()} "
           f"(wall {dt*1e3:.0f} ms, no data, no BP)")
     for b in report.buckets:
-        print(f"  bucket {b.key}: {b.num_layers} layers, "
-              f"{b.dispatch_millis:.2f} ms dispatch")
+        print(f"  bucket {b.key}: {b.num_layers} layers, impl={b.impl}")
     from repro.quant.qtypes import QuantizedTensor
     qbytes = sum(
         leaf.nbytes() for leaf in jax.tree_util.tree_leaves(

@@ -11,8 +11,9 @@ Execution modes:
   dtype, group) buckets; each bucket is stacked and quantized with ONE
   asynchronous dispatch (vmapped jnp core or a single flattened Pallas
   launch, see ``core.dispatch``), and the whole tree synchronizes with the
-  device ONCE at the end. ``QuantReport`` carries the per-bucket wall times
-  plus a dispatch/sync breakdown so Table-3-style numbers stay reportable.
+  device ONCE at the end. ``QuantReport`` carries the whole call's wall
+  time and the buckets; where the host spends it (plan, stack, dispatch,
+  wrap, sync) is in the ``quantize.*`` host spans of a profiler trace.
 * ``batched=True, mesh=...`` — same bucketing, but each bucket's rows are
   partitioned over the mesh's ``mesh_axis`` under ``shard_map``: every device
   quantizes its own output-channel slab (SQuant is row-independent, so the
@@ -59,6 +60,11 @@ METHODS = ("rtn", "squant", "squant_e", "squant_ek", "squant_ec")
 # path calls this exactly once per quantize_tree, the serial path once per
 # quantized leaf.
 _sync = jax.block_until_ready
+
+# host spans on the profiler's clock (free while no trace is being taken):
+# quantize.tree > quantize.plan, quantize.chunk > (quantize.stack,
+# quantize.dispatch, quantize.wrap), quantize.sync
+_span = jax.profiler.TraceAnnotation
 
 
 def is_quantizable(path: Tuple[str, ...], leaf: Any) -> bool:
@@ -192,6 +198,33 @@ def _quantize_tree_serial(flat, treedef, pred, method, bits, group_size,
 _MAX_STACK_BYTES = 256 << 20
 
 
+def _dispatch_chunk(ws, method, bits, eff, scale_method, backend, mesh,
+                    mesh_axis):
+    """Codes and scales of one stacked bucket chunk, unsharded or with its
+    rows partitioned over ``mesh_axis``."""
+    if mesh is None:
+        return quantize_codes_batched(
+            ws, method=method, bits=bits, group_size=eff,
+            scale_method=scale_method, backend=backend)
+    return quantize_codes_sharded(
+        ws, method=method, bits=bits, group_size=eff,
+        scale_method=scale_method, backend=backend, mesh=mesh,
+        mesh_axis=mesh_axis)
+
+
+def _wrap_leaf(codes, scales, bits, leaf, path, dequantize, mesh):
+    """One leaf's output: its ``QuantizedTensor``, or the dequantized
+    weights in the leaf's layout (``dequantize``)."""
+    qt = from_codes(codes, scales, bits)
+    if dequantize:
+        return _restore_dense(qt.dequantize(leaf.dtype), tuple(leaf.shape))
+    if mesh is not None:
+        # codes/scales inherit the source param's sharding rules
+        from repro.distributed.sharding import quantized_tensor_shardings
+        return qt.with_placement(*quantized_tensor_shardings(mesh, path, qt))
+    return qt
+
+
 def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
                            scale_method, dequantize, backend, mesh,
                            mesh_axis):
@@ -202,15 +235,16 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
     out_leaves: List[Any] = [None] * len(flat)
     # bucket key -> list of (leaf index, path, leaf, qt_shape)
     buckets: Dict[Tuple, List] = {}
-    for idx, (keypath, leaf) in enumerate(flat):
-        path = tuple(str(getattr(k, "key", getattr(k, "idx", str(k))))
-                     for k in keypath)
-        if not pred(path, leaf):
-            out_leaves[idx] = leaf
-            continue
-        w2d_shape, qt_shape, eff = _plan_leaf(leaf, method, group_size)
-        key = (w2d_shape, str(leaf.dtype), eff)
-        buckets.setdefault(key, []).append((idx, path, leaf, qt_shape))
+    with _span("quantize.plan"):
+        for idx, (keypath, leaf) in enumerate(flat):
+            path = tuple(str(getattr(k, "key", getattr(k, "idx", str(k))))
+                         for k in keypath)
+            if not pred(path, leaf):
+                out_leaves[idx] = leaf
+                continue
+            w2d_shape, qt_shape, eff = _plan_leaf(leaf, method, group_size)
+            key = (w2d_shape, str(leaf.dtype), eff)
+            buckets.setdefault(key, []).append((idx, path, leaf, qt_shape))
 
     layer_reports: List[LayerReport] = []
     bucket_reports: List[BucketReport] = []
@@ -225,47 +259,36 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
             entries = all_entries[c0:c0 + chunk]
             tag = f"({m},{n})x{len(entries)} {dtype} g{eff}"
             tb0 = time.perf_counter()
-            ws = _stack_2d(tuple(e[2] for e in entries))   # (B, M, N)
-            if mesh is None:
-                codes, scales = quantize_codes_batched(
-                    ws, method=method, bits=bits, group_size=eff,
-                    scale_method=scale_method, backend=backend)
-            else:
-                codes, scales = quantize_codes_sharded(
-                    ws, method=method, bits=bits, group_size=eff,
-                    scale_method=scale_method, backend=backend,
-                    mesh=mesh, mesh_axis=mesh_axis)
-                for d, (r, p) in enumerate(
-                        shard_rows(len(entries) * m, ndev)):
-                    shard_acc[d][0] += r
-                    shard_acc[d][1] += p
-            for bi, (idx, path, leaf, qt_shape) in enumerate(entries):
-                qt = from_codes(codes[bi].reshape(qt_shape), scales[bi], bits)
-                if dequantize:
-                    out = _restore_dense(qt.dequantize(leaf.dtype),
-                                         tuple(leaf.shape))
-                elif mesh is not None:
-                    # codes/scales inherit the source param's sharding rules
-                    from repro.distributed.sharding import \
-                        quantized_tensor_shardings
-                    out = qt.with_placement(
-                        *quantized_tensor_shardings(mesh, path, qt))
-                else:
-                    out = qt
-                out_leaves[idx] = out
-                quantized.append(out)
+            with _span("quantize.chunk", bucket=tag, layers=len(entries)):
+                with _span("quantize.stack"):
+                    ws = _stack_2d(tuple(e[2] for e in entries))  # (B,M,N)
+                with _span("quantize.dispatch"):
+                    codes, scales = _dispatch_chunk(
+                        ws, method, bits, eff, scale_method, backend, mesh,
+                        mesh_axis)
+                if mesh is not None:
+                    for d, (r, p) in enumerate(
+                            shard_rows(len(entries) * m, ndev)):
+                        shard_acc[d][0] += r
+                        shard_acc[d][1] += p
+                with _span("quantize.wrap"):
+                    for bi, (idx, path, leaf, qt_shape) in enumerate(entries):
+                        out = _wrap_leaf(codes[bi].reshape(qt_shape),
+                                         scales[bi], bits, leaf, path,
+                                         dequantize, mesh)
+                        out_leaves[idx] = out
+                        quantized.append(out)
             bucket_ms = (time.perf_counter() - tb0) * 1e3
-            bucket_reports.append(BucketReport(tag, len(entries), bucket_ms,
-                                               impl))
+            bucket_reports.append(BucketReport(tag, len(entries), impl))
             for idx, path, leaf, _ in entries:
                 layer_reports.append(LayerReport("/".join(path),
                                                  tuple(leaf.shape),
                                                  bucket_ms / len(entries),
                                                  method, bits, bucket=tag))
-    dispatch_ms = (time.perf_counter() - t_begin) * 1e3
 
     t_sync0 = time.perf_counter()
-    _sync(quantized)                          # the ONE device sync
+    with _span("quantize.sync"):
+        _sync(quantized)                      # the ONE device sync
     sync_ms = (time.perf_counter() - t_sync0) * 1e3
     # fold the sync into per-layer numbers so Σ layer.millis ≈ total
     for lr in layer_reports:
@@ -276,8 +299,7 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
     shards = [ShardReport(d, r, p) for d, (r, p) in enumerate(shard_acc)] \
         if mesh is not None else []
     return tree, QuantReport(layer_reports, total_ms, method, bits,
-                             backend=backend, dispatch_millis=dispatch_ms,
-                             sync_millis=sync_ms, buckets=bucket_reports,
+                             backend=backend, buckets=bucket_reports,
                              mesh_axis=mesh_axis if mesh is not None else "",
                              mesh_size=ndev, shards=shards)
 
@@ -314,12 +336,18 @@ def quantize_tree(params: Any, method: str = "squant", bits: int = 4,
     backend = resolve_backend(backend)
     pred = predicate or is_quantizable
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    if not batched:
-        if mesh is not None:
-            raise ValueError("mesh= requires batched=True (the serial "
-                             "baseline is single-device by definition)")
-        return _quantize_tree_serial(flat, treedef, pred, method, bits,
-                                     group_size, scale_method, dequantize)
-    return _quantize_tree_batched(flat, treedef, pred, method, bits,
-                                  group_size, scale_method, dequantize,
-                                  backend, mesh, mesh_axis)
+    if not batched and mesh is not None:
+        raise ValueError("mesh= requires batched=True (the serial "
+                         "baseline is single-device by definition)")
+    with _span("quantize.tree") as span:
+        if batched:
+            tree, report = _quantize_tree_batched(
+                flat, treedef, pred, method, bits, group_size, scale_method,
+                dequantize, backend, mesh, mesh_axis)
+        else:
+            tree, report = _quantize_tree_serial(
+                flat, treedef, pred, method, bits, group_size, scale_method,
+                dequantize)
+        span.set_metadata(leaves=len(report.layers),
+                          buckets=len(report.buckets))
+    return tree, report

@@ -93,15 +93,13 @@ def main():
                    "backend": report.backend,
                    "batched": not args.serial,
                    "total_ms": report.total_millis,
-                   "dispatch_ms": report.dispatch_millis,
-                   "sync_ms": report.sync_millis,
                    "mesh_axis": report.mesh_axis,
                    "mesh_size": report.mesh_size,
                    "shards": [{"device": s.device, "rows": s.rows,
                                "pad_rows": s.pad_rows}
                               for s in report.shards],
                    "buckets": [{"key": b.key, "layers": b.num_layers,
-                                "ms": b.dispatch_millis}
+                                "impl": b.impl}
                                for b in report.buckets],
                    "layers": [{"path": l.path, "shape": list(l.shape),
                                "ms": l.millis, "bucket": l.bucket}

@@ -138,7 +138,6 @@ class LayerReport:
 class BucketReport:
     key: str                   # "(M, N)xB dtype gG"
     num_layers: int
-    dispatch_millis: float     # host time to stack + dispatch this bucket
     impl: str = ""             # what ran: "pallas" | "interpret" | "ref"
 
 
@@ -157,8 +156,6 @@ class QuantReport:
     method: str
     bits: int
     backend: str = "ref"
-    dispatch_millis: float = 0.0
-    sync_millis: float = 0.0
     buckets: List[BucketReport] = dataclasses.field(default_factory=list)
     mesh_axis: str = ""        # sharded runs: name of the partitioned axis
     mesh_size: int = 1         # devices along that axis (1 → unsharded)
@@ -169,9 +166,7 @@ class QuantReport:
              f"{self.total_millis:.1f} ms "
              f"({self.total_millis / max(len(self.layers), 1):.2f} ms/layer)")
         if self.buckets:
-            s += (f" [{len(self.buckets)} buckets, backend={self.backend}, "
-                  f"dispatch {self.dispatch_millis:.1f} ms + "
-                  f"sync {self.sync_millis:.1f} ms]")
+            s += f" [{len(self.buckets)} buckets, backend={self.backend}]"
         if self.mesh_size > 1:
             rows = sum(sh.rows for sh in self.shards)
             s += (f" [sharded {self.mesh_axis}={self.mesh_size}, "

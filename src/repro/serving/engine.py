@@ -311,7 +311,9 @@ class ServeEngine:
         self._round_log: collections.deque = collections.deque(maxlen=1024)
         # optional per-step instrumentation hook (tests/benches): called
         # with {"step", "recorded", "version", "draining", "t", ...} after
-        # each lockstep sampling step
+        # each lockstep sampling step (the continuous scheduler over the
+        # paged cache adds "positions_attended" and "positions_walked" of
+        # the decode launch whose logits the step sampled)
         self.on_step = None
         if self.cfg.scheduler == "continuous":
             self.scheduler = ContinuousScheduler(self)
@@ -325,6 +327,9 @@ class ServeEngine:
         def counted(*args):
             self.trace_counts[name] += 1   # runs at trace time only
             return fn(*args)
+        # the program's name in a profiler trace: jit_serve_<name> on the
+        # device, PjitFunction(serve_<name>) on the host
+        counted.__name__ = counted.__qualname__ = f"serve_{name}"
         return jax.jit(counted)
 
     # ------------------------------------------------------------ weights

@@ -63,6 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# host spans on the profiler's clock (free while no trace is being taken)
+_span = jax.profiler.TraceAnnotation
+
 TRASH = 0   # reserved physical block: write target for retired slots
 
 __all__ = ["KVCache", "ContiguousKVCache", "PagedKVCache", "admit_rows"]
@@ -250,6 +253,11 @@ class KVCache:
         keeps speculation off this backend."""
         raise NotImplementedError(
             f"rewind is not supported by the {self.backend!r} KV backend")
+
+    def decode_positions(self) -> Dict[str, int]:
+        """What the decode launches since the previous call attended and
+        walked (backends that count them; empty otherwise)."""
+        return {}
 
     def stats(self) -> Dict[str, Any]:
         return {"backend": self.backend}
@@ -448,6 +456,12 @@ class PagedKVCache(KVCache):
         self.cow_copies = 0
         self.evictions = 0
         self.peak_blocks_active = 0
+        # decode launches: context positions the active slots attend, and
+        # the positions the decode program walks (every row of the block
+        # tables it is handed, to their end); cumulative, and since the
+        # last ``decode_positions``
+        self.positions_attended = self.positions_walked = 0
+        self._step_attended = self._step_walked = 0
         # jitted paged ops with trace accounting (lazy counters: the
         # contiguous path's trace_counts stay exactly as before)
         for name in ("gather", "scatter", "copy"):
@@ -722,7 +736,8 @@ class PagedKVCache(KVCache):
 
     def _admit_one(self, slot: int, r, params) -> None:
         prompt = [int(t) for t in r.prompt]
-        lp, side = self._pin_prefix(slot, prompt)
+        with _span("kv.gather", request_id=r.request_id):
+            lp, side = self._pin_prefix(slot, prompt)
         if lp:
             toks = jnp.asarray(np.asarray(prompt[lp:], np.int32))[None]
             lg, side = self.eng._prefill_chunk(params, {"tokens": toks},
@@ -730,8 +745,9 @@ class PagedKVCache(KVCache):
         else:
             toks = jnp.asarray(np.asarray(prompt, np.int32))[None]
             lg, side = self.eng._prefill(params, {"tokens": toks}, side)
-        rem = self._commit_blocks(slot, prompt, lp, side, lg,
-                                  r.max_new_tokens)
+        with _span("kv.scatter", request_id=r.request_id):
+            rem = self._commit_blocks(slot, prompt, lp, side, lg,
+                                      r.max_new_tokens)
         self._slot_reserved[slot] = rem
         self._reserved += rem
 
@@ -757,7 +773,8 @@ class PagedKVCache(KVCache):
         otherwise free a block the pending gathered from). Returns the
         shared-prefix length and the positioned side cache; the scheduler
         chunk-prefills ``prompt[lp:]`` on it across engine steps."""
-        return self._pin_prefix(slot, [int(t) for t in req.prompt])
+        with _span("kv.gather", request_id=req.request_id):
+            return self._pin_prefix(slot, [int(t) for t in req.prompt])
 
     def complete_chunked_admit(self, slot: int, req, lp: int, side,
                                lg) -> None:
@@ -766,12 +783,13 @@ class PagedKVCache(KVCache):
         per-slot clock — no shared completion clock, no catch-up
         recurrence) and re-point the up-front reservation at the
         decode-only remainder."""
-        rem = self._commit_blocks(slot, [int(t) for t in req.prompt], lp,
-                                  side, lg, req.max_new_tokens)
-        resv = int(self._slot_reserved[slot])
-        self._reserved -= resv - rem
-        self._slot_reserved[slot] = rem
-        jax.block_until_ready(self._logits)
+        with _span("kv.scatter", request_id=req.request_id):
+            rem = self._commit_blocks(slot, [int(t) for t in req.prompt],
+                                      lp, side, lg, req.max_new_tokens)
+            resv = int(self._slot_reserved[slot])
+            self._reserved -= resv - rem
+            self._slot_reserved[slot] = rem
+            jax.block_until_ready(self._logits)
 
     def abandon_chunked_admit(self, slot: int) -> None:
         """A force-swap abandons a pending entry mid-prefill: unpin
@@ -831,17 +849,33 @@ class PagedKVCache(KVCache):
             self.cow_copies += 1
 
     def decode(self, params, nxt, active_ids) -> None:
-        bs = self.block_size
-        for i in active_ids:
-            self._writable_block(i, int(self._lengths[i]) // bs)
-        # snapshots, not views: the device arrays may alias host memory
-        # (zero-copy transfer) and ``_lengths``/``_tables`` are mutated
-        # right after dispatch — aliasing would race the async decode
-        self._cache["pos"] = jnp.asarray(self._lengths.copy())
-        self._cache["block_tables"] = jnp.asarray(self._tables.copy())
-        self._logits, self._cache = self.eng._decode(
-            params, nxt[:, None], self._cache)
-        self._lengths[active_ids] += 1
+        with _span("kv.decode", slots=len(active_ids)):
+            bs = self.block_size
+            for i in active_ids:
+                self._writable_block(i, int(self._lengths[i]) // bs)
+            # snapshots, not views: the device arrays may alias host memory
+            # (zero-copy transfer) and ``_lengths``/``_tables`` are mutated
+            # right after dispatch — aliasing would race the async decode
+            tables = self._tables.copy()
+            self._cache["pos"] = jnp.asarray(self._lengths.copy())
+            self._cache["block_tables"] = jnp.asarray(tables)
+            self._logits, self._cache = self.eng._decode(
+                params, nxt[:, None], self._cache)
+            self._lengths[active_ids] += 1
+        # slot b attends its lengths[b] + 1 positions (now its length); the
+        # program walks the whole table it was handed
+        attended = int(self._lengths[active_ids].sum())
+        walked = tables.size * bs
+        self.positions_attended += attended
+        self.positions_walked += walked
+        self._step_attended += attended
+        self._step_walked += walked
+
+    def decode_positions(self) -> Dict[str, int]:
+        out = {"positions_attended": self._step_attended,
+               "positions_walked": self._step_walked}
+        self._step_attended = self._step_walked = 0
+        return out
 
     # ------------------------------------------------- speculative verify
     def ensure_rows(self, slot: int, start: int, n: int) -> None:
@@ -967,4 +1001,6 @@ class PagedKVCache(KVCache):
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_reused": self.prefix_tokens_reused,
                 "cow_copies": self.cow_copies,
+                "positions_attended": self.positions_attended,
+                "positions_walked": self.positions_walked,
                 "evictions": self.evictions}

@@ -104,6 +104,9 @@ from repro.serving.kvcache import KVCache, admit_rows  # noqa: F401
 from repro.serving.sampling import sample
 from repro.serving.speculative import SpeculativeDecoder
 
+# host spans on the profiler's clock (free while no trace is being taken)
+_span = jax.profiler.TraceAnnotation
+
 
 def _req_eos(req: Request, cfg) -> int:
     """Per-request EOS override (None: the engine-global eos_id)."""
@@ -352,6 +355,7 @@ class ContinuousScheduler(_SchedulerBase):
             self.spec.bind(self)
         self._ver = None              # the currently-acquired WeightVersion
         self._pending_swap_ms = 0.0   # swap time to attribute at admission
+        self._drain_t0: Optional[float] = None   # when the drain began
         self._kv_version = None       # weight version the KV prefix cache
         #                               was built under (flush on change)
         self._pending: Optional[PendingPrefill] = None
@@ -385,11 +389,9 @@ class ContinuousScheduler(_SchedulerBase):
 
     # ------------------------------------------------------------------ api
     def run(self, requests: List[Request]) -> List[Completion]:
-        cfg = self.cfg
         results: List[Optional[Completion]] = [None] * len(requests)
         queue: "collections.deque[Tuple[int, Request]]" = collections.deque()
         ver, swap_ms = self.store.acquire()
-        params = ver.params
         self._ver = ver
         # a version staged between generate() calls swaps at this acquire,
         # bypassing the drain branch — the KV cache must still learn of it
@@ -403,125 +405,86 @@ class ContinuousScheduler(_SchedulerBase):
                 continue
             queue.append((i, r))
         self.kv.begin_run()
-        drain_t0 = None
+        self._drain_t0 = None
         self._last_emit_t = time.perf_counter()
 
         while queue or self._pending is not None \
                 or any(s is not None for s in self.slots):
-            active_ids = [i for i, s in enumerate(self.slots)
-                          if s is not None]
-            # ---- reload-awareness: drain, then swap at a step boundary ----
-            staged = self.store.staged_info()
-            if staged is not None:
-                if drain_t0 is None:
-                    drain_t0 = time.perf_counter()
-                    self.drains += 1
-                    in_flight = len(active_ids) + (
-                        self._pending.remaining_requests
-                        if self._pending else 0)
-                    self.store.note_drain(in_flight)
-                elapsed_ms = (time.perf_counter() - drain_t0) * 1e3
-                deadline = cfg.swap_deadline_ms
-                # the deadline clock starts when the version finished
-                # staging (store-side), not when this loop first saw it —
-                # a version staged between generate() calls swaps at once.
-                # A chunked admission in flight is drained like any other
-                # in-flight work; a forced swap abandons it instead (its
-                # chunks ran on the old weights) and re-queues its requests
-                busy = bool(active_ids) or self._pending is not None
-                if not busy or (deadline is not None
-                                and staged.age_ms >= deadline):
-                    if self._pending is not None:
-                        self._abandon_pending(queue)
-                    forced = busy
-                    ver, sms = self.store.acquire()
-                    params = ver.params
-                    self._ver = ver
-                    self._sync_kv_version(ver.version)
-                    self.store.note_swap(forced=forced, drain_ms=elapsed_ms)
-                    self._pending_swap_ms += sms
-                    if forced:
-                        self.forced_swaps += 1
-                        for i in active_ids:
-                            self.slots[i].forced_swaps += 1
-                            self.slots[i].swap_ms += sms
-                    drain_t0 = None
-            draining = self.store.staged_pending
+            with _span("serve.step", step=self.steps_total + 1):
+                self._step(queue, results)
+        return results  # type: ignore[return-value]
 
-            # ---- admission into free slots (paused while draining or
-            # while a chunked admission is already in flight) ----
-            admit_ms = 0.0
-            if self._pending is None and queue and not draining:
-                free_ids = [i for i, s in enumerate(self.slots)
-                            if s is None]
-                if free_ids:
-                    fresh = len(free_ids) == self.max_slots
-                    head = queue[0]
-                    limit_head = (not fresh and self._head_skips
-                                  >= cfg.starvation_limit)
-                    if self.chunk:
-                        chosen = self._start_pending(
-                            queue, free_ids, fresh, ver.version, limit_head)
-                    else:
-                        chosen, new_clock = self.kv.pick(
-                            queue, len(free_ids), fresh, limit_head)
-                        if chosen:
-                            if fresh:
-                                self.waves += 1
-                            t0 = time.perf_counter()
-                            self._admit(chosen, free_ids, new_clock, params,
-                                        ver.version)
-                            admit_ms = (time.perf_counter() - t0) * 1e3
-                    # FCFS-with-skip starvation guard: count picks that
-                    # jumped the queue head; past the limit, mid-flight
-                    # admission narrows to the head only, so the pool
-                    # drains into a fresh wave that must admit it
-                    if fresh or (chosen and head in chosen):
-                        self._head_skips = 0
-                    elif chosen:
-                        self._head_skips += 1
+    def _step(self, queue, results) -> None:
+        """One scheduler iteration: swap point, admission, one chunk of a
+        pending admission, then (with any slot live) sample, retire, report
+        and dispatch the next decode."""
+        cfg = self.cfg
+        active_ids = [i for i, s in enumerate(self.slots) if s is not None]
+        # ---- reload-awareness: drain, then swap at a step boundary ----
+        staged = self.store.staged_info()
+        if staged is not None:
+            with _span("serve.swap"):
+                self._swap_point(queue, active_ids, staged)
+        ver = self._ver
+        params = ver.params
+        draining = self.store.staged_pending
 
-            # ---- chunked admission: consume this step's prefill budget;
-            # scatter into the pool when it completes at its clock ----
-            chunk_ms = 0.0
-            if self._pending is not None:
-                chunk_ms = self._advance_pending(params)
-                p = self._pending
-                # paged pendings complete per-entry inside the advance (no
-                # completion-clock rendezvous); only the contiguous pending
-                # waits here for the shared clock to reach its target
-                if isinstance(p, PendingPrefill) and p.done >= p.target \
-                        and (self.kv.clock == p.target or not active_ids):
-                    self._scatter_pending(p)
+        # ---- admission into free slots (paused while draining or
+        # while a chunked admission is already in flight) ----
+        admit_ms = 0.0
+        if self._pending is None and queue and not draining:
+            free_ids = [i for i, s in enumerate(self.slots) if s is None]
+            if free_ids:
+                with _span("serve.admit", free=len(free_ids)) as sp:
+                    chosen, admit_ms = self._admit_free(queue, free_ids, ver,
+                                                        params)
+                    sp.set_metadata(requests=len(chosen), request_ids=" ".join(
+                        str(r.request_id) for _, r in chosen))
 
-            active_ids = [i for i, s in enumerate(self.slots)
-                          if s is not None]
-            if not active_ids:
-                # reachable while draining paused admission with an empty
-                # pool (the swap branch fires next iteration) or while a
-                # chunked admission is still consuming its prompt on an
-                # empty pool (the clock is frozen; chunks run back-to-back)
-                continue
+        # ---- chunked admission: consume this step's prefill budget;
+        # scatter into the pool when it completes at its clock ----
+        chunk_ms = 0.0
+        if self._pending is not None:
+            chunk_ms = self._advance_pending(params)
+            p = self._pending
+            # paged pendings complete per-entry inside the advance (no
+            # completion-clock rendezvous); only the contiguous pending
+            # waits here for the shared clock to reach its target
+            if isinstance(p, PendingPrefill) and p.done >= p.target \
+                    and (self.kv.clock == p.target or not active_ids):
+                self._scatter_pending(p)
 
-            # ---- one lockstep step: sample, retire, decode ----
+        active_ids = [i for i, s in enumerate(self.slots) if s is not None]
+        if not active_ids:
+            # reachable while draining paused admission with an empty
+            # pool (the swap branch fires next iteration) or while a
+            # chunked admission is still consuming its prompt on an
+            # empty pool (the clock is frozen; chunks run back-to-back)
+            return
+
+        # ---- one lockstep step: sample, retire, decode ----
+        with _span("serve.sample"):
             self.eng._key, sk = jax.random.split(self.eng._key)
             nxt = sample(self.kv.logits, sk, cfg.temperature, cfg.top_k)
+        with _span("serve.pull"):
             nxt_np = np.asarray(nxt)
-            if self.token_override is not None:
-                nxt_np = nxt_np.copy()
-                for i in active_ids:
-                    s = self.slots[i]
-                    ov = self.token_override(s.req.request_id,
-                                             len(s.tokens),
-                                             int(nxt_np[i]))
-                    if ov is not None:
-                        nxt_np[i] = ov
-                nxt = jnp.asarray(nxt_np)
-            recorded = 0
-            t_now = time.perf_counter()
-            step_ms = (t_now - self._last_emit_t) * 1e3
-            self._last_emit_t = t_now
-            self.step_ms_log.append(step_ms)
+        if self.token_override is not None:
+            nxt_np = nxt_np.copy()
+            for i in active_ids:
+                s = self.slots[i]
+                ov = self.token_override(s.req.request_id,
+                                         len(s.tokens),
+                                         int(nxt_np[i]))
+                if ov is not None:
+                    nxt_np[i] = ov
+            nxt = jnp.asarray(nxt_np)
+        recorded = 0
+        t_now = time.perf_counter()
+        step_ms = (t_now - self._last_emit_t) * 1e3
+        self._last_emit_t = t_now
+        self.step_ms_log.append(step_ms)
+        with _span("serve.retire") as sp:
+            done = []
             for i in active_ids:
                 s = self.slots[i]
                 tok = int(nxt_np[i])
@@ -531,26 +494,98 @@ class ContinuousScheduler(_SchedulerBase):
                 eid = _req_eos(s.req, cfg)
                 if (len(s.tokens) >= s.req.max_new_tokens
                         or (eid >= 0 and tok == eid)):
+                    done.append(str(s.req.request_id))
                     self._finish(results, i, t_now)
-            self.steps_total += 1
-            self.occupancy_sum += recorded
-            self.max_occupancy = max(self.max_occupancy, recorded)
+            if done:
+                sp.set_metadata(request_ids=" ".join(done))
+        self.steps_total += 1
+        self.occupancy_sum += recorded
+        self.max_occupancy = max(self.max_occupancy, recorded)
+        with _span("serve.on_step"):
             self._emit_step({"step": self.steps_total,
                              "clock": self.kv.clock,
                              "recorded": recorded, "version": ver.version,
                              "draining": draining, "t": t_now,
                              "step_ms": step_ms, "chunk_ms": chunk_ms,
-                             "admit_ms": admit_ms})
-            alive = [i for i, s in enumerate(self.slots) if s is not None]
-            if alive:
-                if self.spec is not None:
-                    # speculative cycle: the carry token's K/V row is
-                    # written by the verify forward together with the
-                    # draft run (there is no separate decode step)
-                    self._spec_cycle(results, params, nxt, alive)
-                else:
-                    self.kv.decode(params, nxt, alive)
-        return results  # type: ignore[return-value]
+                             "admit_ms": admit_ms,
+                             **self.kv.decode_positions()})
+        alive = [i for i, s in enumerate(self.slots) if s is not None]
+        if alive:
+            if self.spec is not None:
+                # speculative cycle: the carry token's K/V row is
+                # written by the verify forward together with the
+                # draft run (there is no separate decode step)
+                self._spec_cycle(results, params, nxt, alive)
+            else:
+                self.kv.decode(params, nxt, alive)
+
+    def _swap_point(self, queue, active_ids, staged) -> None:
+        """A staged weight version waits: start (or continue) draining, and
+        swap once nothing is in flight or the deadline has passed."""
+        cfg = self.cfg
+        if self._drain_t0 is None:
+            self._drain_t0 = time.perf_counter()
+            self.drains += 1
+            in_flight = len(active_ids) + (
+                self._pending.remaining_requests if self._pending else 0)
+            self.store.note_drain(in_flight)
+        elapsed_ms = (time.perf_counter() - self._drain_t0) * 1e3
+        deadline = cfg.swap_deadline_ms
+        # the deadline clock starts when the version finished staging
+        # (store-side), not when this loop first saw it — a version staged
+        # between generate() calls swaps at once. A chunked admission in
+        # flight is drained like any other in-flight work; a forced swap
+        # abandons it instead (its chunks ran on the old weights) and
+        # re-queues its requests
+        busy = bool(active_ids) or self._pending is not None
+        if not busy or (deadline is not None and staged.age_ms >= deadline):
+            if self._pending is not None:
+                self._abandon_pending(queue)
+            forced = busy
+            ver, sms = self.store.acquire()
+            self._ver = ver
+            self._sync_kv_version(ver.version)
+            self.store.note_swap(forced=forced, drain_ms=elapsed_ms)
+            self._pending_swap_ms += sms
+            if forced:
+                self.forced_swaps += 1
+                for i in active_ids:
+                    self.slots[i].forced_swaps += 1
+                    self.slots[i].swap_ms += sms
+            self._drain_t0 = None
+
+    def _admit_free(self, queue, free_ids, ver, params):
+        """Admit queued requests into ``free_ids`` (monolithically, or as a
+        chunked pending); returns the ``(order, request)`` pairs chosen and
+        the monolithic admission's wall time."""
+        cfg = self.cfg
+        admit_ms = 0.0
+        fresh = len(free_ids) == self.max_slots
+        head = queue[0]
+        limit_head = (not fresh
+                      and self._head_skips >= cfg.starvation_limit)
+        if self.chunk:
+            chosen = self._start_pending(
+                queue, free_ids, fresh, ver.version, limit_head)
+        else:
+            chosen, new_clock = self.kv.pick(
+                queue, len(free_ids), fresh, limit_head)
+            if chosen:
+                if fresh:
+                    self.waves += 1
+                t0 = time.perf_counter()
+                self._admit(chosen, free_ids, new_clock, params,
+                            ver.version)
+                admit_ms = (time.perf_counter() - t0) * 1e3
+        # FCFS-with-skip starvation guard: count picks that jumped the
+        # queue head; past the limit, mid-flight admission narrows to the
+        # head only, so the pool drains into a fresh wave that must admit
+        # it
+        if fresh or (chosen and head in chosen):
+            self._head_skips = 0
+        elif chosen:
+            self._head_skips += 1
+        return chosen, admit_ms
 
     def _finish(self, results, slot: int, t_now: float) -> None:
         """Retire slot ``slot`` and record its Completion."""
@@ -708,14 +743,18 @@ class ContinuousScheduler(_SchedulerBase):
         if p.cache is None:
             p.cache = self.kv.side_cache(len(p.slot_ids))
         t0 = time.perf_counter()
-        toks = jnp.asarray(p.tokens[:, p.done:p.done + n])
-        # synchronous on purpose: letting chunks queue up async behind the
-        # in-flight decode reads as overlap on idle machines but builds an
-        # unbounded compute backlog on saturated ones, which the scatter
-        # step then pays in one spike — the exact tail this feature bounds
-        p.logits, p.cache = self.eng._prefill_chunk(
-            params, {"tokens": toks}, p.cache)
-        jax.block_until_ready(p.logits)
+        with _span("serve.chunk", request_ids=" ".join(
+                str(r.request_id) for _, r in p.chosen), tokens=n):
+            toks = jnp.asarray(p.tokens[:, p.done:p.done + n])
+            # synchronous on purpose: letting chunks queue up async behind
+            # the in-flight decode reads as overlap on idle machines but
+            # builds an unbounded compute backlog on saturated ones, which
+            # the scatter step then pays in one spike — the exact tail this
+            # feature bounds
+            p.logits, p.cache = self.eng._prefill_chunk(
+                params, {"tokens": toks}, p.cache)
+            with _span("serve.chunk_wait"):
+                jax.block_until_ready(p.logits)
         ms = (time.perf_counter() - t0) * 1e3
         p.prefill_ms += ms
         p.chunks += 1
@@ -734,56 +773,59 @@ class ContinuousScheduler(_SchedulerBase):
         chunking. Returns the step's chunk wall time."""
         p = self._pending
         _, r = p.chosen[p.entry]
-        slot = p.slot_ids[p.entry]
-        t0 = time.perf_counter()
-        if p.cache is None:
-            p.lp, p.cache = self.kv.begin_chunked_admit(slot, r)
-            p.suffix = np.asarray(
-                [int(t) for t in r.prompt[p.lp:]], np.int32)
-            p.done = 0
-            p.chunks = 0
-            p.entry_ms = 0.0
-        n = min(self.chunk, len(p.suffix) - p.done)
-        toks = jnp.asarray(p.suffix[None, p.done:p.done + n])
-        # synchronous for the same tail-bounding reason as the contiguous
-        # path: chunks must not queue up behind the in-flight decode
-        p.logits, p.cache = self.eng._prefill_chunk(
-            params, {"tokens": toks}, p.cache)
-        jax.block_until_ready(p.logits)
-        p.done += n
-        p.chunks += 1
-        self.chunk_steps += 1
-        if p.done >= len(p.suffix):
-            self.kv.complete_chunked_admit(slot, r, p.lp, p.cache,
-                                           p.logits)
+        with _span("serve.chunk", request_id=r.request_id) as sp:
+            slot = p.slot_ids[p.entry]
+            t0 = time.perf_counter()
+            if p.cache is None:
+                p.lp, p.cache = self.kv.begin_chunked_admit(slot, r)
+                p.suffix = np.asarray(
+                    [int(t) for t in r.prompt[p.lp:]], np.int32)
+                p.done = 0
+                p.chunks = 0
+                p.entry_ms = 0.0
+            n = min(self.chunk, len(p.suffix) - p.done)
+            sp.set_metadata(tokens=n)
+            toks = jnp.asarray(p.suffix[None, p.done:p.done + n])
+            # synchronous for the same tail-bounding reason as the contiguous
+            # path: chunks must not queue up behind the in-flight decode
+            p.logits, p.cache = self.eng._prefill_chunk(
+                params, {"tokens": toks}, p.cache)
+            with _span("serve.chunk_wait"):
+                jax.block_until_ready(p.logits)
+            p.done += n
+            p.chunks += 1
+            self.chunk_steps += 1
+            if p.done >= len(p.suffix):
+                self.kv.complete_chunked_admit(slot, r, p.lp, p.cache,
+                                               p.logits)
+                ms = (time.perf_counter() - t0) * 1e3
+                p.entry_ms += ms
+                order = p.chosen[p.entry][0]
+                self.slots[slot] = _Slot(
+                    order=order, req=r, version=p.version,
+                    clock0=len(r.prompt), t0=time.perf_counter(),
+                    prefill_ms=p.entry_ms, swap_ms=self._pending_swap_ms)
+                if self.spec is not None:
+                    # the drafter needs its own prompt K/V before the slot's
+                    # first speculative cycle (one unpadded batch-1 prefill on
+                    # the draft tree — speculative composes with chunked
+                    # admission; only the verifier side is chunked)
+                    self.spec.admit_slot(slot, r.prompt,
+                                         self._ver.draft_params)
+                self.admission_log.append(
+                    {"request_id": r.request_id, "slot": slot,
+                     "clock": len(r.prompt), "version": p.version,
+                     "chunks": p.chunks})
+                self.admitted += 1
+                p.entry += 1
+                p.cache = None
+                if p.entry >= len(p.chosen):
+                    self._pending_swap_ms = 0.0
+                    self._pending = None
+                return ms
             ms = (time.perf_counter() - t0) * 1e3
             p.entry_ms += ms
-            order = p.chosen[p.entry][0]
-            self.slots[slot] = _Slot(
-                order=order, req=r, version=p.version,
-                clock0=len(r.prompt), t0=time.perf_counter(),
-                prefill_ms=p.entry_ms, swap_ms=self._pending_swap_ms)
-            if self.spec is not None:
-                # the drafter needs its own prompt K/V before the slot's
-                # first speculative cycle (one unpadded batch-1 prefill on
-                # the draft tree — speculative composes with chunked
-                # admission; only the verifier side is chunked)
-                self.spec.admit_slot(slot, r.prompt,
-                                     self._ver.draft_params)
-            self.admission_log.append(
-                {"request_id": r.request_id, "slot": slot,
-                 "clock": len(r.prompt), "version": p.version,
-                 "chunks": p.chunks})
-            self.admitted += 1
-            p.entry += 1
-            p.cache = None
-            if p.entry >= len(p.chosen):
-                self._pending_swap_ms = 0.0
-                self._pending = None
             return ms
-        ms = (time.perf_counter() - t0) * 1e3
-        p.entry_ms += ms
-        return ms
 
     def _scatter_pending(self, p: PendingPrefill) -> None:
         """A completed pending joins the pool: scatter its side-cache rows

@@ -169,8 +169,9 @@ def test_bucket_chunking_bit_exact(rng, monkeypatch):
 
 def test_report_breakdown(rng):
     _, rep = quantize_tree(_tree(rng), method="squant", bits=4, group_size=16)
-    assert rep.dispatch_millis > 0 and rep.sync_millis >= 0
-    assert rep.total_millis >= rep.dispatch_millis
+    assert rep.total_millis > 0
+    assert [b.num_layers for b in rep.buckets] and \
+        all(b.num_layers > 0 and b.key and b.impl for b in rep.buckets)
     assert sum(b.num_layers for b in rep.buckets) == len(rep.layers)
     assert "buckets" in rep.summary()
     for lr in rep.layers:

@@ -1,0 +1,203 @@
+"""Host spans, program names and KV counters of the serving loop and of
+``quantize_tree``, read back from a CPU profiler session.
+
+The spans are ``jax.profiler.TraceAnnotation`` host events: the profiler
+writes them into the same ``.xplane.pb`` as the device ops, so a trace of
+the chip ties each idle gap to what the host was doing. Their metadata
+arrives as event stats. The engine's jitted programs carry the names
+``serve_<name>`` (``PjitFunction(serve_decode)`` on the host,
+``jit_serve_decode`` on the device)."""
+import dataclasses
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+from repro.configs import get_config
+from repro.core.pipeline import quantize_tree
+from repro.models.model import build_model
+from repro.serving.engine import Request, ServeConfig, ServeEngine
+
+PREFIXES = ("serve.", "kv.", "quantize.")
+
+
+def _tiny(seed=0):
+    cfg = get_config("granite-3-8b", reduced=True)
+    cfg = dataclasses.replace(cfg, dtype="float32", n_layers=2, d_model=32,
+                              n_heads=2, n_kv_heads=1, head_dim=16, d_ff=64,
+                              vocab=256)
+    model = build_model(cfg)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
+def _paged(model, params, **over):
+    base = dict(max_len=48, scheduler="continuous", max_slots=2,
+                kv_backend="paged", block_size=4, prefill_chunk=4,
+                swap_deadline_ms=None)
+    base.update(over)
+    return ServeEngine(model, params, ServeConfig(**base))
+
+
+def _requests():
+    rng = np.random.default_rng(0)
+    return [Request(prompt=rng.integers(0, 256, n).tolist(),
+                    max_new_tokens=m, request_id=10 + i)
+            for i, (n, m) in enumerate([(9, 5), (14, 3), (6, 6), (11, 4)])]
+
+
+def _profiled(tmp_path, fn):
+    """``fn()`` under a profiler session; returns its result and the host
+    events as ``(name, start_ns, end_ns, thread, stats)``."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for ln in plane.lines:
+                events.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                               ln.name, dict(e.stats)) for e in ln.events)
+    return out, events
+
+
+def _spans(events):
+    """Program spans as ``(name, parent name, stats)``: a span's parent is
+    the innermost span that encloses it on its thread."""
+    spans = sorted((e for e in events if e[0].startswith(PREFIXES)),
+                   key=lambda e: (e[3], e[1], -e[2]))
+    out, stack = [], []
+    for s in spans:
+        while stack and (stack[-1][3] != s[3] or stack[-1][2] < s[2]):
+            stack.pop()
+        out.append((s[0], stack[-1][0] if stack else None, s[4]))
+        stack.append(s)
+    return out
+
+
+def test_serving_spans_nest_and_name_programs(tmp_path):
+    model, params = _tiny(0)
+    _, params2 = _tiny(1)
+    eng = _paged(model, params)
+    eng.generate(_requests())                  # compile outside the trace
+    staged = []
+
+    def stage_once(info):
+        if info["step"] >= 3 and not staged:
+            staged.append(info["step"])
+            eng.store.stage(fp_params=params2, source="midrun", block=True)
+    eng.on_step = stage_once
+    reqs = _requests()
+    outs, events = _profiled(tmp_path, lambda: eng.generate(reqs))
+    assert [len(o.tokens) for o in outs] == [r.max_new_tokens for r in reqs]
+    spans = _spans(events)
+    parent = {}
+    for name, par, _ in spans:
+        parent.setdefault(name, set()).add(par)
+    assert parent == {
+        "serve.step": {None}, "serve.swap": {"serve.step"},
+        "serve.admit": {"serve.step"}, "serve.chunk": {"serve.step"},
+        "serve.chunk_wait": {"serve.chunk"}, "kv.gather": {"serve.chunk"},
+        "kv.scatter": {"serve.chunk"}, "serve.sample": {"serve.step"},
+        "serve.pull": {"serve.step"}, "serve.retire": {"serve.step"},
+        "serve.on_step": {"serve.step"}, "kv.decode": {"serve.step"}}
+    ids = {r.request_id for r in reqs}
+    chunks = [st for n, _, st in spans if n == "serve.chunk"]
+    assert {st["request_id"] for st in chunks} == ids
+    assert all(0 < st["tokens"] <= 4 for st in chunks)
+    # one request's spans share its id: chunk, gather and scatter
+    for n in ("kv.gather", "kv.scatter"):
+        assert {st["request_id"] for m, _, st in spans if m == n} == ids
+    admitted = [st for n, _, st in spans if n == "serve.admit"
+                and st.get("requests")]
+    assert {int(i) for st in admitted for i in str(st["request_ids"]).split()
+            } == ids
+    retired = " ".join(str(st["request_ids"]) for n, _, st in spans
+                       if n == "serve.retire" and "request_ids" in st)
+    assert sorted(int(i) for i in retired.split()) == sorted(ids)
+    steps = [st["step"] for n, _, st in spans if n == "serve.step"]
+    assert steps == sorted(steps)
+    names = {e[0] for e in events}
+    assert "PjitFunction(serve_decode)" in names
+    assert "PjitFunction(serve_prefill_chunk)" in names
+    assert not any("counted" in n for n in names)
+
+
+def test_quantize_tree_spans(tmp_path):
+    _, params = _tiny(0)
+    (_, rep), events = _profiled(tmp_path, lambda: quantize_tree(
+        params, method="squant", bits=4, group_size=16))
+    spans = _spans(events)
+    parent = {}
+    for name, par, _ in spans:
+        parent.setdefault(name, set()).add(par)
+    assert parent == {
+        "quantize.tree": {None}, "quantize.plan": {"quantize.tree"},
+        "quantize.chunk": {"quantize.tree"},
+        "quantize.stack": {"quantize.chunk"},
+        "quantize.dispatch": {"quantize.chunk"},
+        "quantize.wrap": {"quantize.chunk"},
+        "quantize.sync": {"quantize.tree"}}
+    tree, = [st for n, _, st in spans if n == "quantize.tree"]
+    assert tree == {"leaves": len(rep.layers), "buckets": len(rep.buckets)}
+    chunks = [st for n, _, st in spans if n == "quantize.chunk"]
+    assert [(c["bucket"], c["layers"]) for c in chunks] == \
+        [(b.key, b.num_layers) for b in rep.buckets]
+
+
+@pytest.mark.parametrize("chunk", [0, 4])
+def test_decode_counts_positions_attended_and_walked(chunk):
+    """Each step's info carries what the decode launch it sampled walked
+    (the block tables handed to the program, times the block size) and
+    attended (each active slot's context, new token included); the KV
+    stats hold their running sums."""
+    model, params = _tiny(0)
+    eng = _paged(model, params, prefill_chunk=chunk)
+    kv = eng.scheduler.kv
+    launches = []
+    decode, program = kv.decode, eng._decode
+
+    def record_decode(params_, nxt, active_ids):
+        launches.append({"attended": int(sum(kv._lengths[i] + 1
+                                             for i in active_ids))})
+        decode(params_, nxt, active_ids)
+
+    def record_program(params_, tok, cache):
+        launches[-1]["walked"] = (int(np.prod(cache["block_tables"].shape))
+                                  * kv.block_size)
+        return program(params_, tok, cache)
+
+    kv.decode, eng._decode = record_decode, record_program
+    infos = []
+    eng.on_step = infos.append
+    eng.generate(_requests())
+    assert launches and all(l["walked"] == 2 * (48 // 4) * 4
+                            for l in launches)
+    got = [(i["positions_attended"], i["positions_walked"]) for i in infos]
+    # a step samples the logits of the decode launched after the previous
+    # step (every launch is sampled by a later step); the first step of a
+    # wave samples admission logits and reports no launch
+    assert [g for g in got if g != (0, 0)] == \
+        [(l["attended"], l["walked"]) for l in launches]
+    assert (0, 0) in got
+    st = eng.scheduler.stats().kv
+    assert st["positions_attended"] == sum(l["attended"] for l in launches)
+    assert st["positions_walked"] == sum(l["walked"] for l in launches)
+    assert 0 < st["positions_attended"] < st["positions_walked"]
+
+
+def test_contiguous_steps_carry_no_decode_counts():
+    model, params = _tiny(0)
+    eng = ServeEngine(model, params, ServeConfig(
+        max_batch=2, max_len=32, scheduler="continuous"))
+    infos = []
+    eng.on_step = infos.append
+    eng.generate(_requests()[:2])
+    assert infos and not any("positions_walked" in i for i in infos)
