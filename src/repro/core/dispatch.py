@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed import compat
 from repro.kernels import ops
+from repro.kernels.squant_flip import tile_rows
 from repro.quant.qtypes import qmax_for_bits
 from repro.quant.scales import compute_scale
 
@@ -60,6 +61,15 @@ def bucket_impl(method: str, backend: str, group_size: Optional[int]) -> str:
         return "ref"
     enable_k, enable_c = _METHOD_FLAGS[method]
     return ops.squant_impl(backend, group_size, enable_k, enable_c)
+
+
+def bucket_tile_rows(impl: str, rows: int, ndev: int = 1) -> int:
+    """Tile height of the ``squant_flip`` kernel for one dispatch of
+    ``rows`` stacked rows (each device's slab where ``ndev`` devices share
+    them); 0 where the bucket runs no kernel (``impl`` is ``"ref"``)."""
+    if impl == "ref":
+        return 0
+    return tile_rows(sum(shard_rows(rows, ndev)[0]))
 
 
 @functools.lru_cache(maxsize=None)

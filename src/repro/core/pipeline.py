@@ -48,7 +48,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.dispatch import (bucket_impl, quantize_codes_batched,
+from repro.core.dispatch import (bucket_impl, bucket_tile_rows,
+                                 quantize_codes_batched,
                                  quantize_codes_sharded, resolve_backend,
                                  shard_rows)
 from repro.quant.qtypes import (BucketReport, LayerReport, QuantReport,
@@ -63,7 +64,8 @@ _sync = jax.block_until_ready
 
 # host spans on the profiler's clock (free while no trace is being taken):
 # quantize.tree > quantize.plan, quantize.chunk > (quantize.stack,
-# quantize.dispatch, quantize.wrap), quantize.sync
+# quantize.dispatch, quantize.wrap), quantize.sync; a chunk's metadata
+# names its bucket, its layers and the kernel's tile height (0: no kernel)
 _span = jax.profiler.TraceAnnotation
 
 
@@ -259,7 +261,9 @@ def _quantize_tree_batched(flat, treedef, pred, method, bits, group_size,
             entries = all_entries[c0:c0 + chunk]
             tag = f"({m},{n})x{len(entries)} {dtype} g{eff}"
             tb0 = time.perf_counter()
-            with _span("quantize.chunk", bucket=tag, layers=len(entries)):
+            tile = bucket_tile_rows(impl, len(entries) * m, ndev)
+            with _span("quantize.chunk", bucket=tag, layers=len(entries),
+                       tile_rows=tile):
                 with _span("quantize.stack"):
                     ws = _stack_2d(tuple(e[2] for e in entries))  # (B,M,N)
                 with _span("quantize.dispatch"):

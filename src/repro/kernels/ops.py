@@ -54,11 +54,14 @@ def squant_impl(use_pallas: str, group_size: Optional[int],
 
 def squant_flip(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
                 group_size: int, enable_k: bool = True, enable_c: bool = True,
-                use_pallas: str = "auto", tm: int = 8) -> jnp.ndarray:
+                use_pallas: str = "auto", tm: Optional[int] = None
+                ) -> jnp.ndarray:
     """SQuant codes for an (M, N) matrix with per-channel scales (M, 1).
 
     The Pallas path implements the standard E, E&K and E&K&C configurations;
-    the E&C-without-K ablation (row-level flip) is reference-only.
+    the E&C-without-K ablation (row-level flip) is reference-only. ``tm``
+    overrides the kernel's tile height, which is otherwise derived from M
+    (``squant_flip.tile_rows``).
     """
     impl = squant_impl(use_pallas, group_size, enable_k, enable_c)
     if impl == "ref":
@@ -72,7 +75,7 @@ def squant_flip(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
 def squant_flip_batched(w3: jnp.ndarray, scale3: jnp.ndarray, *, bits: int,
                         group_size: Optional[int], enable_k: bool = True,
                         enable_c: bool = True, use_pallas: str = "auto",
-                        tm: int = 8) -> jnp.ndarray:
+                        tm: Optional[int] = None) -> jnp.ndarray:
     """SQuant codes for a (B, M, N) stack of same-shape matrices.
 
     This is the model-level batched entry point: ``quantize_tree`` stacks all
@@ -84,7 +87,8 @@ def squant_flip_batched(w3: jnp.ndarray, scale3: jnp.ndarray, *, bits: int,
     backends flatten the batch into rows and launch the Pallas kernel once
     over ``(B*M, N)``; that is exact, not an approximation. The reference
     backend vmaps the jnp core instead. Which one runs is
-    :func:`squant_impl`.
+    :func:`squant_impl`; the kernel's tile height comes from B*M unless
+    ``tm`` overrides it.
     """
     impl = squant_impl(use_pallas, group_size, enable_k, enable_c)
     if impl == "ref":

@@ -2,22 +2,26 @@
 
 TPU adaptation of the paper's CUDA kernel (one thread-block per output
 channel + warp top-k). There is no warp shuffle / data-dependent sort on the
-TPU vector unit, so selection is re-thought as *rank-via-comparison*:
-
-    rank_i = Σ_j [score_j > score_i] + Σ_{j<i} [score_j == score_i]
-    flip_i = rank_i < k
-
-a dense (G×G) fixed-shape comparison that lives entirely in VMEM and maps
-onto the 8×128 VPU lanes. Two passes:
+TPU vector unit, so the k flips of a row are chosen by *threshold
+selection* (:func:`_select_top_k`): eligible scores are positive f32, whose
+int32 bit patterns order like their values, so a 31-round bisection on the
+bit pattern finds each row's k-th largest score ``t``; every lane above
+``t`` flips, and so do the first ``k - #(> t)`` lanes equal to ``t`` (lower
+index wins ties, as the stable argsort of the jnp reference). The per-row
+counts and the tie prefix count are 0/1 masks times an all-ones or a
+strictly triangular matrix on the MXU (bf16 in, f32 out: exact). Two passes:
 
 * ``squant_ek_kernel`` — grid (M/TM, N/G), block (TM, G): fused
   round (SQuant-E) + group flip (SQuant-K) + the Algorithm-4 candidate
   (index+value) for the C stage, written as lane ``j`` of (TM, NG)
   summary blocks that stay resident across the group axis.
-* ``squant_c_kernel``  — grid (M/TM_C,), block (TM_C, NG): ranks groups by
+* ``squant_c_kernel``  — grid (M/TM,), block (TM, NG): selects groups by
   |candidate| and emits the per-group flip decision (SQuant-C). The ±1
   application is a cheap one-hot select done by the wrapper (no scatter —
   TPU-friendly).
+
+TM is :func:`tile_rows` of the row count: tall tiles (up to 512 rows) for
+real matrices, 8 for a handful of rows.
 
 Both are validated in interpret mode against ``kernels/ref.py`` (which
 delegates to the vectorized core, itself bit-exact against the sequential
@@ -27,25 +31,57 @@ reference by ``chip_smoke.py``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ranks_desc_2d(score: jnp.ndarray) -> jnp.ndarray:
-    """Stable descending rank along the last axis via pairwise comparison.
+def tile_rows(rows: int) -> int:
+    """Tile height for ``rows`` rows: the tallest of 512, 256, ..., 8 that
+    the row count (padded to a multiple of 8) reaches. Rows are then padded
+    to a multiple of it; zero rows are inert."""
+    padded = -(-max(rows, 1) // 8) * 8
+    return min(512, 1 << (padded.bit_length() - 1))
 
-    score: (R, L) → int32 (R, L). Lower index wins ties (matches the stable
-    argsort of the jnp reference).
+
+def _row_count(mask: jnp.ndarray, mat: jnp.ndarray) -> jnp.ndarray:
+    """``mask @ mat`` for a 0/1 mask (R, L) and a 0/1 matrix (L, L) on the
+    MXU: bf16 operands, f32 accumulation, exact for counts up to L."""
+    return jnp.dot(mask.astype(jnp.bfloat16), mat,
+                   preferred_element_type=jnp.float32)
+
+
+def _select_top_k(score: jnp.ndarray, eligible: jnp.ndarray,
+                  k: jnp.ndarray) -> jnp.ndarray:
+    """Mask of the ``k`` largest eligible scores of each row, lower index
+    first among ties: exactly ``stable_rank_desc(score) < k`` restricted to
+    eligible lanes.
+
+    score: (R, L) f32, positive where ``eligible``; k: (R, 1) int32, at most
+    the row's eligible count. Returns a bool (R, L) mask.
     """
     r, l = score.shape
-    s_i = score[:, :, None]                      # (R, L, 1) "self"
-    s_j = score[:, None, :]                      # (R, 1, L) "other"
-    ii = jax.lax.broadcasted_iota(jnp.int32, (r, l, l), 1)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (r, l, l), 2)
-    beats = (s_j > s_i) | ((s_j == s_i) & (jj < ii))
-    return jnp.sum(beats.astype(jnp.int32), axis=2)
+    # positive f32 order like their int32 bit patterns; ineligible → -1
+    bits = jnp.where(eligible, jax.lax.bitcast_convert_type(score, jnp.int32),
+                     -1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    ones = jnp.ones((l, l), jnp.bfloat16)
+    before = (row < col).astype(jnp.bfloat16)     # strictly upper triangular
+    kf = k.astype(jnp.float32)
+    # t: the largest bit pattern with at least k eligible scores at or above
+    # it, i.e. the row's k-th largest (every lane of a row holds the same t)
+    t = jnp.zeros((r, l), jnp.int32)
+    for b in range(30, -1, -1):
+        cand = t | (1 << b)
+        t = jnp.where(_row_count(bits >= cand, ones) >= kf, cand, t)
+    above = bits > t
+    tie = bits == t
+    need = kf - _row_count(above, ones)           # ties to take, ≥ 1 if k > 0
+    take_tie = tie & (_row_count(tie, before) < need)
+    return (above | take_tie) & (k > 0)
 
 
 def _flip_body(q, delta, qmax):
@@ -57,8 +93,7 @@ def _flip_body(q, delta, qmax):
     eligible = (delta * e > 0) & in_range
     k = jnp.minimum(k, jnp.sum(eligible.astype(jnp.int32), axis=1,
                                keepdims=True))
-    score = jnp.where(eligible, jnp.abs(delta), -1.0)
-    flip = (_ranks_desc_2d(score) < k) & eligible
+    flip = _select_top_k(jnp.abs(delta), eligible, k)
     sgn = jnp.sign(delta)
     q = q - jnp.where(flip, sgn, 0.0)
     delta = delta - jnp.where(flip, sgn, 0.0)
@@ -110,7 +145,7 @@ def squant_ek_kernel(ws_ref, q_ref, e1_ref, cidx_ref, cval_ref, *,
 
 
 def squant_c_kernel(e1_ref, cval_ref, gflip_ref):
-    """SQuant-C decision on one (TM_C, NG) block of group summaries."""
+    """SQuant-C decision on one (TM, NG) block of group summaries."""
     e1 = e1_ref[...]
     cval = cval_ref[...]
     e_row = jnp.sum(e1, axis=1, keepdims=True)
@@ -118,8 +153,7 @@ def squant_c_kernel(e1_ref, cval_ref, gflip_ref):
     elig = (cval * e_row > 0.0)                          # cval==0 → ineligible
     k_c = jnp.minimum(k_c, jnp.sum(elig.astype(jnp.int32), axis=1,
                                    keepdims=True))
-    score = jnp.where(elig, jnp.abs(cval), -1.0)
-    gflip = (_ranks_desc_2d(score) < k_c) & elig
+    gflip = _select_top_k(jnp.abs(cval), elig, k_c)
     gflip_ref[...] = gflip.astype(jnp.int32)
 
 
@@ -136,19 +170,21 @@ def _pad_to(x, mult, axis, value=0.0):
     "bits", "group_size", "enable_k", "enable_c", "tm", "interpret"))
 def squant_pallas(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
                   group_size: int, enable_k: bool = True,
-                  enable_c: bool = True, tm: int = 8,
+                  enable_c: bool = True, tm: Optional[int] = None,
                   interpret: bool = False):
     """Full SQuant E(&K)(&C) via the two Pallas passes. Returns int8 codes.
 
     w2d: (M, N) float; scale: (M, 1). N is padded to a multiple of
-    ``group_size``, M to a multiple of ``tm`` (zero rows/cols are inert:
-    δ=0 elements are never flip-eligible and contribute nothing to sums).
-    On a TPU ``group_size`` is a multiple of 128 (or the padded row) and
-    ``tm`` a multiple of 8: the block tiling rule.
+    ``group_size``, M to a multiple of the tile height ``tm`` (zero
+    rows/cols are inert: δ=0 elements are never flip-eligible and
+    contribute nothing to sums). ``tm`` defaults to :func:`tile_rows` of M;
+    both passes use it. On a TPU ``group_size`` is a multiple of 128 (or
+    the padded row) and ``tm`` a multiple of 8: the block tiling rule.
     """
     qmax = float(2 ** (bits - 1) - 1)
     m0, n0 = w2d.shape
     g = group_size
+    tm = tile_rows(m0) if tm is None else tm
     # scale exactly as the jnp reference does (one XLA divide), so the
     # E-stage inputs of both implementations are the same bits
     ws = w2d.astype(jnp.float32) / scale.astype(jnp.float32).reshape(m0, 1)
@@ -174,20 +210,14 @@ def squant_pallas(w2d: jnp.ndarray, scale: jnp.ndarray, *, bits: int,
     )(ws)
 
     if enable_c:
-        # keep the (TM_C, NG, NG) comparison tensor under ~2 MiB of VMEM;
-        # tm_c must divide the (tm-padded) m or the floor-divided grid
-        # leaves the last m % tm_c rows of gflip unwritten
-        tm_c = max(1, min(tm, (1 << 19) // max(ng * ng, 1)))
-        while m % tm_c:
-            tm_c -= 1
         gflip = pl.pallas_call(
             squant_c_kernel,
-            grid=(m // tm_c,),
+            grid=(m // tm,),
             in_specs=[
-                pl.BlockSpec((tm_c, ng), lambda i: (i, 0)),
-                pl.BlockSpec((tm_c, ng), lambda i: (i, 0)),
+                pl.BlockSpec((tm, ng), lambda i: (i, 0)),
+                pl.BlockSpec((tm, ng), lambda i: (i, 0)),
             ],
-            out_specs=pl.BlockSpec((tm_c, ng), lambda i: (i, 0)),
+            out_specs=pl.BlockSpec((tm, ng), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((m, ng), jnp.int32),
             interpret=interpret,
         )(e1, cval)

@@ -1,10 +1,14 @@
 """Pallas squant_flip kernel vs pure-jnp oracle: shape/dtype/bits sweeps in
 interpret mode (kernel body executes on CPU)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
+from repro.core.squant import _ranks_desc
 from repro.kernels import ops, ref
+from repro.kernels.squant_flip import _select_top_k, tile_rows
 from repro.quant.scales import compute_scale
 
 
@@ -33,10 +37,11 @@ def test_pallas_matches_ref_shapes(rng, m, n, g, bits):
 
 
 def test_pallas_c_stage_tile_not_dividing_m(rng):
-    """Many groups shrink the C-stage tile via the VMEM cap; a tm_c that does
-    not divide the padded M used to leave the last rows' gflip unwritten
-    (regression: grid was floor-divided)."""
-    m, g, ng = 8, 4, 300          # cap: 2^19 // 300^2 = 5 → must shrink to 4
+    """Many groups: the C stage selects among 300 candidates a row. (Its
+    tile once shrank by a VMEM cap to a height that did not divide the
+    padded M and left the last rows' gflip unwritten; the cap is gone and
+    both passes share the tile height.)"""
+    m, g, ng = 8, 4, 300
     w = _case(rng, m, ng * g)
     scale = compute_scale(w, 4, "max")
     got = ops.squant_flip(w, scale, bits=4, group_size=g,
@@ -94,3 +99,100 @@ def test_pallas_clipping_scale(rng):
     want = ref.squant_ref(w, scale, bits=4, group_size=32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert np.asarray(got).max() <= 7 and np.asarray(got).min() >= -7
+
+
+def _select_interpret(score, eligible, k):
+    """``_select_top_k`` run as the body of an interpreted Pallas kernel."""
+    def kern(s_ref, e_ref, k_ref, o_ref):
+        o_ref[...] = _select_top_k(s_ref[...], e_ref[...] > 0,
+                                   k_ref[...]).astype(jnp.int32)
+    return pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct(score.shape, jnp.int32),
+        interpret=True)(jnp.asarray(score), jnp.asarray(eligible, jnp.int32),
+                        jnp.asarray(k, jnp.int32))
+
+
+def _select_oracle(score, eligible, k):
+    """The reference's choice: stable double-argsort ranks below k."""
+    ranks = np.asarray(_ranks_desc(jnp.where(jnp.asarray(eligible),
+                                             jnp.asarray(score), -1.0)))
+    return (ranks < k) & eligible
+
+
+@pytest.mark.parametrize("l", [16, 56, 128])
+@pytest.mark.parametrize("case", ["random", "three_values", "k_zero",
+                                  "k_all", "none_eligible"])
+def test_select_top_k_matches_stable_ranks(rng, l, case):
+    """The threshold selection flips exactly the lanes whose stable
+    descending rank is below k: ties go to the lower index."""
+    r = 16
+    if case == "three_values":                  # heavy ties
+        score = rng.choice(np.float32([0.125, 0.25, 0.375]), size=(r, l))
+    else:                                       # wide exponent range
+        score = np.exp(rng.uniform(-30.0, 3.0, size=(r, l)))
+    score = score.astype(np.float32)
+    eligible = rng.random((r, l)) < 0.6
+    if case == "none_eligible":
+        eligible[:] = False
+    n_elig = eligible.sum(axis=1, keepdims=True)
+    if case == "k_zero":
+        k = np.zeros((r, 1), np.int64)
+    elif case == "k_all":
+        k = n_elig
+    else:
+        k = rng.integers(0, n_elig + 1)
+    got = np.asarray(_select_interpret(score, eligible, k)) > 0
+    np.testing.assert_array_equal(got, _select_oracle(score, eligible, k))
+    np.testing.assert_array_equal(got.sum(axis=1, keepdims=True),
+                                  np.minimum(k, n_elig))
+
+
+@pytest.mark.parametrize("rows,tile", [
+    (1, 8), (5, 8), (8, 8), (9, 16), (300, 256), (511, 512), (512, 512),
+    (65536, 512), (7168 * 9, 512)])
+def test_tile_rows_rule(rows, tile):
+    assert tile_rows(rows) == tile
+
+
+def _tie_heavy(rng, m, n, bits):
+    """Weights on a grid of an eighth of the scale (a power of two, so
+    ``w / s`` is exact): many ``|δ|`` are equal and nonzero, and the top
+    of the grid clips."""
+    qmax = 2 ** (bits - 1) - 1
+    s = np.float32(2.0 ** -4)
+    w = rng.integers(-8 * qmax - 4, 8 * qmax + 5, size=(m, n)) / 8.0 * s
+    return jnp.asarray(w.astype(np.float32)), jnp.full((m, 1), s, jnp.float32)
+
+
+@pytest.mark.parametrize("m,n,g", [
+    (300, 256, 128),   # padded M: tile 256, two tiles
+    (64, 384, 128),    # exact tile
+    (24, 100, 32),     # N padding, tile 16
+])
+@pytest.mark.parametrize("weights", ["normal", "ties"])
+@pytest.mark.parametrize("bits", [4, 8])
+def test_pallas_matches_ref_derived_tile(rng, m, n, g, weights, bits):
+    """At the tile height derived from M (no override) the codes are the
+    reference's, bit for bit, also where many |δ| tie."""
+    if weights == "ties":
+        w, scale = _tie_heavy(rng, m, n, bits)
+    else:
+        w = _case(rng, m, n)
+        scale = compute_scale(w, bits, "max")
+    got = ops.squant_flip(w, scale, bits=bits, group_size=g,
+                          use_pallas="interpret")
+    want = ref.squant_ref(w, scale, bits=bits, group_size=g)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_pallas_batched_derived_tile_matches_ref(rng):
+    """The batched entry point flattens B*M = 300 rows and derives its tile
+    from them; every matrix equals its own reference."""
+    b, m, n, g = 3, 100, 256, 128
+    w, scale = _tie_heavy(rng, b * m, n, 4)
+    w3, s3 = w.reshape(b, m, n), scale.reshape(b, m, 1)
+    got = ops.squant_flip_batched(w3, s3, bits=4, group_size=g,
+                                  use_pallas="interpret")
+    for i in range(b):
+        want = ref.squant_ref(w3[i], s3[i], bits=4, group_size=g)
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))

@@ -61,6 +61,13 @@ def _compiles_with_kernel(fn, *args):
     (12800, 4096, 4, True),         # granite FFN rows
     (4096, 4096, 4, False),         # E&K only
     (4096, 128, 8, True),           # one group per row
+    # rwkv6-1.6b buckets at w4, out-major, stacked: the C stage selects
+    # among 56 groups, then 16; the head; a 1 GiB split of the
+    # (2048, 2048) bucket. All run 512-row tiles.
+    (24 * 2048, 7168, 4, True),
+    (24 * 7168, 2048, 4, True),
+    (65536, 2048, 4, True),
+    (128 * 2048, 2048, 4, True),
 ])
 def test_squant_flip_compiles(spec, m, n, bits, enable_c):
     fn = functools.partial(squant_pallas, bits=bits, group_size=128,

@@ -16,6 +16,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.pipeline import quantize_tree
+from repro.kernels.squant_flip import tile_rows
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServeConfig, ServeEngine
 
@@ -130,10 +131,11 @@ def test_serving_spans_nest_and_name_programs(tmp_path):
     assert not any("counted" in n for n in names)
 
 
-def test_quantize_tree_spans(tmp_path):
+@pytest.mark.parametrize("backend", ["auto", "interpret"])
+def test_quantize_tree_spans(tmp_path, backend):
     _, params = _tiny(0)
     (_, rep), events = _profiled(tmp_path, lambda: quantize_tree(
-        params, method="squant", bits=4, group_size=16))
+        params, method="squant", bits=4, group_size=16, backend=backend))
     spans = _spans(events)
     parent = {}
     for name, par, _ in spans:
@@ -150,6 +152,12 @@ def test_quantize_tree_spans(tmp_path):
     chunks = [st for n, _, st in spans if n == "quantize.chunk"]
     assert [(c["bucket"], c["layers"]) for c in chunks] == \
         [(b.key, b.num_layers) for b in rep.buckets]
+    # the kernel's tile height from each chunk's stacked rows; 0 off it
+    for c, b in zip(chunks, rep.buckets):
+        rows = b.num_layers * int(b.key[1:].split(",")[0])
+        want = tile_rows(rows) if b.impl != "ref" else 0
+        assert c["tile_rows"] == want
+    assert any(b.impl != "ref" for b in rep.buckets) == (backend != "auto")
 
 
 @pytest.mark.parametrize("chunk", [0, 4])
