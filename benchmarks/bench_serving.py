@@ -642,12 +642,13 @@ def bench_kv_bytes(smoke: bool = False, repeats: int = 3,
 # registry configs shrunk to 2 layers (agreement is a property of the
 # mixer math, not the width — tiny widths keep the ladder cheap enough
 # for the CI bench gate). The jamba row isolates the mamba mixer
-# (moe=None, one mamba + one attention block); the mixtral row measures
-# the composed sliding_window x moe stack.
+# (moe=None, one mamba + one attention block); the moe row is mixtral
+# without its window; the sliding_window+moe row measures the composed
+# stack.
 CHUNKED_ARCH_ROWS = (
     ("sliding_window", "granite-3-8b", dict(n_layers=2, window=8)),
     ("mla", "minicpm3-4b", dict(n_layers=2)),
-    ("moe", "moonshot-v1-16b-a3b", dict(n_layers=2)),
+    ("moe", "mixtral-8x7b", dict(n_layers=2, window=None)),
     ("mamba", "jamba-1.5-large-398b",
      dict(n_layers=2, block_pattern=("m", "a"), moe=None)),
     ("rwkv", "rwkv6-1.6b", dict(n_layers=2)),

@@ -14,10 +14,26 @@ from repro.models.attention import MLAConfig
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int             # routed experts the router scores
     top_k: int
     every: int = 1             # MoE replaces the FFN every N layers
     capacity_factor: float = 1.25
+    d_expert: Optional[int] = None     # routed expert width (None: d_ff)
+    n_shared: int = 0          # shared experts: one SwiGLU n_shared*d_expert wide
+    # "softmax": top-k of the softmax; "sigmoid": top-k of sigmoid scores
+    # plus a per-expert selection bias (DeepSeek-V3's noaux_tc, one group),
+    # weighted by the chosen scores without the bias
+    scoring: str = "softmax"
+    norm_topk: bool = True     # chosen weights normalised to sum to one
+    routed_scale: float = 1.0  # routed part times this (routed_scaling_factor)
+    # expert parallelism: this chip holds experts [first_held, first_held +
+    # n_held) of every MoE layer (None: all of them)
+    n_held: Optional[int] = None
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +65,7 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     block_pattern: Optional[Tuple[str, ...]] = None   # per-period, "a"/"m"
     mamba: Optional[MambaConfig] = None
+    first_k_dense: int = 0              # leading dense layers before the MoE
     rwkv: bool = False
     rwkv_head_dim: int = 64
     # encoder-decoder (audio frontend stubbed: encoder consumes embeddings)
@@ -86,11 +103,13 @@ class ArchConfig:
         if self.moe is not None:
             small_moe = dataclasses.replace(
                 self.moe, n_experts=min(4, self.moe.n_experts),
-                top_k=min(2, self.moe.top_k))
+                top_k=min(2, self.moe.top_k), n_held=None, first_held=0,
+                d_expert=32 if self.moe.d_expert else None)
         small_mla = None
         if self.mla is not None:
-            small_mla = MLAConfig(q_lora=16, kv_lora=8, nope_dim=8,
-                                  rope_dim=4, v_dim=8)
+            small_mla = MLAConfig(
+                q_lora=16 if self.mla.q_lora else None, kv_lora=8,
+                nope_dim=8, rope_dim=4, v_dim=8, absorb=self.mla.absorb)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

@@ -3,8 +3,9 @@
 Grouped-query attention uses the grouped einsum form (no materialized KV
 repeat). Sliding-window decode keeps a ring-buffer cache of window size
 (O(window) state — the sub-quadratic path mixtral uses for long contexts).
-MLA (MiniCPM3/DeepSeek-style) caches the *compressed* c_kv + shared RoPE key,
-reconstructing K/V per step.
+MLA (MiniCPM3/DeepSeek-style) caches one latent row per position (normed
+c_kv ‖ the shared RoPE key), re-expanding K/V or attending in the latent
+space (absorbed; the paged pool always does).
 
 KV caches optionally store int8 codes with per-(token, head) scales
 (``quantize_kv``): a serving-memory optimization SQuant's weight format pairs
@@ -26,7 +27,7 @@ NEG_INF = -2.0 ** 30
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int = 768
+    q_lora: Optional[int] = 768         # None: one direct q projection
     kv_lora: int = 256
     nope_dim: int = 64
     rope_dim: int = 32
@@ -49,16 +50,20 @@ def init_attention(key, cfg) -> Dict[str, Any]:
     if cfg.mla is not None:
         m = cfg.mla
         qk_dim = m.nope_dim + m.rope_dim
-        p = {
-            "q_down": _init_dense(keys[0], d, m.q_lora),
-            "q_norm": init_norm(m.q_lora),
-            "q_up": _init_dense(keys[1], m.q_lora, cfg.n_heads * qk_dim),
+        if m.q_lora is None:
+            p = {"wq": _init_dense(keys[0], d, cfg.n_heads * qk_dim)}
+        else:
+            p = {"q_down": _init_dense(keys[0], d, m.q_lora),
+                 "q_norm": init_norm(m.q_lora),
+                 "q_up": _init_dense(keys[1], m.q_lora,
+                                     cfg.n_heads * qk_dim)}
+        p.update({
             "kv_down": _init_dense(keys[2], d, m.kv_lora + m.rope_dim),
             "kv_norm": init_norm(m.kv_lora),
             "kv_up": _init_dense(keys[3], m.kv_lora,
                                  cfg.n_heads * (m.nope_dim + m.v_dim)),
             "wo": _init_dense(keys[4], cfg.n_heads * m.v_dim, d),
-        }
+        })
         return p
     p = {
         "wq": _init_dense(keys[0], d, cfg.n_heads * hd),
@@ -303,11 +308,9 @@ def attention(params, x, *, cfg, rope, mode: str = "train",
     :mod:`repro.kernels.paged_attention`).
     """
     if cfg.mla is not None:
-        if mode == "verify":
-            raise NotImplementedError(
-                "'verify' mode is not implemented for MLA attention")
         return _mla_attention(params, x, cfg=cfg, rope=rope, mode=mode,
-                              cache=cache, pos=pos)
+                              cache=cache, pos=pos,
+                              block_tables=block_tables)
     if mode == "verify" and cfg.window:
         raise NotImplementedError(
             "'verify' mode is not implemented for sliding-window "
@@ -482,16 +485,28 @@ def attention(params, x, *, cfg, rope, mode: str = "train",
     return linear(params["wo"], out), cache
 
 
-def _mla_attention(params, x, *, cfg, rope, mode, cache, pos):
-    """Multi-head latent attention with compressed KV cache."""
+def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
+                   block_tables=None):
+    """Multi-head latent attention over a cache of latent rows.
+
+    Each position caches one row ``kv_norm(c_kv) ‖ rope(k_rope)`` of width
+    ``kv_lora + rope_dim``, as one "KV head": ``(B, T, 1, L+R)`` in a
+    contiguous cache, ``(num_blocks, block_size, 1, L+R)`` in a paged pool.
+    RMS norm is per position, so the normed latent is what every read
+    would compute. Train/prefill/chunk expand the latent to per-head K/V;
+    decode either expands it or (``absorb``, and always on the paged pool)
+    attends in the latent space (:func:`_mla_absorbed_decode`)."""
     m = cfg.mla
     b, s, d = x.shape
     h = cfg.n_heads
     cos_t, sin_t = rope                      # (s, rope_dim/2)
     qk_dim = m.nope_dim + m.rope_dim
 
-    q = linear(params["q_up"],
-               rms_norm(params["q_norm"], linear(params["q_down"], x)))
+    if m.q_lora is None:
+        q = linear(params["wq"], x)
+    else:
+        q = linear(params["q_up"],
+                   rms_norm(params["q_norm"], linear(params["q_down"], x)))
     q = q.reshape(b, s, h, qk_dim)
     q_nope, q_rope = q[..., :m.nope_dim], q[..., m.nope_dim:]
 
@@ -501,113 +516,131 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos):
 
     q_rope = apply_rotary(q_rope, cos_t, sin_t)
     k_rope = apply_rotary(k_rope, cos_t, sin_t)
+    lat = jnp.concatenate(
+        [rms_norm(params["kv_norm"], c_kv)[:, :, None, :],
+         k_rope.astype(c_kv.dtype)], axis=-1)          # (B,S,1,L+R)
 
-    def expand_kv(c_kv_in, k_rope_in):
-        t = c_kv_in.shape[1]
-        kv = linear(params["kv_up"], rms_norm(params["kv_norm"], c_kv_in))
+    def expand_kv(lat_in):
+        t = lat_in.shape[1]
+        kv = linear(params["kv_up"], lat_in[:, :, 0, :m.kv_lora])
         kv = kv.reshape(b, t, h, m.nope_dim + m.v_dim)
         k_nope, v = kv[..., :m.nope_dim], kv[..., m.nope_dim:]
         k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope_in.astype(k_nope.dtype),
-                                      (b, t, h, m.rope_dim))], axis=-1)
+            [k_nope, jnp.broadcast_to(lat_in[..., m.kv_lora:].astype(
+                k_nope.dtype), (b, t, h, m.rope_dim))], axis=-1)
         return k, v
 
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
     scale = qk_dim ** -0.5
+    if mode == "verify":
+        raise NotImplementedError(
+            "'verify' mode is not implemented for MLA attention")
     if mode in ("train", "prefill"):
-        k, v = expand_kv(c_kv, k_rope)
+        k, v = expand_kv(lat)
         out = _chunked_attention(qfull, k, v, scale=scale, causal=True,
                                  window=cfg.window,
                                  q_chunk=cfg.attn_q_chunk,
                                  unroll=cfg.unroll_chunks)
         if mode == "prefill":
             cache = dict(cache)
-            cache["c_kv"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), 0, 1)
-            cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), 0, 1)
+            cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["latent"], lat.astype(cache["latent"].dtype), 0, 1)
     elif mode == "chunk":
         # partial-prefill continuation, mirroring the dense chunk path:
-        # write the chunk's compressed rows at the clock, re-expand the
-        # WHOLE cache, and mask the unwritten suffix by absolute position.
-        # Unwritten rows are zeros → rms_norm(0) = 0 → their expanded K/V
-        # are masked before softmax, so they contribute exact zeros; the
-        # expansion itself is recomputed per chunk, which can reassociate
-        # vs the monolithic prefill gemm — served under the "mla"
-        # agreement budget (measured ≈ exact).
+        # write the chunk's latent rows at the clock, re-expand the WHOLE
+        # cache, and mask the unwritten suffix by absolute position
+        # (unwritten rows expand to K/V that are masked before softmax, so
+        # they contribute exact zeros); the expansion is recomputed per
+        # chunk, which can reassociate vs the monolithic prefill gemm —
+        # served under the "mla" agreement budget (measured exact)
         cache = dict(cache)
-        cache["c_kv"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), pos, 1)
-        cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), pos, 1)
-        ckv_all = shard_act(cache["c_kv"], ("batch", "seq_shard", None))
-        k, v = expand_kv(ckv_all.astype(x.dtype),
-                         cache["k_rope"].astype(x.dtype))
+        cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
+            cache["latent"], lat.astype(cache["latent"].dtype), pos, 1)
+        lat_all = shard_act(cache["latent"],
+                            ("batch", "seq_shard", None, None))
+        k, v = expand_kv(lat_all.astype(x.dtype))
         out = _chunked_attention(qfull, k, v, scale=scale, causal=True,
                                  window=None, q_chunk=cfg.attn_q_chunk,
                                  unroll=cfg.unroll_chunks, row0=pos)
-    else:
+    elif block_tables is not None:   # paged decode: s == 1, pos is (B,)
+        # the new latent row lands through the table (retired slots write
+        # the trash block); attention runs absorbed over the latent pool,
+        # which the gather kernel reads as both K and V
+        bs_blk = cache["latent"].shape[1]
+        rows = jnp.arange(b)
+        phys = block_tables[rows, pos // bs_blk]
         cache = dict(cache)
-        cache["c_kv"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), pos, 1)
-        cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), pos, 1)
-        ckv_all = shard_act(cache["c_kv"], ("batch", "seq_shard", None))
-        krope_all = cache["k_rope"]
-        t = ckv_all.shape[1]
+        cache["latent"] = cache["latent"].at[phys, pos % bs_blk].set(
+            lat[:, 0].astype(cache["latent"].dtype))
+        from repro.kernels.paged_attention import paged_attention
+        pool = cache["latent"]
+        out = _mla_absorbed_decode(
+            params, qfull, m, h, scale,
+            lambda qa: paged_attention(qa, pool, pool, block_tables, pos,
+                                       scale=scale))
+    else:                            # contiguous decode at clock ``pos``
+        cache = dict(cache)
+        cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
+            cache["latent"], lat.astype(cache["latent"].dtype), pos, 1)
+        lat_all = shard_act(cache["latent"],
+                            ("batch", "seq_shard", None, None))
+        t = lat_all.shape[1]
         mask = jnp.where(jnp.arange(t) <= pos, 0.0,
                          NEG_INF)[None, None, None, None, :]
+        lat_all = lat_all.astype(x.dtype)
         if m.absorb:
-            out = _mla_absorbed_decode(params, qfull, ckv_all.astype(x.dtype),
-                                       krope_all.astype(x.dtype), mask,
-                                       scale, m, h)
+            out = _mla_absorbed_decode(
+                params, qfull, m, h, scale,
+                lambda qa: _grouped_attention(qa[:, None], lat_all, lat_all,
+                                              mask, scale)[:, 0])
         else:
-            k, v = expand_kv(ckv_all.astype(x.dtype),
-                             krope_all.astype(x.dtype))
+            k, v = expand_kv(lat_all)
             out = _grouped_attention(qfull, k, v, mask, scale)
     out = out.reshape(b, s, h * m.v_dim)
     return linear(params["wo"], out), cache
 
 
-def _mla_absorbed_decode(params, qfull, ckv_all, krope_all, mask, scale,
-                         m: MLAConfig, h: int):
-    """Weight-absorbed MLA decode: attend in the compressed kv_lora space.
+def _kv_up_weight(params, m: MLAConfig, h: int, dtype):
+    """``kv_up`` as (kv_lora, H, nope + v), from any weight format."""
+    p = params["kv_up"]
+    if "w_q" in p or "w_q4" in p:
+        from repro.quant.apply import dequant_kernel
+        w = dequant_kernel(p, dtype).T
+    else:
+        w = p["w"]
+        if hasattr(w, "dequantize"):                   # (out, in) codes
+            w = w.dequantize(dtype).T
+    return w.astype(dtype).reshape(m.kv_lora, h, m.nope_dim + m.v_dim)
+
+
+def _mla_absorbed_decode(params, qfull, m: MLAConfig, h: int, scale,
+                         attend):
+    """Weight-absorbed MLA decode: attend in the latent space.
 
     scores[h,s] = (W_uk[h]ᵀ q_nope[h]) · n(c_s)  +  q_rope[h] · k_rope_s
     out[h]      = W_uv[h] @ Σ_s p[h,s] · n(c_s)
 
-    Per step this costs O(H·kv_lora·(nope+v)) for the two absorptions plus
-    O(S·H·kv_lora) for attention — the O(S·H·(nope+v)·kv_lora) cache
-    re-expansion of the naive path is gone.
+    ``attend(qa)`` takes the absorbed queries ``(B, H, L+R)`` against the
+    latent rows ``n(c_s) ‖ k_rope_s`` (their first ``L`` lanes are the
+    values) and returns ``(B, H, L+R)``. Per step this costs
+    O(H·kv_lora·(nope+v)) for the two absorptions plus O(S·H·kv_lora) for
+    attention — no cache re-expansion.
     """
-    from repro.models.layers import rms_norm as _rms
-    b, s, _, _ = qfull.shape                       # s == 1 (decode)
-    q_nope = qfull[..., :m.nope_dim]               # (B,1,H,nope)
-    q_rope = qfull[..., m.nope_dim:]               # (B,1,H,rope)
-    w_up = params["kv_up"]["w"]                    # (kv_lora, H*(nope+v))
-    if hasattr(w_up, "dequantize"):
-        w_up = w_up.dequantize(qfull.dtype).T
-    w_up = w_up.reshape(m.kv_lora, h, m.nope_dim + m.v_dim)
+    q_nope = qfull[:, 0, :, :m.nope_dim]           # (B,H,nope)
+    q_rope = qfull[:, 0, :, m.nope_dim:]           # (B,H,rope)
+    w_up = _kv_up_weight(params, m, h, qfull.dtype)
     w_uk = w_up[..., :m.nope_dim]                  # (kv_lora, H, nope)
     w_uv = w_up[..., m.nope_dim:]                  # (kv_lora, H, v)
-    ckv_n = _rms(params["kv_norm"], ckv_all)       # normalize once per step
-    # absorb K-half into the query: q̃ = W_ukᵀ q_nope → (B,1,H,kv_lora)
-    q_tilde = jnp.einsum("bshn,chn->bshc", q_nope, w_uk.astype(qfull.dtype))
-    s_nope = jnp.einsum("bshc,btc->bhst", q_tilde, ckv_n.astype(qfull.dtype))
-    s_rope = jnp.einsum("bshr,btor->bhst", q_rope,
-                        krope_all.astype(qfull.dtype))
-    scores = (s_nope + s_rope).astype(jnp.float32) * scale
-    scores = scores + mask[:, :, 0]               # (B,H,1,T)
-    p = jax.nn.softmax(scores, axis=-1).astype(qfull.dtype)
-    attended = jnp.einsum("bhst,btc->bshc", p, ckv_n.astype(qfull.dtype))
-    # absorb V-half into the output
-    return jnp.einsum("bshc,chv->bshv", attended, w_uv.astype(qfull.dtype))
+    q_tilde = jnp.einsum("bhn,chn->bhc", q_nope, w_uk)
+    attended = attend(jnp.concatenate([q_tilde, q_rope], -1))
+    out = jnp.einsum("bhc,chv->bhv", attended[..., :m.kv_lora], w_uv)
+    return out[:, None]                            # (B,1,H,v)
 
 
 def init_mla_cache(batch: int, max_len: int, cfg, dtype=jnp.bfloat16):
     m = cfg.mla
-    return {"c_kv": jnp.zeros((batch, max_len, m.kv_lora), dtype),
-            "k_rope": jnp.zeros((batch, max_len, 1, m.rope_dim), dtype)}
+    return {"latent": jnp.zeros((batch, max_len, 1, m.kv_lora + m.rope_dim),
+                                dtype)}
 
 
 def cross_attention(params, x, enc_out, *, cfg,

@@ -98,6 +98,15 @@ class LM:
     def forward(self, params, batch, mode: str = "train",
                 caches: Optional[dict] = None
                 ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray]:
+        """Returns (logits, new caches, MoE aux loss)."""
+        logits, caches, aux, _ = self._forward(params, batch, mode, caches)
+        return logits, caches, aux
+
+    def _forward(self, params, batch, mode: str, caches: Optional[dict]):
+        """:meth:`forward`, plus the token-expert pairs the held experts
+        served over all MoE layers (an int32 scalar; paged rows without
+        blocks, retired or admitting, carry no token and count nothing;
+        None without experts)."""
         tokens = batch["tokens"]
         b, s = tokens.shape
         block_tables = caches.get("block_tables") \
@@ -128,7 +137,7 @@ class LM:
         enc_out = batch.get("enc_out")
         if enc_out is None:
             enc_out = self._encode(params, batch)
-        x, new_caches, aux = apply_stack(
+        x, new_caches, aux, pairs = apply_stack(
             params["stack"], x, cfg=self.cfg, rope=rope, mode=mode,
             caches=caches, pos=pos, enc_out=enc_out,
             block_tables=block_tables)
@@ -138,8 +147,12 @@ class LM:
                 new_caches["block_tables"] = block_tables
             if enc_out is not None:
                 new_caches["enc_out"] = enc_out
+        if pairs is not None:
+            if block_tables is not None:
+                pairs = pairs * (block_tables[:, :1] != 0)
+            pairs = pairs.sum().astype(jnp.int32)
         logits = self._logits(params, x)
-        return logits, new_caches, aux
+        return logits, new_caches, aux, pairs
 
     # --------------------------------------------------------------- train
     def train_loss(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
@@ -158,14 +171,16 @@ class LM:
             c["enc_out"] = jnp.zeros((batch, enc_len, self.cfg.d_model), dt)
         return c
 
-    def prefill(self, params, batch, caches) -> Tuple[jnp.ndarray, dict]:
-        logits, caches, _ = self.forward(params, batch, mode="prefill",
-                                         caches=caches)
-        return logits[:, -1], caches
+    def prefill(self, params, batch, caches, with_pairs: bool = False):
+        """Returns (last-token logits, caches), and with ``with_pairs``
+        the launch's held expert-token pairs (see :meth:`_forward`)."""
+        logits, caches, _, pairs = self._forward(params, batch, "prefill",
+                                                 caches)
+        return (logits[:, -1], caches) + ((pairs,) if with_pairs else ())
 
-    def prefill_chunk(self, params, batch, caches
-                      ) -> Tuple[jnp.ndarray, dict]:
-        """Consume the next ``s`` prompt tokens of a partial prefill.
+    def prefill_chunk(self, params, batch, caches, with_pairs: bool = False):
+        """Consume the next ``s`` prompt tokens of a partial prefill
+        (returns as :meth:`prefill`).
 
         ``caches["pos"]`` is the chunk's start offset (0 for a fresh cache);
         the chunk attends over the already-written cache prefix plus itself,
@@ -192,12 +207,20 @@ class LM:
         if singleton:
             p0 = caches["pos"]
             toks = jnp.concatenate([toks, toks[:, -1:]], axis=1)
-        logits, caches, _ = self.forward(params, {"tokens": toks},
-                                         mode="chunk", caches=caches)
+        logits, caches, _, pairs = self._forward(params, {"tokens": toks},
+                                                 "chunk", caches)
         if singleton:
             caches["pos"] = p0 + 1
-            return logits[:, 0], caches
-        return logits[:, -1], caches
+        out = (logits[:, 0] if singleton else logits[:, -1]), caches
+        return out + ((pairs,) if with_pairs else ())
+
+    def moe_rows_per_token(self) -> int:
+        """Rows the held experts' matmuls process per token of a serving
+        launch, over all layers (dropless: each held expert runs every
+        token); 0 for a stack without experts."""
+        from repro.models.transformer import n_moe_layers
+        moe = self.cfg.moe
+        return n_moe_layers(self.cfg) * moe.held if moe else 0
 
     def arch_features(self) -> Tuple[str, ...]:
         """See :func:`arch_features`."""
@@ -226,13 +249,13 @@ class LM:
         feats = arch_features(self.cfg)
         return "mamba" in feats or "rwkv" in feats
 
-    def decode_step(self, params, tokens, caches
-                    ) -> Tuple[jnp.ndarray, dict]:
-        """tokens: (B, 1) — one new token per sequence."""
+    def decode_step(self, params, tokens, caches, with_pairs: bool = False):
+        """tokens: (B, 1) — one new token per sequence (returns as
+        :meth:`prefill`)."""
         batch = {"tokens": tokens, "enc_out": caches.get("enc_out")}
-        logits, caches, _ = self.forward(params, batch, mode="decode",
-                                         caches=caches)
-        return logits[:, -1], caches
+        logits, caches, _, pairs = self._forward(params, batch, "decode",
+                                                 caches)
+        return (logits[:, -1], caches) + ((pairs,) if with_pairs else ())
 
     def verify_step(self, params, tokens, caches
                     ) -> Tuple[jnp.ndarray, dict]:

@@ -2,6 +2,11 @@
 RWKV stacks, and the encoder-decoder wiring — all scan-over-layers with
 stacked parameters (small HLO, fast SPMD partitioning) and optional remat.
 
+Stacks with ``first_k_dense`` leading dense layers (DeepSeek-V3,
+Moonlight) keep them under ``"lead"``, a list of blocks run before the
+periods, beside ``"periods"``/``"list"`` in both the parameters and the
+caches.
+
 Block kinds:
   "a"    attention block   : x += attn(ln1(x)); x += ffn_or_moe(ln2(x))
   "m"    mamba block       : x += mamba(ln1(x)); x += ffn_or_moe(ln2(x))
@@ -71,8 +76,14 @@ def init_block(key, cfg, kind: str, use_moe: bool,
     else:
         raise ValueError(kind)
     if use_moe:
-        p["moe"] = moe_lib.init_moe(k3, d, cfg.d_ff, cfg.moe.n_experts,
-                                    cfg.ffn_kind)
+        m = cfg.moe
+        d_exp = m.d_expert or cfg.d_ff
+        p["moe"] = moe_lib.init_moe(k3, d, d_exp, m.held, cfg.ffn_kind,
+                                    n_routed=m.n_experts,
+                                    score_bias=m.scoring == "sigmoid")
+        if m.n_shared:
+            p["shared"] = ffn_lib.init_ffn(k2, d, m.n_shared * d_exp,
+                                           cfg.ffn_kind)
     else:
         p["ffn"] = ffn_lib.init_ffn(k3, d, cfg.d_ff, cfg.ffn_kind)
     return p
@@ -82,9 +93,13 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
                 cache: Optional[dict], pos,
                 enc_out: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None
-                ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray]:
-    """Returns (x, new_cache, moe_aux)."""
+                ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray,
+                           Optional[jnp.ndarray]]:
+    """Returns (x, new_cache, moe_aux, moe_pairs): ``moe_pairs`` (B, S)
+    counts each token's expert choices the held experts computed (None
+    for a block without experts)."""
     aux = jnp.zeros((), jnp.float32)
+    pairs = None
     if mode == "verify" and kind != "a":
         # the speculative multi-position verify scores every draft row as
         # if it were a lockstep decode step; recurrent mixers would need a
@@ -105,7 +120,7 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
         new_cache = None
         if st_tm is not None:
             new_cache = {**st_tm, **(st_cm or {})}
-        return x, new_cache, aux
+        return x, new_cache, aux, pairs
 
     if kind == "a":
         h, new_cache = attn_lib.attention(
@@ -126,22 +141,26 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
         x = x + hx
     h2 = rms_norm(p["ln2"], x, plus_one=cfg.norm_plus_one)
     if use_moe:
-        # dropless at decode AND verify: with no capacity competition each
-        # token's expert mix is batch-independent, which keeps speculative
-        # verify rows bit-identical to the decode steps they stand in for.
-        # Prefill/chunk use capacity routing: a chunked prefill therefore
-        # routes per chunk, and capacity competition (hence token dropping)
-        # depends on the chunk split — that chunk-split-dependence is the
-        # measured "moe" agreement budget (see repro.serving.equivalence).
-        h2, aux = moe_lib.moe_ffn(p["moe"], h2, n_experts=cfg.moe.n_experts,
-                                  top_k=cfg.moe.top_k, kind=cfg.ffn_kind,
-                                  capacity_factor=cfg.moe.capacity_factor,
-                                  dropless=(mode in ("decode", "verify")))
+        # dropless in every serving mode: with no capacity competition each
+        # token's expert mix is independent of the batch and of the chunk
+        # split, so prefill, chunks, decode and speculative verify route a
+        # token alike (and as a reference that drops nothing does).
+        # Training keeps the GShard capacity.
+        m = cfg.moe
+        h_moe, aux, pairs = moe_lib.moe_apply(
+            p["moe"], h2, n_experts=m.n_experts, top_k=m.top_k,
+            kind=cfg.ffn_kind, capacity_factor=m.capacity_factor,
+            dropless=mode != "train", scoring=m.scoring,
+            norm_topk=m.norm_topk, routed_scale=m.routed_scale,
+            first_held=m.first_held, n_held=m.held)
+        if "shared" in p:
+            h_moe = h_moe + ffn_lib.ffn(p["shared"], h2, cfg.ffn_kind)
+        h2 = h_moe
     else:
         h2 = ffn_lib.ffn(p["ffn"], h2, cfg.ffn_kind)
     x = x + h2
     x = shard_act(x, ("batch", None, None))
-    return x, new_cache, aux
+    return x, new_cache, aux, pairs
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +168,9 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
 # ---------------------------------------------------------------------------
 
 def layer_plan(cfg) -> Tuple[Tuple[str, bool], ...]:
-    """((kind, use_moe) per layer in one stack period, n_periods)."""
+    """(kind, use_moe) per layer of one stack period. The
+    ``cfg.first_k_dense`` leading dense attention layers come before the
+    periods and are not part of the plan."""
     if cfg.rwkv:
         pattern = (("rwkv", False),)
     elif cfg.block_pattern is not None:
@@ -158,7 +179,7 @@ def layer_plan(cfg) -> Tuple[Tuple[str, bool], ...]:
         pattern = tuple(
             (k, bool(moe_every) and (i % moe_every == moe_every - 1))
             for i, k in enumerate(cfg.block_pattern))
-        assert cfg.n_layers % period == 0
+        assert (cfg.n_layers - cfg.first_k_dense) % period == 0
     elif cfg.moe is not None and cfg.moe.every > 1:
         ev = cfg.moe.every
         pattern = tuple(("a", i % ev == ev - 1) for i in range(ev))
@@ -170,7 +191,12 @@ def layer_plan(cfg) -> Tuple[Tuple[str, bool], ...]:
 
 
 def n_periods(cfg) -> int:
-    return cfg.n_layers // len(layer_plan(cfg))
+    return (cfg.n_layers - cfg.first_k_dense) // len(layer_plan(cfg))
+
+
+def n_moe_layers(cfg) -> int:
+    """Layers with experts."""
+    return n_periods(cfg) * sum(1 for _, moe in layer_plan(cfg) if moe)
 
 
 # ---------------------------------------------------------------------------
@@ -182,9 +208,10 @@ def unstack_stack(stack: Dict[str, Any], periods: int) -> Dict[str, Any]:
     where QuantizedTensor leaves cannot be scanned over)."""
     if "list" in stack:
         return stack
-    return {"list": [jax.tree_util.tree_map(lambda a: a[i],
-                                            stack["periods"])
-                     for i in range(periods)]}
+    out = {k: v for k, v in stack.items() if k != "periods"}
+    out["list"] = [jax.tree_util.tree_map(lambda a: a[i], stack["periods"])
+                   for i in range(periods)]
+    return out
 
 
 def init_stack(key, cfg) -> Dict[str, Any]:
@@ -199,8 +226,14 @@ def init_stack(key, cfg) -> Dict[str, Any]:
                 for i, (kind, moe) in enumerate(pattern)}
 
     if cfg.scan_layers and periods > 1:
-        return {"periods": jax.vmap(one_period)(keys)}
-    return {"list": [one_period(k) for k in keys]}
+        out = {"periods": jax.vmap(one_period)(keys)}
+    else:
+        out = {"list": [one_period(k) for k in keys]}
+    if cfg.first_k_dense:
+        lead_keys = jax.random.split(jax.random.fold_in(key, 1),
+                                     cfg.first_k_dense)
+        out["lead"] = [init_block(k, cfg, "a", False) for k in lead_keys]
+    return out
 
 
 def init_layer_cache(cfg, batch: int, max_len: int, kind: str,
@@ -240,6 +273,10 @@ def init_cache(cfg, batch: int, max_len: int, quantize_kv: bool = False,
         caches = {"periods": stacked}
     else:
         caches = {"list": [one_period() for _ in range(periods)]}
+    if cfg.first_k_dense:
+        caches["lead"] = [init_layer_cache(cfg, batch, max_len, "a",
+                                           quantize_kv, dtype)
+                          for _ in range(cfg.first_k_dense)]
     caches["pos"] = jnp.zeros((), jnp.int32)
     return caches
 
@@ -247,37 +284,57 @@ def init_cache(cfg, batch: int, max_len: int, quantize_kv: bool = False,
 def apply_stack(stack, x, *, cfg, rope, mode: str, caches, pos,
                 enc_out: Optional[jnp.ndarray] = None,
                 block_tables: Optional[jnp.ndarray] = None
-                ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Run all layers. Returns (x, new_caches, moe_aux_mean)."""
+                ) -> Tuple[jnp.ndarray, Any, jnp.ndarray,
+                           Optional[jnp.ndarray]]:
+    """Run all layers. Returns (x, new_caches, moe_aux_mean, moe_pairs):
+    ``moe_pairs`` (B, S) sums :func:`apply_block`'s counts over the layers
+    (None for a stack without experts)."""
     pattern = layer_plan(cfg)
+    kw = dict(cfg=cfg, rope=rope, mode=mode, pos=pos, enc_out=enc_out,
+              block_tables=block_tables)
+
+    def add(total, part):
+        return part if total is None else (
+            total if part is None else total + part)
 
     def run_period(pp, xin, pcache):
         aux_sum = jnp.zeros((), jnp.float32)
+        pairs = None
         new_c = {}
         for i, (kind, moe) in enumerate(pattern):
             c_in = None if pcache is None else pcache.get(f"b{i}")
-            xin, c_out, aux = apply_block(
-                pp[f"b{i}"], xin, cfg=cfg, kind=kind, use_moe=moe, rope=rope,
-                mode=mode, cache=c_in, pos=pos, enc_out=enc_out,
-                block_tables=block_tables)
+            xin, c_out, aux, pairs_i = apply_block(
+                pp[f"b{i}"], xin, kind=kind, use_moe=moe, cache=c_in, **kw)
             aux_sum += aux
+            pairs = add(pairs, pairs_i)
             if c_out is not None:
                 new_c[f"b{i}"] = c_out
-        return xin, (new_c if new_c else None), aux_sum
+        return xin, (new_c if new_c else None), aux_sum, pairs
+
+    def run_lead(lp, xin, c_in):
+        xin, c_out, _, _ = apply_block(lp, xin, kind="a", use_moe=False,
+                                       cache=c_in, **kw)
+        return xin, c_out
 
     needs_cache = mode in ("prefill", "decode", "chunk", "verify")
+    lead_caches = []
+    runl = jax.checkpoint(run_lead) if cfg.remat else run_lead
+    for i, lp in enumerate(stack.get("lead", ())):
+        pc = caches["lead"][i] if needs_cache else None
+        x, new_c = runl(lp, x, pc)
+        lead_caches.append(new_c)
     if "periods" in stack:
         pcaches = caches["periods"] if needs_cache else None
 
         def body(xc, per):
             pp, pc = per
-            xout, new_c, aux = run_period(pp, xc,
-                                          pc if needs_cache else None)
+            xout, new_c, aux, pairs = run_period(
+                pp, xc, pc if needs_cache else None)
             if not needs_cache:
                 new_c = 0.0
             elif new_c is None:
                 new_c = pc
-            return xout, (new_c, aux)
+            return xout, (new_c, aux, pairs)
 
         if cfg.remat:
             body = jax.checkpoint(body)
@@ -285,21 +342,27 @@ def apply_stack(stack, x, *, cfg, rope, mode: str, caches, pos,
         xs = (stack["periods"],
               pcaches if pcaches is not None
               else jnp.zeros((periods,), jnp.float32))
-        x, (new_caches, auxs) = jax.lax.scan(body, x, xs)
+        x, (new_caches, auxs, pairs) = jax.lax.scan(body, x, xs)
         aux = jnp.mean(auxs)
+        if pairs is not None:
+            pairs = pairs.sum(0)
         out_caches = {"periods": new_caches} if needs_cache else None
     else:
         new_list = []
         aux_total = jnp.zeros((), jnp.float32)
+        pairs = None
         runp = jax.checkpoint(run_period) if cfg.remat else run_period
         for i, pp in enumerate(stack["list"]):
             pc = caches["list"][i] if needs_cache else None
-            x, new_c, aux_i = runp(pp, x, pc)
+            x, new_c, aux_i, pairs_i = runp(pp, x, pc)
             aux_total += aux_i
+            pairs = add(pairs, pairs_i)
             new_list.append(new_c if new_c is not None else pc)
         aux = aux_total / max(len(stack["list"]), 1)
         out_caches = {"list": new_list} if needs_cache else None
-    return x, out_caches, aux
+    if needs_cache and lead_caches:
+        out_caches["lead"] = lead_caches
+    return x, out_caches, aux, pairs
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple, Union)
 
 import jax
 
@@ -191,14 +192,26 @@ ARCH_GATES: Tuple[ArchGate, ...] = (
     ArchGate(
         "paged_x_non_positional_kv",
         lambda c, a: c.kv_backend == "paged" and any(
-            f in ("mla", "sliding_window", "mamba", "rwkv")
+            f in ("sliding_window", "mamba", "rwkv")
             for f in _arch_features(a)),
         NotImplementedError,
-        "the paged KV cache requires per-position cache rows: MLA "
-        "compressed-latent caches, sliding-window rings, and mamba/rwkv "
-        "recurrent state cannot be block-paged; use "
-        "kv_backend='contiguous' (MoE stacks with plain attention page "
-        "fine — only the sequence-mixer cache layout matters)"),
+        "the paged KV cache requires per-position cache rows: "
+        "sliding-window rings and mamba/rwkv recurrent state cannot be "
+        "block-paged; use kv_backend='contiguous' (MLA latent rows and "
+        "MoE stacks page fine — only the sequence-mixer cache layout "
+        "matters)"),
+    ArchGate(
+        "speculative_x_mla",
+        lambda c, a: c.speculative and a.mla is not None,
+        NotImplementedError,
+        "unsupported combination: speculative x MLA (the multi-position "
+        "verify forward is not implemented for latent attention)"),
+    ArchGate(
+        "quantize_kv_x_mla",
+        lambda c, a: c.quantize_kv and a.mla is not None,
+        NotImplementedError,
+        "unsupported combination: quantize_kv x MLA (the cache holds "
+        "fp latent rows; there is no int8 latent pool)"),
 )
 
 
@@ -245,6 +258,11 @@ class ServeConfig:
     # block (0: full capacity, max_slots * (max_len // block_size) + 1 —
     # no admission backpressure; smaller pools admit under a block budget)
     kv_blocks: int = 0
+    # paged only: donate the pool to the programs that write it (decode,
+    # block scatter, copy-on-write), so XLA updates it in place and one
+    # pool is held instead of two. A caller that keeps the cache dict
+    # across those calls then holds deleted arrays
+    donate_kv: bool = False
     # self-speculative decoding (paged + continuous + greedy only): a
     # draft_bits quantization of the SAME checkpoint autoregressively
     # proposes draft_k-token runs per slot, the serving tree verifies all
@@ -266,6 +284,23 @@ class ServeConfig:
     def __post_init__(self):
         for gate in CONFIG_GATES:
             gate.check(self)
+
+
+class StepOut(NamedTuple):
+    """What a serving launch returns beside its caches: the logits it
+    samples from, and the token-expert pairs the held experts served over
+    all MoE layers (an int32 device scalar; None without experts)."""
+    logits: jax.Array
+    moe_pairs: Optional[jax.Array]
+
+
+def _step_out(fn):
+    """``fn`` (a model's prefill, chunk or decode step) as a serving
+    launch: ``(StepOut, caches)``."""
+    def launch(params, batch, caches):
+        logits, caches, pairs = fn(params, batch, caches, with_pairs=True)
+        return StepOut(logits, pairs), caches
+    return launch
 
 
 class ServeEngine:
@@ -298,12 +333,17 @@ class ServeEngine:
         # assert same-shape rounds/steps never retrace
         self.trace_counts: Dict[str, int] = \
             {"prefill": 0, "prefill_chunk": 0, "decode": 0, "admit": 0}
-        self._prefill = self._jit_counted("prefill", self.model.prefill)
+        # serving launches return ``(StepOut(logits, moe_pairs), caches)``
+        self._prefill = self._jit_counted(
+            "prefill", _step_out(self.model.prefill))
         # chunk continuation: one trace per distinct chunk length (the
         # start offset is a traced cache scalar, so it never retraces)
-        self._prefill_chunk = self._jit_counted("prefill_chunk",
-                                                self.model.prefill_chunk)
-        self._decode = self._jit_counted("decode", self.model.decode_step)
+        self._prefill_chunk = self._jit_counted(
+            "prefill_chunk", _step_out(self.model.prefill_chunk))
+        self._decode = self._jit_counted(
+            "decode", _step_out(self.model.decode_step),
+            donate=(2,) if self.cfg.donate_kv
+            and self.cfg.kv_backend == "paged" else ())
         self._admit_rows = self._jit_counted("admit", admit_rows)
         self._key = jax.random.PRNGKey(0)
         self._rounds_total = 0
@@ -323,14 +363,14 @@ class ServeEngine:
             raise ValueError(f"unknown scheduler {self.cfg.scheduler!r} "
                              "(expected 'round' or 'continuous')")
 
-    def _jit_counted(self, name: str, fn):
+    def _jit_counted(self, name: str, fn, donate: Tuple[int, ...] = ()):
         def counted(*args):
             self.trace_counts[name] += 1   # runs at trace time only
             return fn(*args)
         # the program's name in a profiler trace: jit_serve_<name> on the
         # device, PjitFunction(serve_<name>) on the host
         counted.__name__ = counted.__qualname__ = f"serve_{name}"
-        return jax.jit(counted)
+        return jax.jit(counted, donate_argnums=donate)
 
     # ------------------------------------------------------------ weights
     @property

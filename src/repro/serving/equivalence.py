@@ -9,7 +9,7 @@ The serving test story started as bit-identity: chunked == monolithic,
 paged == contiguous, continuous == round, all asserted token-for-token.
 Some features break bit-identity by construction — int8 KV codes perturb
 every attention read; a sliding-window ring chunk permutes the key axis;
-MoE capacity competition depends on how a prefill is chunked; mamba/rwkv
+MoE expert gemms reassociate with how a prefill is chunked; mamba/rwkv
 chunk continuations regroup the prefix scan — so configs carrying them
 are held to a *measured agreement budget* instead, in the spirit of the
 mixtral 0.041 serving-divergence budget the weight path already uses.

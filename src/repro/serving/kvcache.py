@@ -68,6 +68,10 @@ _span = jax.profiler.TraceAnnotation
 
 TRASH = 0   # reserved physical block: write target for retired slots
 
+# layer-state entries: leading dense layers and periods (batch at axis 0),
+# scan-stacked periods (batch at axis 1)
+LAYER_KEYS = ("lead", "list", "periods")
+
 __all__ = ["KVCache", "ContiguousKVCache", "PagedKVCache", "admit_rows"]
 
 
@@ -119,7 +123,7 @@ def gather_blocks(side, pool, phys):
                            + blk.shape[3:]).astype(s.dtype)
         return jax.lax.dynamic_update_slice_in_dim(s, flat, 0, 2)
 
-    for key in ("periods", "list"):
+    for key in LAYER_KEYS:
         if key in pool:
             out[key] = jax.tree_util.tree_map(
                 g1 if key == "periods" else g0, side[key], pool[key])
@@ -145,7 +149,7 @@ def scatter_blocks(pool, side, phys, start):
             seg.reshape((seg.shape[0], n, bs) + seg.shape[3:])
             .astype(p.dtype))
 
-    for key in ("periods", "list"):
+    for key in LAYER_KEYS:
         if key in pool:
             out[key] = jax.tree_util.tree_map(
                 s1 if key == "periods" else s0, pool[key], side[key])
@@ -162,7 +166,7 @@ def copy_block(pool, src, dst):
     def c1(p):
         return p.at[:, dst].set(p[:, src])
 
-    for key in ("periods", "list"):
+    for key in LAYER_KEYS:
         if key in pool:
             out[key] = jax.tree_util.tree_map(
                 c1 if key == "periods" else c0, pool[key])
@@ -369,8 +373,9 @@ class ContiguousKVCache(KVCache):
         for j, (_, r) in enumerate(chosen):
             tokens[j, clock - len(r.prompt):] = np.asarray(r.prompt)
         tmp_cache = self.side_cache(k)
-        lg, tmp_cache = self.eng._prefill(
+        out, tmp_cache = self.eng._prefill(
             params, {"tokens": jnp.asarray(tokens)}, tmp_cache)
+        lg = out.logits
         self._ensure_pool(lg)
         idx = jnp.asarray(np.asarray(slot_ids[:k], np.int32))
         self._cache, self._logits = self.eng._admit_rows(
@@ -390,8 +395,9 @@ class ContiguousKVCache(KVCache):
 
     # -------------------------------------------------------------- decode
     def decode(self, params, nxt, active_ids) -> None:
-        self._logits, self._cache = self.eng._decode(
-            params, nxt[:, None], self._cache)
+        out, self._cache = self.eng._decode(params, nxt[:, None],
+                                            self._cache)
+        self._logits = out.logits
         self._clock += 1
 
     def retire(self, slot_id: int) -> None:
@@ -462,13 +468,27 @@ class PagedKVCache(KVCache):
         # last ``decode_positions``
         self.positions_attended = self.positions_walked = 0
         self._step_attended = self._step_walked = 0
+        # MoE stacks, per decode launch and per prefill chunk: token-expert
+        # pairs routed to the experts held here, and the rows the held
+        # experts' matmuls process (held experts x capacity, dropless);
+        # cumulative, and since the last ``decode_positions``. A launch's
+        # pair count stays on the device until the step's token pull has
+        # waited for it anyway (``_moe_pending``)
+        self.moe_pairs_here = self.moe_rows_computed = 0
+        self._step_pairs = self._step_rows = 0
+        self._moe_rows_per_token = self.model.moe_rows_per_token()
+        self._moe_pending: List[Any] = []
         # jitted paged ops with trace accounting (lazy counters: the
-        # contiguous path's trace_counts stay exactly as before)
+        # contiguous path's trace_counts stay exactly as before). With
+        # ``donate_kv`` the ops that write the pool donate it, as decode
+        # does
         for name in ("gather", "scatter", "copy"):
             engine.trace_counts.setdefault(name, 0)
+        donate = (0,) if engine.cfg.donate_kv else ()
         self._gather = engine._jit_counted("gather", gather_blocks)
-        self._scatter = engine._jit_counted("scatter", scatter_blocks)
-        self._copy = engine._jit_counted("copy", copy_block)
+        self._scatter = engine._jit_counted("scatter", scatter_blocks,
+                                            donate=donate)
+        self._copy = engine._jit_counted("copy", copy_block, donate=donate)
         # multi-position verifier forward (speculative decoding only —
         # the counter is lazy so non-speculative paged runs keep their
         # exact trace_counts dict)
@@ -740,11 +760,13 @@ class PagedKVCache(KVCache):
             lp, side = self._pin_prefix(slot, prompt)
         if lp:
             toks = jnp.asarray(np.asarray(prompt[lp:], np.int32))[None]
-            lg, side = self.eng._prefill_chunk(params, {"tokens": toks},
-                                               side)
+            out, side = self.eng._prefill_chunk(params, {"tokens": toks},
+                                                side)
         else:
             toks = jnp.asarray(np.asarray(prompt, np.int32))[None]
-            lg, side = self.eng._prefill(params, {"tokens": toks}, side)
+            out, side = self.eng._prefill(params, {"tokens": toks}, side)
+        lg = out.logits
+        self.note_moe(out.moe_pairs, toks.shape[1])
         with _span("kv.scatter", request_id=r.request_id):
             rem = self._commit_blocks(slot, prompt, lp, side, lg,
                                       r.max_new_tokens)
@@ -859,8 +881,10 @@ class PagedKVCache(KVCache):
             tables = self._tables.copy()
             self._cache["pos"] = jnp.asarray(self._lengths.copy())
             self._cache["block_tables"] = jnp.asarray(tables)
-            self._logits, self._cache = self.eng._decode(
-                params, nxt[:, None], self._cache)
+            out, self._cache = self.eng._decode(params, nxt[:, None],
+                                                self._cache)
+            self._logits = out.logits
+            self.note_moe(out.moe_pairs, self.max_slots)
             self._lengths[active_ids] += 1
         # slot b attends its lengths[b] + 1 positions (now its length); the
         # program walks the whole table it was handed
@@ -872,10 +896,37 @@ class PagedKVCache(KVCache):
         self._step_walked += walked
 
     def decode_positions(self) -> Dict[str, int]:
+        self._pull_moe()
         out = {"positions_attended": self._step_attended,
-               "positions_walked": self._step_walked}
+               "positions_walked": self._step_walked,
+               "moe_pairs_here": self._step_pairs,
+               "moe_rows_computed": self._step_rows}
         self._step_attended = self._step_walked = 0
+        self._step_pairs = self._step_rows = 0
         return out
+
+    def note_moe(self, pairs, tokens: int) -> None:
+        """Count one launch of ``tokens`` rows (a decode over every slot,
+        or a prefill chunk): its held pairs, the launch's ``StepOut.
+        moe_pairs`` (pulled later, by :meth:`_pull_moe`), and the held
+        experts' rows (each runs every token, dropless). No-op without
+        experts."""
+        if pairs is None:
+            return
+        self._moe_pending.append(pairs)
+        rows = self._moe_rows_per_token * int(tokens)
+        self.moe_rows_computed += rows
+        self._step_rows += rows
+
+    def _pull_moe(self) -> None:
+        """Bring the noted launches' pair counts to the host: called after
+        the step's token pull (or a chunk's wait), when those launches
+        have finished, so it adds no wait of its own."""
+        if self._moe_pending:
+            got = int(sum(int(x) for x in jax.device_get(self._moe_pending)))
+            self._moe_pending = []
+            self.moe_pairs_here += got
+            self._step_pairs += got
 
     # ------------------------------------------------- speculative verify
     def ensure_rows(self, slot: int, start: int, n: int) -> None:
@@ -964,7 +1015,7 @@ class PagedKVCache(KVCache):
         if self._cache is None:
             return 0
         total = 0
-        for key in ("periods", "list"):
+        for key in LAYER_KEYS:
             if key in self._cache:
                 total += sum(l.nbytes for l in
                              jax.tree_util.tree_leaves(self._cache[key]))
@@ -984,6 +1035,7 @@ class PagedKVCache(KVCache):
         return self.block_bytes() * self.num_blocks
 
     def stats(self) -> Dict[str, Any]:
+        self._pull_moe()
         free, cached = len(self._free), len(self._cached)
         return {"backend": self.backend,
                 "quantize_kv": self.cfg.quantize_kv,
@@ -1003,4 +1055,6 @@ class PagedKVCache(KVCache):
                 "cow_copies": self.cow_copies,
                 "positions_attended": self.positions_attended,
                 "positions_walked": self.positions_walked,
+                "moe_pairs_here": self.moe_pairs_here,
+                "moe_rows_computed": self.moe_rows_computed,
                 "evictions": self.evictions}

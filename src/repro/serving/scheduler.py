@@ -270,7 +270,8 @@ class RoundScheduler(_SchedulerBase):
                 (b, max(1, plen // self.model.cfg.enc_ratio),
                  self.model.cfg.d_model), jnp.float32)
         t0 = time.perf_counter()
-        logits, cache = self.eng._prefill(params, batch, cache)
+        out, cache = self.eng._prefill(params, batch, cache)
+        logits = out.logits
         jax.block_until_ready(logits)
         prefill_ms = (time.perf_counter() - t0) * 1e3
 
@@ -297,7 +298,8 @@ class RoundScheduler(_SchedulerBase):
                              "t": time.perf_counter()})
             if all(done[i] for i in range(b)):
                 break
-            logits, cache = self.eng._decode(params, nxt[:, None], cache)
+            out, cache = self.eng._decode(params, nxt[:, None], cache)
+            logits = out.logits
         jax.block_until_ready(logits)
         decode_ms = (time.perf_counter() - t0) * 1e3
 
@@ -751,8 +753,9 @@ class ContinuousScheduler(_SchedulerBase):
             # builds an unbounded compute backlog on saturated ones, which
             # the scatter step then pays in one spike — the exact tail this
             # feature bounds
-            p.logits, p.cache = self.eng._prefill_chunk(
+            out, p.cache = self.eng._prefill_chunk(
                 params, {"tokens": toks}, p.cache)
+            p.logits = out.logits
             with _span("serve.chunk_wait"):
                 jax.block_until_ready(p.logits)
         ms = (time.perf_counter() - t0) * 1e3
@@ -788,10 +791,12 @@ class ContinuousScheduler(_SchedulerBase):
             toks = jnp.asarray(p.suffix[None, p.done:p.done + n])
             # synchronous for the same tail-bounding reason as the contiguous
             # path: chunks must not queue up behind the in-flight decode
-            p.logits, p.cache = self.eng._prefill_chunk(
+            out, p.cache = self.eng._prefill_chunk(
                 params, {"tokens": toks}, p.cache)
+            p.logits = out.logits
             with _span("serve.chunk_wait"):
                 jax.block_until_ready(p.logits)
+            self.kv.note_moe(out.moe_pairs, n)
             p.done += n
             p.chunks += 1
             self.chunk_steps += 1

@@ -17,25 +17,35 @@ import jax
 import numpy as np
 import pytest
 
-from repro.configs import get_config
+from repro.configs import ArchConfig, MoEConfig, get_config
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServeConfig, ServeEngine
 from repro.serving.equivalence import (AGREEMENT_BUDGETS, active_budget_keys,
                                        agreement_budget,
                                        greedy_token_agreement, oracle_tokens)
 
-# label -> (registry arch, shrink overrides). Mirrors the
-# ``CHUNKED_ARCH_ROWS`` ladder in benchmarks/bench_serving.py: the jamba
-# row isolates the mamba mixer; the mixtral row is the composed
-# sliding_window x moe stack.
+# The plain-attention MoE stack: 4 experts, top-2 of the softmax, every
+# layer (what the reduced moonshot-v1-16b-a3b was before it became the
+# published MLA + shared-expert model).
+PLAIN_MOE = ArchConfig(
+    name="plain-moe", family="moe", n_layers=2, d_model=64, n_heads=4,
+    n_kv_heads=4, head_dim=16, d_ff=128, vocab=256,
+    moe=MoEConfig(n_experts=4, top_k=2))
+
+# label -> (registry arch or explicit config, shrink overrides). Mirrors
+# the ``CHUNKED_ARCH_ROWS`` ladder in benchmarks/bench_serving.py: the
+# jamba row isolates the mamba mixer; the mixtral row is the composed
+# sliding_window x moe stack; the moonshot row composes MLA, a leading
+# dense layer, and sigmoid-routed experts beside shared ones.
 ARCHS = {
     "sliding_window": ("granite-3-8b", dict(n_layers=2, window=8)),
     "mla": ("minicpm3-4b", dict(n_layers=2)),
-    "moe": ("moonshot-v1-16b-a3b", dict(n_layers=2)),
+    "moe": (PLAIN_MOE, {}),
     "mamba": ("jamba-1.5-large-398b",
               dict(n_layers=2, block_pattern=("m", "a"), moe=None)),
     "rwkv": ("rwkv6-1.6b", dict(n_layers=2)),
     "sliding_window+moe": ("mixtral-8x7b", dict(n_layers=2, window=8)),
+    "mla+moe": ("moonshot-v1-16b-a3b", dict(n_layers=3)),
 }
 
 _MODELS = {}
@@ -44,8 +54,9 @@ _MODELS = {}
 def _model(label):
     if label not in _MODELS:
         name, over = ARCHS[label]
-        cfg = dataclasses.replace(get_config(name, reduced=True),
-                                  dtype="float32", **over)
+        base = name if isinstance(name, ArchConfig) \
+            else get_config(name, reduced=True)
+        cfg = dataclasses.replace(base, dtype="float32", **over)
         m = build_model(cfg)
         _MODELS[label] = (m, m.init(jax.random.PRNGKey(0)))
     return _MODELS[label]
@@ -133,12 +144,11 @@ def test_mla_chunked_identity():
     assert chunked == mono
 
 
-@pytest.mark.parametrize("label",
-                         ["sliding_window", "mla", "mamba", "rwkv"])
+@pytest.mark.parametrize("label", ["sliding_window", "mamba", "rwkv"])
 def test_paged_backend_still_gated_for_non_positional_caches(label):
-    """The paged backend requires per-position cache rows; rings, latent
-    caches, and recurrent state stay gated (engine.ARCH_GATES) with a
-    pointer to the contiguous backend."""
+    """The paged backend requires per-position cache rows; rings and
+    recurrent state stay gated (engine.ARCH_GATES) with a pointer to the
+    contiguous backend. MLA latent rows page (tests/test_moonlight.py)."""
     model, params = _model(label)
     with pytest.raises(NotImplementedError, match="paged KV cache"):
         ServeEngine(model, params,
@@ -171,12 +181,14 @@ def test_agreement_budget_composes_multiplicatively():
     # ... which includes the paged backend's suffix continuations
     dense = dataclasses.replace(get_config("granite-3-8b", reduced=True),
                                 dtype="float32", n_layers=2)
-    moonshot = dataclasses.replace(
-        get_config("moonshot-v1-16b-a3b", reduced=True),
-        dtype="float32", n_layers=2)
+    moe = dataclasses.replace(PLAIN_MOE, dtype="float32")
     paged = ServeConfig(max_batch=2, max_len=32, scheduler="continuous",
                         kv_backend="paged", block_size=8)
-    assert agreement_budget(paged, moonshot) == AGREEMENT_BUDGETS["moe"]
+    assert agreement_budget(paged, moe) == AGREEMENT_BUDGETS["moe"]
+    moonshot = dataclasses.replace(
+        get_config("moonshot-v1-16b-a3b", reduced=True), dtype="float32")
+    assert agreement_budget(paged, moonshot) == pytest.approx(
+        AGREEMENT_BUDGETS["mla"] * AGREEMENT_BUDGETS["moe"])
     assert agreement_budget(paged, dense) == 1.0
     # legacy single-argument form (serve-config keys only) still works
     assert agreement_budget(chunked_quant) == AGREEMENT_BUDGETS["int8_kv"]

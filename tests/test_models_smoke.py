@@ -73,7 +73,8 @@ def test_prefill_decode_smoke(arch):
 
 @pytest.mark.parametrize("arch", ["granite-3-8b", "mixtral-8x7b",
                                   "rwkv6-1.6b", "minicpm3-4b",
-                                  "jamba-1.5-large-398b"])
+                                  "jamba-1.5-large-398b",
+                                  "moonshot-v1-16b-a3b"])
 def test_decode_matches_teacher_forcing(arch):
     """Prefill+decode logits equal full-sequence forward logits."""
     import dataclasses
@@ -110,9 +111,14 @@ def test_decode_matches_teacher_forcing(arch):
 
 def test_swa_ring_buffer_long_prefill():
     """Mixtral-style SWA: prefill longer than the window, then decode."""
+    import dataclasses
     cfg = get_config("mixtral-8x7b", reduced=True)
+    # serving routes dropless; the training forward it is compared with
+    # is made dropless too (capacity above the tokens)
     cfg = cfg.__class__(**{**cfg.__dict__, "dtype": "float32",
-                           "window": 8, "remat": False})
+                           "window": 8, "remat": False,
+                           "moe": dataclasses.replace(cfg.moe,
+                                                      capacity_factor=1e3)})
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     b, s = 1, 24          # 3× window

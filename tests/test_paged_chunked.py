@@ -298,8 +298,9 @@ def test_pins_survive_eviction_between_chunk_steps():
     logits = None
     for t in follow.prompt[lp:]:
         toks = jnp.asarray(np.asarray([[t]], np.int32))
-        logits, side = eng._prefill_chunk(params_tree, {"tokens": toks},
-                                          side)
+        out, side = eng._prefill_chunk(params_tree, {"tokens": toks},
+                                       side)
+        logits = out.logits
     kv.complete_chunked_admit(0, follow, lp, side, logits)
     kv.check_invariants()
     # the pinned prefix survived the eviction: the slot's first greedy

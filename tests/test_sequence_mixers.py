@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.models.mamba import init_mamba, init_mamba_state, mamba
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import init_moe, moe_apply
 from repro.models.rwkv import wkv_chunked, wkv_scan
 
 
@@ -82,7 +82,8 @@ def test_moe_output_is_gated_expert_mix(rng):
     d, ff, e = 16, 32, 4
     p = init_moe(jax.random.PRNGKey(0), d, ff, e, kind="relu")
     x = jnp.asarray(rng.normal(size=(2, 8, d)).astype(np.float32))
-    y, _ = moe_ffn(p, x, n_experts=e, top_k=e, kind="relu", dropless=True)
+    y, _, _ = moe_apply(p, x, n_experts=e, top_k=e, kind="relu",
+                        dropless=True)
     xt = x.reshape(-1, d)
     probs = jax.nn.softmax(xt @ p["router"]["w"], axis=-1)
     ref = jnp.zeros_like(xt)
@@ -98,12 +99,12 @@ def test_moe_capacity_drops_tokens(rng):
     d, ff, e = 8, 16, 4
     p = init_moe(jax.random.PRNGKey(0), d, ff, e, kind="relu")
     x = jnp.asarray(rng.normal(size=(1, 64, d)).astype(np.float32))
-    y_full, _ = moe_ffn(p, x, n_experts=e, top_k=2, kind="relu",
-                        dropless=True)
-    y_tight, _ = moe_ffn(p, x, n_experts=e, top_k=2, kind="relu",
-                         capacity_factor=0.25)
+    y_full, _, _ = moe_apply(p, x, n_experts=e, top_k=2, kind="relu",
+                             dropless=True)
+    y_tight, _, _ = moe_apply(p, x, n_experts=e, top_k=2, kind="relu",
+                              capacity_factor=0.25)
     # tight capacity must zero some token outputs that dropless serves
     changed = np.abs(np.asarray(y_full - y_tight)).max(-1) > 1e-6
     assert changed.any()
-    aux = moe_ffn(p, x, n_experts=e, top_k=2, kind="relu")[1]
+    aux = moe_apply(p, x, n_experts=e, top_k=2, kind="relu")[1]
     assert float(aux) > 0

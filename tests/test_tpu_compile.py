@@ -113,3 +113,21 @@ def test_paged_attention_compiles(spec, heads, quantized):
                                    scale=d ** -0.5, interpret=False)
 
     _compiles_with_kernel(fn, *args)
+
+
+def test_paged_attention_compiles_at_latent_width(spec):
+    """Absorbed MLA decode as the Moonlight cell runs it: 64 slots of 16
+    query heads over one latent "KV head" of 576 lanes (kv_lora 512 +
+    rope 64), read as keys and values, blocks of 16 to max_len 4096, a
+    pool of 16385 blocks."""
+    slots, heads, d, bs, context, n = 64, 16, 576, 16, 4096, 16385
+    nb = context // bs
+
+    def fn(q, pool, tables, lengths):
+        return paged_decode_pallas(q, pool, pool, tables, lengths,
+                                   scale=192 ** -0.5, interpret=False)
+
+    _compiles_with_kernel(fn, spec((slots, heads, d), jnp.bfloat16),
+                          spec((n, bs, 1, d), jnp.bfloat16),
+                          spec((slots, nb), jnp.int32),
+                          spec((slots,), jnp.int32))

@@ -11,6 +11,7 @@ import dataclasses
 import glob
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -209,3 +210,98 @@ def test_contiguous_steps_carry_no_decode_counts():
     eng.on_step = infos.append
     eng.generate(_requests()[:2])
     assert infos and not any("positions_walked" in i for i in infos)
+
+
+def _moe_tiny(seed=0):
+    """The reduced Moonlight stack holding 2 of its 4 routed experts (the
+    experts 1 and 2), with a random selection bias."""
+    cfg = get_config("moonshot-v1-16b-a3b", reduced=True)
+    cfg = dataclasses.replace(
+        cfg, dtype="float32", scan_layers=False,
+        moe=dataclasses.replace(cfg.moe, n_held=2, first_held=1))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed)
+    params = jax.tree_util.tree_map_with_path(
+        lambda p, x: jnp.asarray(rng.normal(0, 0.5, x.shape), x.dtype)
+        if getattr(p[-1], "key", None) == "score_bias" else x, params)
+    return model, params
+
+
+def _held_pairs(model, params, seq):
+    """Host recount: per position of ``seq``, the choices of the held
+    experts in every MoE layer (the reference's hidden states and
+    router)."""
+    from repro.models import reference as ref
+    cfg = model.cfg
+    f32 = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jnp.asarray(a, jnp.float32), t)
+    out = np.zeros(len(seq), np.int64)
+    lo, hi = cfg.moe.first_held, cfg.moe.first_held + cfg.moe.held
+    with jax.default_matmul_precision("highest"):
+        x = f32(params["embedding"]["embedding"])[jnp.asarray([seq])]
+        for p, use_moe in ref._layers(params["stack"]):
+            p = f32(p)
+            if use_moe:
+                h = x + ref.mla(ref.rms(x, p["ln1"]["gain"]), p["attn"], cfg)
+                hn = ref.rms(h, p["ln2"]["gain"])
+                _, idx = ref.route(hn @ p["moe"]["router"]["w"],
+                                   p["moe"]["router"]["score_bias"],
+                                   cfg.moe)
+                idx = np.asarray(idx[0])
+                out += ((idx >= lo) & (idx < hi)).sum(-1)
+            x = ref.block(x, p, cfg, use_moe)
+    return out
+
+
+@pytest.mark.parametrize("chunk", [0, 4])
+def test_moe_counters_match_a_host_recount(chunk):
+    """Held pairs and held-expert rows per decode launch and prefill
+    chunk: summed, they equal a host recount of the router's choices over
+    every prompt position and every decoded token (the last token of a
+    request is sampled, never fed back); ``stats()`` holds the running
+    sums and each step's info only that step's share."""
+    model, params = _moe_tiny()
+    eng = _paged(model, params, prefill_chunk=chunk)
+    kv = eng.scheduler.kv
+    launches = []
+    decode = kv.decode
+
+    def count_decode(params_, nxt, active_ids):
+        launches.append(len(active_ids))
+        decode(params_, nxt, active_ids)
+    kv.decode = count_decode
+    infos = []
+    eng.on_step = infos.append
+    reqs = _requests()
+    outs = eng.generate(reqs)
+    want_pairs = sum(int(_held_pairs(model, params,
+                                     list(r.prompt) + o.tokens[:-1]).sum())
+                     for r, o in zip(reqs, outs))
+    moe_layers = model.cfg.n_layers - model.cfg.first_k_dense
+    want_rows = moe_layers * 2 * (len(launches) * 2
+                                  + sum(len(r.prompt) for r in reqs))
+    st = eng.scheduler.stats().kv
+    assert st["moe_pairs_here"] == want_pairs > 0
+    assert st["moe_rows_computed"] == want_rows
+    # a held pair is a computed row, and most rows carry none
+    assert st["moe_pairs_here"] < st["moe_rows_computed"]
+    steps = [(i["moe_pairs_here"], i["moe_rows_computed"]) for i in infos]
+    assert sum(p for p, _ in steps) == want_pairs
+    assert sum(r for _, r in steps) == want_rows
+    # per step, not running sums: the last step carries the last launch
+    assert 0 < steps[-1][1] < want_rows
+    eng.close()
+
+
+def test_dense_stack_counts_no_expert_pairs():
+    model, params = _tiny(0)
+    eng = _paged(model, params)
+    infos = []
+    eng.on_step = infos.append
+    eng.generate(_requests())
+    assert infos and all(i["moe_pairs_here"] == i["moe_rows_computed"] == 0
+                         for i in infos)
+    st = eng.scheduler.stats().kv
+    assert st["moe_pairs_here"] == st["moe_rows_computed"] == 0
+    eng.close()
