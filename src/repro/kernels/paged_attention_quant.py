@@ -8,8 +8,8 @@ Dequantization happens *inside VMEM* after the scalar-prefetched
 block-table gather — the ``kernels/dequant_matmul.py`` idiom applied to
 attention:
 
-* each grid step loads a physical block's ``(bs, KV, D)`` int8 codes and
-  its ``(bs, KV)`` scales, and dequantizes head ``h``'s ``(bs, D)`` code
+* each step copies a group of physical blocks' ``(bs, KV, D)`` int8
+  codes and their ``(bs, KV)`` scales, and dequantizes head ``h``'s code
   tiles in VMEM (``codes * scale`` per row) before the QK and PV dots.
   The kernel is :func:`repro.kernels.paged_attention.paged_decode_pallas`
   with ``scales`` given: one gather and one online softmax for both pool

@@ -490,8 +490,14 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
     """Multi-head latent attention over a cache of latent rows.
 
     Each position caches one row ``kv_norm(c_kv) ‖ rope(k_rope)`` of width
-    ``kv_lora + rope_dim``, as one "KV head": ``(B, T, 1, L+R)`` in a
-    contiguous cache, ``(num_blocks, block_size, 1, L+R)`` in a paged pool.
+    ``kv_lora + rope_dim``: ``(B, T, W)`` in a contiguous cache,
+    ``(num_blocks, block_size, W)`` in a paged pool, read as one "KV
+    head". ``W`` is the width rounded up to whole 128-lane tiles, the
+    lanes past it zero (:func:`latent_store_width`): a row the tiles fit
+    exactly keeps the pool row-major on the device, where the row scatter
+    writes it in place and the decode kernel copies its pages as they
+    are; a 576-wide row is stored position-minor and converted in and out
+    of every step. Zero lanes add exact zeros to every score and value.
     RMS norm is per position, so the normed latent is what every read
     would compute. Train/prefill/chunk expand the latent to per-head K/V;
     decode either expands it or (``absorb``, and always on the paged pool)
@@ -516,18 +522,25 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
 
     q_rope = apply_rotary(q_rope, cos_t, sin_t)
     k_rope = apply_rotary(k_rope, cos_t, sin_t)
+    width = m.kv_lora + m.rope_dim
     lat = jnp.concatenate(
-        [rms_norm(params["kv_norm"], c_kv)[:, :, None, :],
-         k_rope.astype(c_kv.dtype)], axis=-1)          # (B,S,1,L+R)
+        [rms_norm(params["kv_norm"], c_kv),
+         k_rope[:, :, 0].astype(c_kv.dtype)], axis=-1)  # (B,S,L+R)
+    lat_row = jnp.pad(lat, ((0, 0), (0, 0),
+                            (0, latent_store_width(m) - width)))
+
+    def wide(qa):                        # absorbed queries over stored rows
+        return jnp.pad(qa, ((0, 0), (0, 0), (0, lat_row.shape[-1] - width)))
 
     def expand_kv(lat_in):
         t = lat_in.shape[1]
-        kv = linear(params["kv_up"], lat_in[:, :, 0, :m.kv_lora])
+        kv = linear(params["kv_up"], lat_in[..., :m.kv_lora])
         kv = kv.reshape(b, t, h, m.nope_dim + m.v_dim)
         k_nope, v = kv[..., :m.nope_dim], kv[..., m.nope_dim:]
         k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(lat_in[..., m.kv_lora:].astype(
-                k_nope.dtype), (b, t, h, m.rope_dim))], axis=-1)
+            [k_nope, jnp.broadcast_to(
+                lat_in[:, :, None, m.kv_lora:width].astype(k_nope.dtype),
+                (b, t, h, m.rope_dim))], axis=-1)
         return k, v
 
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -544,7 +557,7 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
         if mode == "prefill":
             cache = dict(cache)
             cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["latent"], lat.astype(cache["latent"].dtype), 0, 1)
+                cache["latent"], lat_row.astype(cache["latent"].dtype), 0, 1)
     elif mode == "chunk":
         # partial-prefill continuation, mirroring the dense chunk path:
         # write the chunk's latent rows at the clock, re-expand the WHOLE
@@ -555,9 +568,8 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
         # served under the "mla" agreement budget (measured exact)
         cache = dict(cache)
         cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["latent"], lat.astype(cache["latent"].dtype), pos, 1)
-        lat_all = shard_act(cache["latent"],
-                            ("batch", "seq_shard", None, None))
+            cache["latent"], lat_row.astype(cache["latent"].dtype), pos, 1)
+        lat_all = shard_act(cache["latent"], ("batch", "seq_shard", None))
         k, v = expand_kv(lat_all.astype(x.dtype))
         out = _chunked_attention(qfull, k, v, scale=scale, causal=True,
                                  window=None, q_chunk=cfg.attn_q_chunk,
@@ -571,28 +583,28 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
         phys = block_tables[rows, pos // bs_blk]
         cache = dict(cache)
         cache["latent"] = cache["latent"].at[phys, pos % bs_blk].set(
-            lat[:, 0].astype(cache["latent"].dtype))
+            lat_row[:, 0].astype(cache["latent"].dtype))
         from repro.kernels.paged_attention import paged_attention
-        pool = cache["latent"]
+        pool = cache["latent"][:, :, None]
         out = _mla_absorbed_decode(
             params, qfull, m, h, scale,
-            lambda qa: paged_attention(qa, pool, pool, block_tables, pos,
-                                       scale=scale))
+            lambda qa: paged_attention(wide(qa), pool, pool, block_tables,
+                                       pos, scale=scale))
     else:                            # contiguous decode at clock ``pos``
         cache = dict(cache)
         cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["latent"], lat.astype(cache["latent"].dtype), pos, 1)
-        lat_all = shard_act(cache["latent"],
-                            ("batch", "seq_shard", None, None))
+            cache["latent"], lat_row.astype(cache["latent"].dtype), pos, 1)
+        lat_all = shard_act(cache["latent"], ("batch", "seq_shard", None))
         t = lat_all.shape[1]
         mask = jnp.where(jnp.arange(t) <= pos, 0.0,
                          NEG_INF)[None, None, None, None, :]
         lat_all = lat_all.astype(x.dtype)
         if m.absorb:
+            heads = lat_all[:, :, None]                 # one "KV head"
             out = _mla_absorbed_decode(
                 params, qfull, m, h, scale,
-                lambda qa: _grouped_attention(qa[:, None], lat_all, lat_all,
-                                              mask, scale)[:, 0])
+                lambda qa: _grouped_attention(wide(qa)[:, None], heads,
+                                              heads, mask, scale)[:, 0])
         else:
             k, v = expand_kv(lat_all)
             out = _grouped_attention(qfull, k, v, mask, scale)
@@ -622,7 +634,8 @@ def _mla_absorbed_decode(params, qfull, m: MLAConfig, h: int, scale,
 
     ``attend(qa)`` takes the absorbed queries ``(B, H, L+R)`` against the
     latent rows ``n(c_s) ‖ k_rope_s`` (their first ``L`` lanes are the
-    values) and returns ``(B, H, L+R)``. Per step this costs
+    values) and returns ``(B, H, W)`` for rows ``W`` lanes wide, of which
+    the first ``L`` are used. Per step this costs
     O(H·kv_lora·(nope+v)) for the two absorptions plus O(S·H·kv_lora) for
     attention — no cache re-expansion.
     """
@@ -637,10 +650,15 @@ def _mla_absorbed_decode(params, qfull, m: MLAConfig, h: int, scale,
     return out[:, None]                            # (B,1,H,v)
 
 
+def latent_store_width(m: MLAConfig) -> int:
+    """Lanes a cached latent row takes: ``kv_lora + rope_dim`` rounded up
+    to whole 128-lane tiles (576 → 640)."""
+    return -(-(m.kv_lora + m.rope_dim) // 128) * 128
+
+
 def init_mla_cache(batch: int, max_len: int, cfg, dtype=jnp.bfloat16):
-    m = cfg.mla
-    return {"latent": jnp.zeros((batch, max_len, 1, m.kv_lora + m.rope_dim),
-                                dtype)}
+    return {"latent": jnp.zeros((batch, max_len,
+                                 latent_store_width(cfg.mla)), dtype)}
 
 
 def cross_attention(params, x, enc_out, *, cfg,

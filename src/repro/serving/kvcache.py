@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import positions_walked
+
 # host spans on the profiler's clock (free while no trace is being taken)
 _span = jax.profiler.TraceAnnotation
 
@@ -463,11 +465,12 @@ class PagedKVCache(KVCache):
         self.evictions = 0
         self.peak_blocks_active = 0
         # decode launches: context positions the active slots attend, and
-        # the positions the decode program walks (every row of the block
-        # tables it is handed, to their end); cumulative, and since the
-        # last ``decode_positions``
+        # the positions the decode program walks (the kernel: each row's
+        # live page groups; the reference: every table to its end);
+        # cumulative, and since the last ``decode_positions``
         self.positions_attended = self.positions_walked = 0
         self._step_attended = self._step_walked = 0
+        self._pool_row = None          # (KV, D, itemsize) of attention rows
         # MoE stacks, per decode launch and per prefill chunk: token-expert
         # pairs routed to the experts held here, and the rows the held
         # experts' matmuls process (held experts x capacity, dropless);
@@ -878,22 +881,44 @@ class PagedKVCache(KVCache):
             # snapshots, not views: the device arrays may alias host memory
             # (zero-copy transfer) and ``_lengths``/``_tables`` are mutated
             # right after dispatch — aliasing would race the async decode
-            tables = self._tables.copy()
-            self._cache["pos"] = jnp.asarray(self._lengths.copy())
-            self._cache["block_tables"] = jnp.asarray(tables)
+            pos = self._lengths.copy()
+            self._cache["pos"] = jnp.asarray(pos)
+            self._cache["block_tables"] = jnp.asarray(self._tables.copy())
             out, self._cache = self.eng._decode(params, nxt[:, None],
                                                 self._cache)
             self._logits = out.logits
             self.note_moe(out.moe_pairs, self.max_slots)
             self._lengths[active_ids] += 1
         # slot b attends its lengths[b] + 1 positions (now its length); the
-        # program walks the whole table it was handed
+        # program walks what the attention backend walks of every row
         attended = int(self._lengths[active_ids].sum())
-        walked = tables.size * bs
+        walked = self._walked(pos)
         self.positions_attended += attended
         self.positions_walked += walked
         self._step_attended += attended
         self._step_walked += walked
+
+    def _walked(self, pos) -> int:
+        """Pool positions one decode launch walks, per the backend that
+        ``paged_attention`` resolves to (one walk stands for every layer:
+        their pools share one row shape). A stack without attention rows
+        counts its tables."""
+        if self._pool_row is None:
+            self._pool_row = ()
+            leaves = jax.tree_util.tree_flatten_with_path(self._cache)[0]
+            for path, leaf in leaves:
+                key = getattr(path[-1], "key", None)
+                if key in ("k", "latent"):
+                    # MLA's latent rows are one "KV head" of their width
+                    kv = 1 if key == "latent" else leaf.shape[-2]
+                    self._pool_row = (kv, leaf.shape[-1], leaf.dtype.itemsize)
+                    break
+        if not self._pool_row:
+            return pos.size * self.nb_per_slot * self.block_size
+        kv, d, itemsize = self._pool_row
+        return positions_walked(pos, self.nb_per_slot,
+                                block_size=self.block_size, kv=kv, d=d,
+                                itemsize=itemsize)
 
     def decode_positions(self) -> Dict[str, int]:
         self._pull_moe()

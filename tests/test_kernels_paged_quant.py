@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_walk import paged_walk_case
 from repro.kernels.paged_attention import paged_attention_ref
 from repro.kernels.paged_attention_quant import (paged_attention_quant,
                                                  paged_attention_quant_ref)
@@ -30,17 +31,33 @@ def _rand_quant_pools(key, nblocks, bs, kv, d):
 # interpret-mode parity vs the jnp reference
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rep", [1, 2, 4])      # MQA → GQA head ratios
-def test_quant_kernel_matches_ref(rep):
+@pytest.mark.parametrize("rep,case", [             # MQA → GQA head ratios
+    pytest.param(1, "mixed", id="1"),
+    pytest.param(2, "mixed", id="2"),
+    pytest.param(4, "mixed", id="4"),
+    # lengths 0, P*bs - 1, P*bs and nb*bs - 1, nb not a multiple of P
+    pytest.param(2, "edges", id="edges"),
+    # the same, with every position no slot attends (the trash block, the
+    # last page past each length) holding extreme codes and NaN and 1e30
+    # scales
+    pytest.param(2, "poison", id="poison"),
+])
+def test_quant_kernel_matches_ref(rep, case):
     key = jax.random.PRNGKey(0)
     b, kv, d, bs, nb_slot, nblocks = 3, 2, 16, 4, 6, 20
     h = kv * rep
     ks_ = jax.random.split(key, 3)
+    if case == "mixed":
+        bt = jax.random.randint(ks_[2], (b, nb_slot), 1, nblocks) \
+            .astype(jnp.int32)
+        lengths = jnp.asarray([0, 7, 21], jnp.int32)   # mixed fills
+    else:
+        bs = 8
+        bt, lengths, nblocks, dead, _ = paged_walk_case(kv, d, bs, 1)
+        b = len(lengths)
+        bt, lengths = jnp.asarray(bt), jnp.asarray(lengths)
     q = jax.random.normal(ks_[0], (b, h, d), jnp.float32)
     _, _, kq, ksc, vq, vsc = _rand_quant_pools(ks_[1], nblocks, bs, kv, d)
-    bt = jax.random.randint(ks_[2], (b, nb_slot), 1, nblocks) \
-        .astype(jnp.int32)
-    lengths = jnp.asarray([0, 7, 21], jnp.int32)   # mixed fills
     scale = 1.0 / np.sqrt(d)
     ref = paged_attention_quant_ref(q, kq, vq, ksc, vsc, bt, lengths,
                                     scale=scale)
@@ -48,6 +65,14 @@ def test_quant_kernel_matches_ref(rep):
                                 scale=scale, use_pallas="interpret")
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    if case == "poison":
+        bad = jnp.asarray(dead)[:, :, None]
+        out = paged_attention_quant(
+            q, jnp.where(bad[..., None], jnp.int8(127), kq),
+            jnp.where(bad[..., None], jnp.int8(-127), vq),
+            jnp.where(bad, jnp.nan, ksc), jnp.where(bad, 1e30, vsc), bt,
+            lengths, scale=scale, use_pallas="interpret")
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ker))
 
 
 def test_quant_ref_masks_trash_columns_and_scales():

@@ -19,6 +19,7 @@ import pytest
 from repro.configs import get_config
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServeConfig, ServeEngine
+from paged_walk import paged_walk_case
 
 
 def _tiny(seed=0, vocab=256, **over):
@@ -210,25 +211,47 @@ def test_oversized_request_rejected_against_pool():
 # gather-attention kernel parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rep", [1, 4])
-def test_paged_attention_kernel_matches_ref(rep):
+@pytest.mark.parametrize("rep,case", [
+    pytest.param(1, "mixed", id="1"),
+    pytest.param(4, "mixed", id="4"),
+    # lengths 0, P*bs - 1, P*bs and nb*bs - 1, nb not a multiple of P
+    pytest.param(2, "edges", id="edges"),
+    # the same, with every position no slot attends (the trash block, the
+    # last page past each length) holding NaN keys and 1e30 values
+    pytest.param(2, "poison", id="poison"),
+])
+def test_paged_attention_kernel_matches_ref(rep, case):
     from repro.kernels.paged_attention import (paged_attention,
                                                paged_attention_ref)
     key = jax.random.PRNGKey(0)
-    b, kv, d, bs, nb_slot, nblocks = 3, 2, 16, 4, 6, 20
-    h = kv * rep
     ks = jax.random.split(key, 4)
+    kv, d, bs = 2, 16, 4
+    if case == "mixed":
+        b, nb_slot, nblocks = 3, 6, 20
+        bt = jax.random.randint(ks[3], (b, nb_slot), 1,
+                                nblocks).astype(jnp.int32)
+        lengths = jnp.asarray([0, 7, 21], jnp.int32)  # mixed fills
+    else:
+        bs = 8
+        bt, lengths, nblocks, dead, _ = paged_walk_case(kv, d, bs, 4)
+        b = len(lengths)
+        bt, lengths = jnp.asarray(bt), jnp.asarray(lengths)
+    h = kv * rep
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
     k_pool = jax.random.normal(ks[1], (nblocks, bs, kv, d), jnp.float32)
     v_pool = jax.random.normal(ks[2], (nblocks, bs, kv, d), jnp.float32)
-    bt = jax.random.randint(ks[3], (b, nb_slot), 1, nblocks).astype(jnp.int32)
-    lengths = jnp.asarray([0, 7, 21], jnp.int32)      # mixed fills
     scale = 1.0 / np.sqrt(d)
     ref = paged_attention_ref(q, k_pool, v_pool, bt, lengths, scale=scale)
     ker = paged_attention(q, k_pool, v_pool, bt, lengths, scale=scale,
                           use_pallas="interpret")
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    if case == "poison":
+        bad = jnp.asarray(dead)[:, :, None, None]
+        out = paged_attention(q, jnp.where(bad, jnp.nan, k_pool),
+                              jnp.where(bad, 1e30, v_pool), bt, lengths,
+                              scale=scale, use_pallas="interpret")
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ker))
 
 
 def test_paged_attention_ref_masks_trash_columns():

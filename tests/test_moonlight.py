@@ -16,10 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_walk import paged_walk_case
 from repro.configs import get_config
 from repro.kernels.paged_attention import (paged_attention_ref,
                                            paged_decode_pallas)
 from repro.models import moe as moe_lib
+from repro.models.attention import latent_store_width
 from repro.models import reference
 from repro.models.model import build_model
 from repro.serving.engine import Request, ServeConfig, ServeEngine
@@ -266,7 +268,8 @@ def test_donated_pool_serves_the_same_tokens(arch):
 
 def test_latent_rows_page_and_stay_bounded():
     """The paged pool holds one latent row per position: width kv_lora +
-    rope, one "head" (the pool is ``model.init_cache(blocks, block_size)``)."""
+    rope rounded up to whole 128-lane tiles, with no head axis (the pool
+    is ``model.init_cache(blocks, block_size)``)."""
     cfg = _cfg()
     eng = ServeEngine(build_model(cfg), _params(cfg), ServeConfig(
         scheduler="continuous", max_slots=2, max_len=32,
@@ -275,9 +278,10 @@ def test_latent_rows_page_and_stay_bounded():
     pool = eng.scheduler.kv._cache
     m = cfg.mla
     assert pool["lead"][0]["latent"].shape == \
-        (2 * 8 + 1, 4, 1, m.kv_lora + m.rope_dim)
+        (2 * 8 + 1, 4, latent_store_width(m))
+    assert latent_store_width(m) == -(-(m.kv_lora + m.rope_dim) // 128) * 128
     assert eng.scheduler.kv.bytes_per_position() == \
-        cfg.n_layers * (m.kv_lora + m.rope_dim) * 4
+        cfg.n_layers * latent_store_width(m) * 4
     eng.close()
 
 
@@ -292,22 +296,41 @@ def test_mla_gates(over, match):
             scheduler="continuous", max_slots=2, max_len=32, **over))
 
 
-@pytest.mark.parametrize("heads,context", [(16, 64), (4, 48)])
+@pytest.mark.parametrize("heads,context", [
+    (16, 64), (4, 48),
+    # lengths 0, P*bs - 1, P*bs and nb*bs - 1, nb not a multiple of P
+    pytest.param(16, "edges", id="16-edges"),
+    # the same, with every position no slot attends (the trash block, the
+    # last page past each length) holding NaN and 1e30 lanes
+    pytest.param(16, "poison", id="16-poison"),
+])
 def test_paged_kernel_at_latent_width(heads, context):
     """The gather kernel (interpret mode) over a latent pool of width 576,
     read as keys and as values, against the jnp gather."""
     rng = np.random.default_rng(heads)
     slots, bs, d = 3, 16, 576
-    nb = context // bs
-    n = slots * nb + 1
+    if isinstance(context, int):
+        nb = context // bs
+        n = slots * nb + 1
+        tables = jnp.asarray(rng.permutation(np.arange(1, n))
+                             .reshape(slots, nb), jnp.int32)
+        lengths = jnp.asarray([context - 1, 5, 17], jnp.int32)
+    else:
+        tables, lengths, n, dead, _ = paged_walk_case(1, d, bs, 4)
+        slots = len(lengths)
+        tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
     pool = jnp.asarray(rng.normal(size=(n, bs, 1, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(slots, heads, d)), jnp.float32)
-    tables = jnp.asarray(rng.permutation(np.arange(1, n))
-                         .reshape(slots, nb), jnp.int32)
-    lengths = jnp.asarray([context - 1, 5, 17], jnp.int32)
     scale = 192 ** -0.5
     got = paged_decode_pallas(q, pool, pool, tables, lengths, scale=scale,
                               interpret=True)
     want = paged_attention_ref(q, pool, pool, tables, lengths, scale=scale)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+    if context == "poison":
+        lanes = np.where(np.arange(d) % 2, np.nan, 1e30)
+        bad = jnp.asarray(np.where(dead[:, :, None, None], lanes,
+                                   np.asarray(pool)), jnp.float32)
+        out = paged_decode_pallas(q, bad, bad, tables, lengths, scale=scale,
+                                  interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(got))

@@ -131,3 +131,45 @@ def test_paged_attention_compiles_at_latent_width(spec):
                           spec((n, bs, 1, d), jnp.bfloat16),
                           spec((slots, nb), jnp.int32),
                           spec((slots,), jnp.int32))
+
+
+@pytest.mark.parametrize("s", [1, 4])           # decode; verify of 4 rows
+def test_paged_attention_compiles_at_chat_shapes(spec, s):
+    """One layer's call in the chat cell: 64 slots of 32 query heads over
+    8 KV heads of 128, tables of 192 blocks of 16, a pool of 4097 blocks.
+    With ``s`` > 1, the speculative verify's call: row ``b*s+j`` per slot
+    and draft position, each slot's table repeated ``s`` times."""
+    slots, heads, kv, d, bs, nb, n = 64, 32, 8, 128, 16, 192, 4097
+
+    def fn(q, k, v, tables, lengths):
+        return paged_decode_pallas(q, k, v, jnp.repeat(tables, s, axis=0),
+                                   lengths, scale=d ** -0.5,
+                                   interpret=False)
+
+    _compiles_with_kernel(fn, spec((slots * s, heads, d), jnp.bfloat16),
+                          spec((n, bs, kv, d), jnp.bfloat16),
+                          spec((n, bs, kv, d), jnp.bfloat16),
+                          spec((slots, nb), jnp.int32),
+                          spec((slots * s,), jnp.int32))
+
+
+def test_paged_attention_compiles_at_stored_latent_width(spec):
+    """The Moonlight cell's call as the model makes it: latent rows stored
+    640 lanes wide (576 rounded up to whole lane tiles, read as keys and
+    values), queries padded to match; the kernel copies the pool's pages
+    with no copy of the pool before it."""
+    slots, heads, d, bs, context, n = 64, 16, 640, 16, 4096, 16385
+    nb = context // bs
+
+    def fn(q, pool, tables, lengths):
+        return paged_decode_pallas(q, pool, pool, tables, lengths,
+                                   scale=192 ** -0.5, interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        spec((slots, heads, d), jnp.bfloat16),
+        spec((n, bs, 1, d), jnp.bfloat16), spec((slots, nb), jnp.int32),
+        spec((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not any(op in line for line in text.splitlines()
+                   if f"bf16[{n}," in line for op in (" copy(", " pad("))

@@ -202,6 +202,36 @@ def test_decode_counts_positions_attended_and_walked(chunk):
     assert 0 < st["positions_attended"] < st["positions_walked"]
 
 
+def test_decode_walk_counts_the_kernels_live_groups(monkeypatch):
+    """Where ``paged_attention`` resolves to the kernel (interpret mode
+    here, the compiled kernel on a TPU), a launch walks each row's live
+    page groups: ``ceil((len + 1) / (P * bs)) * P * bs`` positions for a
+    row at position ``len``, retired and empty rows (at 0) one group."""
+    import repro.kernels.paged_attention as pa
+    auto = pa.resolve
+    monkeypatch.setattr(pa, "resolve", lambda use: "interpret"
+                        if use == "auto" else auto(use))
+    model, params = _tiny(0)
+    eng = _paged(model, params, max_len=512, prefill_chunk=0)
+    kv = eng.scheduler.kv
+    p = pa.pages_per_step(kv.block_size, 1, 16, 4, kv.nb_per_slot)
+    group = p * kv.block_size
+    assert p < kv.nb_per_slot
+    positions, program = [], eng._decode
+
+    def record_program(params_, tok, cache):
+        positions.append(np.asarray(cache["pos"]))
+        return program(params_, tok, cache)
+
+    eng._decode = record_program
+    eng.generate(_requests())
+    assert positions and any((pos == 0).any() for pos in positions)
+    want = [int(sum(-(-(int(n) + 1) // group) for n in pos)) * group
+            for pos in positions]
+    assert kv.stats()["positions_walked"] == sum(want)
+    assert want[0] < len(positions[0]) * kv.nb_per_slot * kv.block_size
+
+
 def test_contiguous_steps_carry_no_decode_counts():
     model, params = _tiny(0)
     eng = ServeEngine(model, params, ServeConfig(
