@@ -8,7 +8,7 @@ import time
 
 
 TABLES = ("accuracy", "ablation", "adaround", "time", "approx_precision",
-          "kernels", "roofline", "reload", "serving")
+          "kernels", "reload", "serving")
 
 
 def main() -> None:

@@ -20,11 +20,14 @@ reassembles from the index and reshards to the *current* mesh
 elastic restarts (different chip counts) work.
 
 Quantized checkpoints (``save_serving`` with ``quant_meta``) hold the
-serving-format ``w_q``/``w_q4``/``w_scale`` trees from ``quant.apply``
-natively — int4 nibbles stay packed two-per-int8-byte on disk — and record
-``{"format": "quantized", "quant": {bits, method, group_size, report…}}``
-in ``index.json`` so restore can refuse a tree that does not match the
-requested serve config instead of silently dequantizing garbage.
+``QuantizedTensor`` trees ``core.pipeline.quantize_tree`` returns natively:
+each tensor's ``data`` and ``scale`` arrays are payload entries
+(``…/w/data``, ``…/w/scale``; int4 nibbles stay packed two-per-int8-byte
+on disk) and its ``bits``/``group_size``/``shape`` are listed under
+``qtensors`` in ``index.json``, beside ``{"format": "quantized", "quant":
+{bits, method, group_size, report…}}`` so restore can refuse a tree that
+does not match the requested serve config instead of silently
+dequantizing garbage.
 """
 from __future__ import annotations
 
@@ -38,6 +41,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.quant.qtypes import QuantizedTensor
+
 
 class CheckpointMetaError(ValueError):
     """A checkpoint's index.json is unreadable or contradicts the caller's
@@ -45,13 +50,30 @@ class CheckpointMetaError(ValueError):
 
 
 def _flatten(tree: Any):
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    """``({"a/b/w": leaf}, treedef)``; a ``QuantizedTensor`` is one leaf."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        tree, is_leaf=lambda x: isinstance(x, QuantizedTensor))
     out = {}
     for keypath, leaf in flat:
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                        for k in keypath)
         out[key] = leaf
     return out, treedef
+
+
+def quant_tree_meta(bits: int, method: str, group_size: Optional[int],
+                    report=None) -> dict:
+    """``quant_meta`` for :meth:`Checkpointer.save_serving`: the bits/method
+    contract restore validates against, plus a digest of the
+    ``QuantReport`` of the ``quantize_tree`` call that made the tree."""
+    meta = {"bits": bits, "method": method, "group_size": group_size,
+            "packed_int4": bits <= 4}
+    if report is not None:
+        meta["report"] = {"layers": len(report.layers),
+                          "total_ms": report.total_millis,
+                          "backend": report.backend,
+                          "mesh_size": report.mesh_size}
+    return meta
 
 
 class Checkpointer:
@@ -78,15 +100,24 @@ class Checkpointer:
 
     def save_serving(self, step: int, params: Any,
                      quant_meta: Optional[Dict[str, Any]] = None):
-        """Save a serving weight tree (fp, or a quantized qdict tree from
-        ``quant.apply.quantize_params_sharded``).
+        """Save a serving weight tree: fp, or with ``QuantizedTensor``
+        leaves (``quantize_tree(..., dequantize=False)``).
 
-        ``quant_meta`` marks the checkpoint as quantized and must carry at
-        least ``bits`` and ``method`` (plus group_size / QuantReport digest);
-        packed int4 codes are written as-is — nibbles stay packed on disk.
+        ``quant_meta`` (:func:`quant_tree_meta`) marks the checkpoint as
+        quantized and must carry at least ``bits`` and ``method``; a tree
+        with ``QuantizedTensor`` leaves needs it. Packed int4 codes are
+        written as-is — nibbles stay packed on disk.
         """
         flat, _ = _flatten(params)
-        payload = {f"params::{k}": np.asarray(v) for k, v in flat.items()}
+        payload, qtensors = {}, {}
+        for k, v in flat.items():
+            if isinstance(v, QuantizedTensor):
+                payload[f"params::{k}/data"] = np.asarray(v.data)
+                payload[f"params::{k}/scale"] = np.asarray(v.scale)
+                qtensors[k] = {"bits": v.bits, "group_size": v.group_size,
+                               "shape": list(v.shape)}
+            else:
+                payload[f"params::{k}"] = np.asarray(v)
         meta = {"step": step, "trees": {"params": {"keys": sorted(flat)}},
                 "format": "fp", "quant": None}
         if quant_meta is not None:
@@ -95,6 +126,10 @@ class Checkpointer:
                 raise ValueError(f"quant_meta missing {sorted(missing)}")
             meta["format"] = "quantized"
             meta["quant"] = dict(quant_meta)
+            meta["qtensors"] = qtensors
+        elif qtensors:
+            raise ValueError("a tree with QuantizedTensor leaves needs "
+                             "quant_meta")
         self.wait()
         self._dispatch(step, payload, meta)
 
@@ -231,11 +266,14 @@ class Checkpointer:
             return self._restore_raw(steps[-1])
         return self.restore(steps[-1], shardings, template)
 
-    def _restore_raw(self, step: int, to_jax: bool = True):
+    def _restore_raw(self, step: int, to_jax: bool = True,
+                     qtensors: Optional[Dict[str, Any]] = None):
         """Tree-structure-free restore (single-host): rebuilds nested dicts
-        from the flat key paths. ``to_jax=False`` keeps leaves as host numpy
-        arrays (for callers that place them on devices themselves — one
-        transfer instead of commit-to-default-device-then-reshard)."""
+        from the flat key paths, and a ``QuantizedTensor`` at each key of
+        ``qtensors`` (index.json's listing) from its data and scale.
+        ``to_jax=False`` keeps leaves as host numpy arrays (for callers
+        that place them on devices themselves — one transfer instead of
+        commit-to-default-device-then-reshard)."""
         d = os.path.join(self.dir, f"step_{step:08d}")
         data = np.load(os.path.join(d, "shard_00000.npz"))
         conv = jax.numpy.asarray if to_jax else (lambda a: a)
@@ -250,6 +288,15 @@ class Checkpointer:
         for full_key in data.files:
             name, key = full_key.split("::", 1)
             insert(trees[name], key.split("/"), conv(data[full_key]))
+        for key, q in (qtensors or {}).items():
+            *parents, last = key.split("/")
+            node = trees["params"]
+            for p in parents:
+                node = node[p]
+            node[last] = QuantizedTensor(
+                data=node[last]["data"], scale=node[last]["scale"],
+                bits=q["bits"], group_size=q["group_size"],
+                shape=tuple(q["shape"]))
 
         def listify(node):
             """Convert dicts with integer-contiguous keys back to lists."""
@@ -282,9 +329,10 @@ class Checkpointer:
         garbage. fp checkpoints always pass (the caller re-quantizes).
 
         ``mesh``: reshard-on-restore — every leaf is ``device_put`` onto the
-        current mesh's parameter shardings (``distributed.sharding`` rules,
-        which cover ``w_q``/``w_q4``/``w_scale`` leaves; bit-exact for any
-        device count because the full logical arrays live on disk). Leaves
+        current mesh's parameter shardings (``distributed.sharding`` rules;
+        ``QuantizedTensor`` codes and scales take
+        ``quantized_tensor_shardings``; bit-exact for any device count
+        because the full logical arrays live on disk). Leaves
         stay on the host until the single placing transfer — a full tree is
         never first committed to one default device.
         """
@@ -309,7 +357,8 @@ class Checkpointer:
                     f"step {step} quant metadata mismatch: checkpoint is "
                     f"{quant.get('method')} w{quant.get('bits')}, serve "
                     f"config requests {want_m} w{want_b}")
-        params, _, _ = self._restore_raw(step, to_jax=False)
+        params, _, _ = self._restore_raw(step, to_jax=False,
+                                         qtensors=meta.get("qtensors"))
         if mesh is not None:
             from repro.distributed.sharding import reshard_serving_tree
             params = reshard_serving_tree(params, mesh)

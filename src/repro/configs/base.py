@@ -2,7 +2,7 @@
 
 Every assigned architecture is an ``ArchConfig`` instance (exact published
 dimensions); ``reduced()`` derives the small same-family variant used by CPU
-smoke tests (full configs are exercised only via the dry-run).
+smoke tests.
 """
 from __future__ import annotations
 
@@ -77,17 +77,13 @@ class ArchConfig:
     norm_plus_one: bool = False         # gemma: (1+g) RMSNorm
     tie_embeddings: bool = True
     dtype: str = "bfloat16"
-    sub_quadratic: bool = False         # eligible for long_500k
-    # memory knobs (defaults; per-cell overrides in launch/dryrun.py)
+    # memory knobs
     remat: bool = True
     scan_layers: bool = True
     # chunk sizes bounding working sets (seq must divide cleanly)
     attn_q_chunk: int = 1024
     mamba_chunk: int = 512
     rwkv_chunk: int = 32
-    # costing mode: python-loop the chunk/microbatch scans so XLA
-    # cost_analysis sees every iteration (it does not multiply loop trips)
-    unroll_chunks: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:

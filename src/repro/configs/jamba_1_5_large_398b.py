@@ -11,6 +11,5 @@ CONFIG = ArchConfig(
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2),
     moe=MoEConfig(n_experts=16, top_k=2, every=2),
     ffn_kind="swiglu", rope_theta=10_000.0,
-    sub_quadratic=True,
     tie_embeddings=False,
 )

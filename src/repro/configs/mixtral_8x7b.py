@@ -9,5 +9,4 @@ CONFIG = ArchConfig(
     window=4096,
     moe=MoEConfig(n_experts=8, top_k=2, every=1),
     ffn_kind="swiglu", rope_theta=10_000.0,
-    sub_quadratic=True,   # SWA ⇒ O(window) decode state
 )

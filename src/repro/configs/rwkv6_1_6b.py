@@ -7,6 +7,5 @@ CONFIG = ArchConfig(
     n_layers=24, d_model=2048, n_heads=32, n_kv_heads=32,
     d_ff=7168, vocab=65_536,
     rwkv=True, rwkv_head_dim=64,
-    sub_quadratic=True,
     tie_embeddings=False,
 )

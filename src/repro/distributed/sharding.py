@@ -20,7 +20,6 @@ FSDP-only and the expert ffn dim takes TP instead).
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -29,6 +28,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import compat
+from repro.quant.qtypes import QuantizedTensor
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,8 +132,7 @@ def shard_logits(logits: jax.Array) -> jax.Array:
     """LM-head logits: vocab-sharded over 'model' when divisible (the
     matmul-natural layout from the sharded embedding), otherwise
     sequence-sharded over 'model' — never replicate a (B, S, V) f32 tensor
-    (a 200 GB/chip blow-up for 256k-vocab non-divisible models; found by
-    the dry-run)."""
+    (a 200 GB/chip blow-up for 256k-vocab non-divisible models)."""
     mesh = _current_mesh()
     if mesh is None or getattr(mesh, "empty", True) or mesh.size == 1:
         return logits
@@ -168,48 +167,45 @@ _PARAM_RULES = AxisRules(rules=AxisRules().rules + (
 ))
 
 
+def _pad(spec: Tuple, rank: int) -> Tuple:
+    """Prepend Nones for the stack dim(s) so spec matches rank."""
+    return (None,) * (rank - len(spec)) + spec if len(spec) < rank else spec
+
+
+def _kernel_rules(path: Tuple[str, ...]) -> Tuple[Optional[Tuple], bool]:
+    """``(spec, transposes)`` for a kernel ``w`` at ``path``: its logical
+    axes in (in, out) order (``(E, in, out)`` for an expert bank), or None
+    to replicate; ``transposes`` is False where the spec does not follow
+    the kernel's orientation (the decay LoRA's)."""
+    parent = path[-2] if len(path) >= 2 else ""
+    if parent == "w" or parent == "":
+        return None, False
+    if parent in ("w_lora_a", "w_lora_b"):
+        # rwkv decay LoRA: ~0.26 M params/layer — replicating them and
+        # duplicating the tiny matmul removes a (B,S,d) psum + the
+        # surrounding reshard per layer
+        return ("fsdp", None), False
+    if "lm_head" in path:
+        return ("fsdp", "vocab"), True
+    if "moe" in path:                          # expert bank (E, in, out)
+        return ("experts", "fsdp", "expert_ff"), True
+    key = parent
+    if key == "wv" and "cm" in path:
+        key = "wv_cm"                          # rwkv channel-mix down-proj
+    return ((_TP, "fsdp") if key in _ROW_PARALLEL else ("fsdp", _TP)), True
+
+
 def param_sharding_rules(path: Tuple[str, ...], leaf: Any) -> Tuple:
     """Logical axes for a parameter leaf."""
-    shape = getattr(leaf, "shape", ())
-    rank = len(shape)
+    rank = len(getattr(leaf, "shape", ()))
     name = path[-1] if path else ""
-    parent = path[-2] if len(path) >= 2 else ""
-
-    def pad(spec: Tuple) -> Tuple:
-        """Prepend Nones for the stack dim(s) so spec matches rank."""
-        if len(spec) < rank:
-            return (None,) * (rank - len(spec)) + spec
-        return spec
-
     if name == "embedding":
-        return pad(("vocab", "fsdp"))
+        return _pad(("vocab", "fsdp"), rank)
     if name == "conv_w":
-        return pad((None, "ff"))
-    if name in ("w", "w_q", "w_q4", "w_scale"):
-        if parent == "w" or parent == "":
-            return (None,) * rank
-        if parent in ("w_lora_a", "w_lora_b") and \
-                not os.environ.get("REPRO_LORA_TP"):
-            # rwkv decay LoRA: ~0.26 M params/layer — replicating them and
-            # duplicating the tiny matmul removes a (B,S,d) psum + the
-            # surrounding reshard per layer (§Perf hillclimb: the
-            # most-collective-bound cell). REPRO_LORA_TP=1 restores the
-            # naive TP sharding for the before/after measurement.
-            return pad(("fsdp", None))
-        if "lm_head" in path:
-            spec = ("fsdp", "vocab")
-        elif "moe" in path:                    # expert bank (E, in, out)
-            spec = ("experts", "fsdp", "expert_ff")
-        else:
-            key = parent
-            if key == "wv" and "cm" in path:
-                key = "wv_cm"                  # rwkv channel-mix down-proj
-            spec = (_TP, "fsdp") if key in _ROW_PARALLEL else ("fsdp", _TP)
-        if name in ("w_q", "w_q4"):            # quantized codes: (out, in)
-            spec = spec[:-2] + (spec[-1], spec[-2])
-        elif name == "w_scale":                # (out, 1)
-            spec = spec[:-2] + (spec[-1], None)
-        return pad(spec)
+        return _pad((None, "ff"), rank)
+    if name == "w":
+        spec, _ = _kernel_rules(path)
+        return (None,) * rank if spec is None else _pad(spec, rank)
     # vectors / norm gains / lerp factors / u bonus: replicate
     return (None,) * rank
 
@@ -234,95 +230,42 @@ def quantized_tensor_shardings(mesh: Mesh, path: Tuple[str, ...], qt
                                ) -> Tuple[NamedSharding, NamedSharding]:
     """(codes, scale) NamedShardings for a pipeline ``QuantizedTensor``.
 
-    ``path`` is the source kernel's tree path (…, parent, "w"); the codes and
-    scales inherit the *serving-format* rules the same kernel would get as
-    plain ``w_q``/``w_q4``/``w_scale`` arrays (transposed Megatron placement,
-    divisibility fallbacks included). The pipeline's 2-D carriers collapse
-    any stacked dims (experts, scan periods, conv taps) into the row dim, so
-    rules written for the full stacked rank keep their trailing (row, col)
-    entries — non-divisible collapsed dims fall back to replication inside
-    ``logical_to_mesh``.
+    ``path`` is the source kernel's tree path (…, parent, "w"). Codes are
+    (out, in)-major, so they take the kernel's rules transposed (Megatron
+    placement, divisibility fallbacks included); scales, (out, groups),
+    take the out-dim entry alone. The pipeline's 2-D carriers collapse
+    any stacked dims (experts, scan periods, conv taps) into the row dim,
+    so only the spec's trailing (in, out) entries apply — non-divisible
+    collapsed dims fall back to replication inside ``logical_to_mesh``.
     """
-    qname = "w_q4" if qt.packed else "w_q"
-    logical_q = param_sharding_rules(path[:-1] + (qname,), qt.data)
-    logical_s = param_sharding_rules(path[:-1] + ("w_scale",), qt.scale)
-    logical_q = tuple(logical_q)[-qt.data.ndim:]
-    logical_s = tuple(logical_s)[-qt.scale.ndim:]
+    spec, transposes = _kernel_rules(path)
+    if spec is None:
+        logical_q, logical_s = (None,) * qt.data.ndim, (None,) * qt.scale.ndim
+    elif transposes:
+        logical_q, logical_s = (spec[-1], spec[-2]), (spec[-1], None)
+    else:
+        logical_q = logical_s = spec[-2:]
     spec_q = logical_to_mesh(logical_q, qt.data.shape, mesh, _PARAM_RULES)
     spec_s = logical_to_mesh(logical_s, qt.scale.shape, mesh, _PARAM_RULES)
     return NamedSharding(mesh, spec_q), NamedSharding(mesh, spec_s)
 
 
 def reshard_serving_tree(tree: Any, mesh: Mesh) -> Any:
-    """Place every leaf of a serving weight tree (fp params or the quantized
-    ``w_q``/``w_q4``/``w_scale`` qdict format) onto ``mesh``'s parameter
-    shardings — the reshard-on-restore path: a tree checkpointed from an
-    8-device mesh lands bit-exactly on a 1- or 2-device mesh because the
-    checkpoint holds full logical arrays and ``device_put`` only re-splits
-    them. Asynchronous (no host sync)."""
-    shardings = make_param_shardings(mesh, tree)
-    return jax.tree_util.tree_map(jax.device_put, tree, shardings)
-
-
-# ---------------------------------------------------------------------------
-# Cache sharding rules (serving)
-# ---------------------------------------------------------------------------
-
-_CACHE_LOGICAL: Dict[str, Tuple] = {
-    "k":       ("batch", "seq_cache", "kv_heads", "head_dim"),
-    "v":       ("batch", "seq_cache", "kv_heads", "head_dim"),
-    "k_scale": ("batch", "seq_cache", "kv_heads"),
-    "v_scale": ("batch", "seq_cache", "kv_heads"),
-    "c_kv":    ("batch", "seq_cache", "mla_rank"),
-    "k_rope":  ("batch", "seq_cache", None, None),
-    "h":       ("batch", "d_inner", None),
-    "conv":    ("batch", None, "d_inner"),
-    "wkv":     ("batch", "heads", None, None),
-    "x_tm":    ("batch", None),
-    "x_cm":    ("batch", None),
-    "enc_out": ("batch", None, None),
-}
-
-# Paged pools put K/V in (num_blocks, block_size, kv_heads, head_dim):
-# the pool dim takes the DP axes (each device holds a slice of the block
-# pool — the paged analogue of sequence parallelism; block tables index
-# logically so the gather reshards transparently under GSPMD), while the
-# tiny block_size dim is never split.
-_PAGED_CACHE_LOGICAL: Dict[str, Tuple] = {
-    "k":       ("kv_blocks", None, "kv_heads", "head_dim"),
-    "v":       ("kv_blocks", None, "kv_heads", "head_dim"),
-    "k_scale": ("kv_blocks", None, "kv_heads"),
-    "v_scale": ("kv_blocks", None, "kv_heads"),
-}
-
-_CACHE_RULES = AxisRules(rules=(
-    # long-context SP: the cache sequence dim takes whatever DP axes the
-    # (possibly tiny) batch left unused — 500k decode shards its KV over them
-    ("seq_cache", (("pod", "data"), ("data",), None)),
-    ("kv_blocks", (("pod", "data"), ("data",), None)),
-    ("mla_rank",  (("model",), None)),
-    ("d_inner",   (("model",), None)),
-    ("head_dim",  (("model",), None)),
-) + AxisRules().rules)
-
-
-def make_cache_shardings(mesh: Mesh, cache_shape: Any,
-                         paged: bool = False) -> Any:
-    table = _PAGED_CACHE_LOGICAL if paged else _CACHE_LOGICAL
-
-    def one(keypath, leaf):
+    """Place every leaf of a serving weight tree (fp params, or
+    ``QuantizedTensor`` leaves through :func:`quantized_tensor_shardings`)
+    onto ``mesh``'s parameter shardings — the reshard-on-restore path: a
+    tree checkpointed from an 8-device mesh lands bit-exactly on a 1- or
+    2-device mesh because the checkpoint holds full logical arrays and
+    ``device_put`` only re-splits them. Asynchronous (no host sync)."""
+    def place(keypath, leaf):
         path = tuple(str(getattr(k, "key", getattr(k, "idx", k)))
                      for k in keypath)
-        name = path[-1] if path else ""
-        logical = table.get(name)
-        if logical is None and paged:
-            logical = _CACHE_LOGICAL.get(name)
-        if logical is None:
-            logical = (None,) * len(leaf.shape)
-        if len(logical) != len(leaf.shape):
-            stack = len(leaf.shape) - len(logical)
-            logical = (None,) * stack + tuple(logical)
-        spec = logical_to_mesh(logical, leaf.shape, mesh, _CACHE_RULES)
-        return NamedSharding(mesh, spec)
+        if isinstance(leaf, QuantizedTensor):
+            return leaf.with_placement(
+                *quantized_tensor_shardings(mesh, path, leaf))
+        spec = logical_to_mesh(param_sharding_rules(path, leaf), leaf.shape,
+                               mesh, _PARAM_RULES)
+        return jax.device_put(leaf, NamedSharding(mesh, spec))
 
-    return jax.tree_util.tree_map_with_path(one, cache_shape)
+    return jax.tree_util.tree_map_with_path(
+        place, tree, is_leaf=lambda x: isinstance(x, QuantizedTensor))

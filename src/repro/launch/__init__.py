@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/quantize CLIs."""
+"""Launchers: production mesh, train/serve/quantize CLIs."""

@@ -1,8 +1,7 @@
 """Production mesh factories.
 
 Functions, not module-level constants — importing this module never touches
-jax device state (required: the dry-run overrides the device count before
-any jax initialization, and smoke tests must keep seeing one device).
+jax device state (smoke tests must keep seeing one device).
 """
 from __future__ import annotations
 

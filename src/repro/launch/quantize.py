@@ -14,13 +14,14 @@ import dataclasses
 import json
 import os
 
-from repro.checkpoint.checkpointer import Checkpointer
+from repro.checkpoint.checkpointer import Checkpointer, quant_tree_meta
 from repro.configs import get_config, list_archs
 from repro.core.dispatch import BACKENDS
 from repro.core.pipeline import quantize_tree
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import init_params
 from repro.models.model import build_model
+from repro.serving.weights import unstacked
 
 
 def main():
@@ -53,10 +54,11 @@ def main():
     ap.add_argument("--out", default="/tmp/repro_quantized")
     ap.add_argument("--serving-ckpt", default=None, metavar="DIR",
                     help="additionally write a *native* quantized serving "
-                         "checkpoint (w_q/w_q4+w_scale qdict tree, int4 "
-                         "kept packed on disk, quant metadata in "
-                         "index.json) that repro.launch.serve "
-                         "--reload-from hot-loads without re-quantizing")
+                         "checkpoint (QuantizedTensor leaves on the "
+                         "unrolled layer stack, int4 kept packed on disk, "
+                         "quant metadata in index.json) that "
+                         "repro.launch.serve --reload-from hot-loads "
+                         "without re-quantizing")
     ap.add_argument("--serving-step", type=int, default=0,
                     help="step number for --serving-ckpt (watchers reload "
                          "steps in increasing order)")
@@ -108,20 +110,19 @@ def main():
     print(f"[quantize] wrote {args.out}")
 
     if args.serving_ckpt:
-        # the serving checkpoint needs the qdict layout (stack dims kept,
-        # plain shardable arrays), which only the serving-format quantizer
-        # emits — a separate pass from the pipeline run above, so its
-        # metadata records this pass's own timing rather than the batched
-        # run's backend/mesh digest.
-        from repro.quant.apply import quantize_params_serving
-        qserve, meta = quantize_params_serving(params, args.bits,
-                                               method=args.method,
-                                               group_size=args.group_size)
+        # what a real-code serving engine serves: integer codes on the
+        # unrolled layer stack
+        qserve, srep = quantize_tree(unstacked(params, cfg),
+                                     method=args.method, bits=args.bits,
+                                     group_size=args.group_size,
+                                     backend=args.backend,
+                                     batched=not args.serial, mesh=mesh)
         Checkpointer(args.serving_ckpt, async_save=False).save_serving(
-            args.serving_step, qserve, quant_meta=meta)
+            args.serving_step, qserve,
+            quant_meta=quant_tree_meta(args.bits, args.method,
+                                       args.group_size, report=srep))
         print(f"[quantize] wrote serving checkpoint step "
-              f"{args.serving_step} → {args.serving_ckpt} "
-              f"({meta['leaf_format']})")
+              f"{args.serving_step} → {args.serving_ckpt}")
 
 
 if __name__ == "__main__":

@@ -2,8 +2,7 @@
 
 Container scale: reduced configs train for real on CPU (synthetic/Markov
 data) with the full fault-tolerance path (checkpoint/restart, straggler
-monitor). Production scale: the same step lowered in launch/dryrun.py runs
-unchanged on a real mesh — pass --production to build the 16×16(-per-pod)
+monitor). Production scale: pass --production to build the 16×16(-per-pod)
 mesh and shard params/opt/data with the framework rules.
 
 Examples:

@@ -218,8 +218,7 @@ Q_CHUNK = 1024   # query-block size bounding the (B,H,Cq,T) score tensor
 
 def _chunked_attention(q, k, v, *, scale, causal: bool,
                        window: Optional[int] = None,
-                       q_chunk: int = Q_CHUNK,
-                       unroll: bool = False, row0=0) -> jnp.ndarray:
+                       q_chunk: int = Q_CHUNK, row0=0) -> jnp.ndarray:
     """Train/prefill attention: KV repeated to H heads (so scores shard over
     the TP axis) and queries processed in blocks — the (B, H, Cq, T) block,
     not (B, H, S, T), bounds the working set. Softmax sees the full key axis
@@ -255,17 +254,13 @@ def _chunked_attention(q, k, v, *, scale, causal: bool,
         return block(q, row0)
     nc = s // q_chunk
     qr = q.reshape(b, nc, q_chunk, h, dq).swapaxes(0, 1)
-    if unroll:
-        outs = jnp.stack([block(qr[i], row0 + i * q_chunk)
-                          for i in range(nc)])
-    else:
-        offs = row0 + jnp.arange(nc) * q_chunk
+    offs = row0 + jnp.arange(nc) * q_chunk
 
-        def body(_, qc_off):
-            qc, off = qc_off
-            return 0, block(qc, off)
+    def body(_, qc_off):
+        qc, off = qc_off
+        return 0, block(qc, off)
 
-        _, outs = jax.lax.scan(body, 0, (qr, offs))
+    _, outs = jax.lax.scan(body, 0, (qr, offs))
     return outs.swapaxes(0, 1).reshape(b, s, h, v.shape[-1])
 
 
@@ -332,8 +327,7 @@ def attention(params, x, *, cfg, rope, mode: str = "train",
     if mode in ("train", "prefill"):
         out = _chunked_attention(q, k, v, scale=scale, causal=True,
                                  window=cfg.window,
-                                 q_chunk=cfg.attn_q_chunk,
-                                 unroll=cfg.unroll_chunks)
+                                 q_chunk=cfg.attn_q_chunk)
         if mode == "prefill":
             cache = _cache_write(cache, k, v, 0, cfg.window)
     elif mode == "chunk" and cfg.window:
@@ -375,8 +369,7 @@ def attention(params, x, *, cfg, rope, mode: str = "train",
         vc = shard_act(vc, ("batch", "seq_shard", "kv_heads", None))
         out = _chunked_attention(q, kc.astype(q.dtype), vc.astype(q.dtype),
                                  scale=scale, causal=True, window=None,
-                                 q_chunk=cfg.attn_q_chunk,
-                                 unroll=cfg.unroll_chunks, row0=pos)
+                                 q_chunk=cfg.attn_q_chunk, row0=pos)
     elif mode == "verify":  # paged multi-position verify: pos is (B,)
         # speculative decoding's verifier forward: slot b's S tokens are
         # written through the block table at absolute positions
@@ -552,8 +545,7 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
         k, v = expand_kv(lat)
         out = _chunked_attention(qfull, k, v, scale=scale, causal=True,
                                  window=cfg.window,
-                                 q_chunk=cfg.attn_q_chunk,
-                                 unroll=cfg.unroll_chunks)
+                                 q_chunk=cfg.attn_q_chunk)
         if mode == "prefill":
             cache = dict(cache)
             cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
@@ -573,7 +565,7 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
         k, v = expand_kv(lat_all.astype(x.dtype))
         out = _chunked_attention(qfull, k, v, scale=scale, causal=True,
                                  window=None, q_chunk=cfg.attn_q_chunk,
-                                 unroll=cfg.unroll_chunks, row0=pos)
+                                 row0=pos)
     elif block_tables is not None:   # paged decode: s == 1, pos is (B,)
         # the new latent row lands through the table (retired slots write
         # the trash block); attention runs absorbed over the latent pool,
@@ -613,15 +605,10 @@ def _mla_attention(params, x, *, cfg, rope, mode, cache, pos,
 
 
 def _kv_up_weight(params, m: MLAConfig, h: int, dtype):
-    """``kv_up`` as (kv_lora, H, nope + v), from any weight format."""
-    p = params["kv_up"]
-    if "w_q" in p or "w_q4" in p:
-        from repro.quant.apply import dequant_kernel
-        w = dequant_kernel(p, dtype).T
-    else:
-        w = p["w"]
-        if hasattr(w, "dequantize"):                   # (out, in) codes
-            w = w.dequantize(dtype).T
+    """``kv_up`` as (kv_lora, H, nope + v), fp or quantized."""
+    w = params["kv_up"]["w"]
+    if hasattr(w, "dequantize"):                       # (out, in) codes
+        w = w.dequantize(dtype).T
     return w.astype(dtype).reshape(m.kv_lora, h, m.nope_dim + m.v_dim)
 
 
@@ -676,6 +663,5 @@ def cross_attention(params, x, enc_out, *, cfg,
                       _grouped_attention(q, k, v, mask, hd ** -0.5)
                       .reshape(b, s, cfg.n_heads * hd))
     out = _chunked_attention(q, k, v, scale=hd ** -0.5, causal=False,
-                             q_chunk=cfg.attn_q_chunk,
-                             unroll=cfg.unroll_chunks)
+                             q_chunk=cfg.attn_q_chunk)
     return linear(params["wo"], out.reshape(b, s, cfg.n_heads * hd))

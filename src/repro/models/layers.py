@@ -23,15 +23,8 @@ def _init_dense(key, d_in: int, d_out: int, dtype=jnp.float32,
 
 
 def linear(params, x: jnp.ndarray, use_kernel: str = "auto") -> jnp.ndarray:
-    """x @ W. Accepts three kernel formats:
-    * ``{"w": (in, out) float}`` — dense;
-    * ``{"w": QuantizedTensor}`` — single-host quantized (Pallas path);
-    * ``{"w_q"/"w_q4", "w_scale"}`` — sharded quantized serving format
-      (dequant-on-the-fly; GSPMD shards the int codes)."""
-    if "w_q" in params or "w_q4" in params:
-        from repro.quant.apply import dequant_kernel
-        w = dequant_kernel(params, x.dtype)               # (out, in)
-        return x @ w.T
+    """x @ W, for ``{"w": (in, out) float}`` (dense) or
+    ``{"w": QuantizedTensor}`` (dequant-on-the-fly matmul)."""
     w = params["w"]
     if isinstance(w, QuantizedTensor):
         from repro.kernels import ops                     # lazy import

@@ -84,7 +84,7 @@ def _chunk_scan(a_t, bx, h0):
 
 def mamba(params, x: jnp.ndarray, *, d_state: int = 16,
           state: Optional[Dict] = None, mode: str = "train",
-          chunk: int = 512, unroll: bool = False
+          chunk: int = 512
           ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """x: (B, S, D) for train/prefill; (B, 1, D) for decode.
 
@@ -112,20 +112,10 @@ def mamba(params, x: jnp.ndarray, *, d_state: int = 16,
                                 c.astype(jnp.float32))
                 return h_out, yc.astype(x.dtype)
 
-            if not unroll:
-                # remat per chunk: the scan's backward otherwise stores the
-                # (B, C, DI, N) f32 chunk-state residuals for every chunk —
-                # tens of GB/chip at jamba scale (found by the dry-run).
-                body = jax.checkpoint(body)
-            if unroll:
-                ys = []
-                h_last = h0
-                for i in range(nc):
-                    h_last, yc = body(h_last, xc_r[i])
-                    ys.append(yc)
-                ys = jnp.stack(ys)
-            else:
-                h_last, ys = jax.lax.scan(body, h0, xc_r)
+            # remat per chunk: the scan's backward otherwise stores the
+            # (B, C, DI, N) f32 chunk-state residuals for every chunk —
+            # tens of GB/chip at jamba scale
+            h_last, ys = jax.lax.scan(jax.checkpoint(body), h0, xc_r)
             y = ys.swapaxes(0, 1).reshape(b, s, d_inner)
         else:
             a_t, bx, c = _ssm_inputs(params, xc, dt_rank, d_state)

@@ -1,7 +1,7 @@
 """Model builder: ArchConfig → init / train_loss / prefill / decode_step.
 
 The returned ``LM`` object is the single interface used by the trainer, the
-serving engine, the quantization pipeline, and the multi-pod dry-run.
+serving engine and the quantization pipeline.
 """
 from __future__ import annotations
 
@@ -273,30 +273,9 @@ class LM:
         return logits, caches
 
     # ---------------------------------------------------------------- specs
-    def input_specs(self, shape) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for one step's data inputs."""
-        b, s = shape.global_batch, shape.seq_len
-        tok = jax.ShapeDtypeStruct((b, s), jnp.int32)
-        if shape.kind == "train":
-            spec = {"tokens": tok, "labels": tok}
-        elif shape.kind == "prefill":
-            spec = {"tokens": tok}
-        else:  # decode
-            spec = {"tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32)}
-        if self.cfg.is_encdec and shape.kind != "decode":
-            dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else jnp.float32
-            spec["enc_frames"] = jax.ShapeDtypeStruct(
-                (b, max(1, s // self.cfg.enc_ratio), self.cfg.d_model), dt)
-        return spec
-
     def param_shapes(self, key=None) -> Any:
         key = key if key is not None else jax.random.PRNGKey(0)
         return jax.eval_shape(self.init, key)
-
-    def cache_shapes(self, batch: int, max_len: int,
-                     quantize_kv: bool = False) -> Any:
-        return jax.eval_shape(
-            lambda: self.init_cache(batch, max_len, quantize_kv=quantize_kv))
 
 
 def build_model(cfg) -> LM:

@@ -52,10 +52,6 @@ def init_moe(key, d_model: int, d_ff: int, n_experts: int,
 
 def _expert_matmul(bank, x):
     """x: (E, C, din) @ bank (E, din, dout) → (E, C, dout)."""
-    if "w_q" in bank or "w_q4" in bank:              # sharded quant format
-        from repro.quant.apply import dequant_kernel
-        wd = dequant_kernel(bank, x.dtype)           # (E, out, in)
-        return jnp.einsum("ecd,efd->ecf", x, wd)
     w = bank["w"]
     if hasattr(w, "dequantize"):                     # QuantizedTensor
         e = x.shape[0]
@@ -134,7 +130,7 @@ def moe_apply(params, x: jnp.ndarray, *, n_experts: int, top_k: int,
 
     Long sequences are processed in TOKEN_CHUNK blocks (scan): the dense
     (T, E, C) dispatch one-hots are quadratic-ish in T and reached
-    129 GB/device at the 32k-prefill cells (found by the dry-run).
+    129 GB/device at 32k-token prefills.
     Capacity competition becomes per-block — the standard microbatched-MoE
     behaviour of production serving stacks.
     """
@@ -193,7 +189,7 @@ def moe_apply(params, x: jnp.ndarray, *, n_experts: int, top_k: int,
 
         # dispatch (T, E, C) / combine (T, E, C) — accumulated over the K
         # routing slots to avoid materializing a (T, K, E, C) tensor (a
-        # 12 GB blow-up for moonshot-sized cells; found by the dry-run).
+        # 12 GB blow-up for moonshot-sized cells).
         disp = jnp.zeros((t, n_held, capacity), x.dtype)
         comb = jnp.zeros((t, n_held, capacity), x.dtype)
         for kk in range(top_k):

@@ -87,8 +87,7 @@ def wkv_scan(r, k, v, logw, u, s0):
     return o.swapaxes(0, 1).swapaxes(1, 2), s_fin       # (B, H, S, D)
 
 
-def wkv_chunked(r, k, v, logw, u, s0, chunk: int = 32,
-                unroll: bool = False):
+def wkv_chunked(r, k, v, logw, u, s0, chunk: int = 32):
     """Chunkwise-parallel WKV. Same signature as wkv_scan."""
     b, h, s, d = r.shape
     assert s % chunk == 0, f"seq {s} % chunk {chunk}"
@@ -116,23 +115,15 @@ def wkv_chunked(r, k, v, logw, u, s0, chunk: int = 32,
         return a.reshape(b, h, n, chunk, d).swapaxes(0, 2).swapaxes(1, 2)
 
     xs = tuple(resh(a) for a in (r, k, v, logw))
-    if unroll:
-        os_ = []
-        s_fin = s0
-        for i in range(n):
-            s_fin, oc = per_chunk(s_fin, tuple(a[i] for a in xs))
-            os_.append(oc)
-        o = jnp.stack(os_)
-    else:
-        # remat per chunk: bounds backward residuals to one chunk's
-        # (B,H,L,L)+(B,H,L,D) working set instead of all n chunks'
-        s_fin, o = jax.lax.scan(jax.checkpoint(per_chunk), s0, xs)
+    # remat per chunk: bounds backward residuals to one chunk's
+    # (B,H,L,L)+(B,H,L,D) working set instead of all n chunks'
+    s_fin, o = jax.lax.scan(jax.checkpoint(per_chunk), s0, xs)
     o = o.swapaxes(1, 2).swapaxes(0, 2).reshape(b, h, s, d)
     return o, s_fin
 
 
 def rwkv_timemix(p, x, *, head_dim: int = 64, state: Optional[Dict] = None,
-                 mode: str = "train", chunk: int = 32, unroll: bool = False
+                 mode: str = "train", chunk: int = 32
                  ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     b, s, d = x.shape
     h = d // head_dim
@@ -163,7 +154,7 @@ def rwkv_timemix(p, x, *, head_dim: int = 64, state: Optional[Dict] = None,
         o, s_fin = wkv_scan(rf, kf, vf, logw, u, s0)
     elif mode in ("train", "prefill", "chunk"):
         if s % chunk == 0:
-            o, s_fin = wkv_chunked(rf, kf, vf, logw, u, s0, chunk, unroll)
+            o, s_fin = wkv_chunked(rf, kf, vf, logw, u, s0, chunk)
         else:
             o, s_fin = wkv_scan(rf, kf, vf, logw, u, s0)
     o = o.swapaxes(1, 2).reshape(b, s, d).astype(x.dtype)

@@ -110,7 +110,6 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
         h, st_tm = rwkv_lib.rwkv_timemix(
             p["tm"], rms_norm(p["ln1"], x, plus_one=cfg.norm_plus_one),
             head_dim=cfg.rwkv_head_dim, chunk=cfg.rwkv_chunk,
-            unroll=cfg.unroll_chunks,
             state=cache, mode=mode)
         x = x + h
         h, st_cm = rwkv_lib.rwkv_channelmix(
@@ -131,7 +130,7 @@ def apply_block(p, x, *, cfg, kind: str, use_moe: bool, rope, mode: str,
         h, new_cache = mamba_lib.mamba(
             p["mixer"], rms_norm(p["ln1"], x, plus_one=cfg.norm_plus_one),
             d_state=cfg.mamba.d_state, state=cache, mode=mode,
-            chunk=cfg.mamba_chunk, unroll=cfg.unroll_chunks)
+            chunk=cfg.mamba_chunk)
     x = x + h
     x = shard_act(x, ("batch", None, None))
     if "xattn" in p:
@@ -211,6 +210,17 @@ def unstack_stack(stack: Dict[str, Any], periods: int) -> Dict[str, Any]:
     out = {k: v for k, v in stack.items() if k != "periods"}
     out["list"] = [jax.tree_util.tree_map(lambda a: a[i], stack["periods"])
                    for i in range(periods)]
+    return out
+
+
+def stack_periods(stack: Dict[str, Any]) -> Dict[str, Any]:
+    """Inverse of :func:`unstack_stack`: {"list": [...]} → {"periods":
+    stacked} (fp leaves only)."""
+    if "list" not in stack:
+        return stack
+    out = {k: v for k, v in stack.items() if k != "list"}
+    out["periods"] = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                            *stack["list"])
     return out
 
 

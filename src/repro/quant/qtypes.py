@@ -120,6 +120,13 @@ class QuantizedTensor:
             bits=self.bits, group_size=self.group_size, shape=self.shape)
 
 
+def is_quantized_tree(tree) -> bool:
+    """True if any leaf of ``tree`` is a :class:`QuantizedTensor`."""
+    leaves = jax.tree_util.tree_leaves(
+        tree, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+    return any(isinstance(x, QuantizedTensor) for x in leaves)
+
+
 # ---------------------------------------------------------------------------
 # Quantization-run reports (filled by core.pipeline.quantize_tree)
 # ---------------------------------------------------------------------------

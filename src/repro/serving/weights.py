@@ -24,9 +24,9 @@ Model
   (``checkpoint.Checkpointer`` layout). New COMMITTED steps are restored
   (torn/corrupt step dirs are skipped), validated against the serve
   config's quant expectations, and staged: quantized checkpoints
-  (``w_q``/``w_q4``/``w_scale`` serving trees) are served directly with no
-  re-quantization; fp checkpoints go through the store's quantize_fn
-  (the batched/sharded ``quantize_tree`` path).
+  (``QuantizedTensor`` trees) are served with no re-quantization, through
+  the store's prepare_fn; fp checkpoints go through the store's
+  quantize_fn (the batched/sharded ``quantize_tree`` path).
 """
 from __future__ import annotations
 
@@ -52,7 +52,7 @@ class WeightVersion:
     pair a new verifier with an old drafter. None when the store has no
     draft pipeline (speculation off)."""
     version: int                       # monotonically increasing, from 1
-    params: Any                        # serving tree (fp, fake-quant, qdict…)
+    params: Any                        # serving tree (fp, fake-quant, codes)
     report: Optional[QuantReport] = None
     source: str = "init"               # "init" | "ckpt:<step>" | caller tag
     step: Optional[int] = None         # checkpoint step, when applicable
@@ -60,42 +60,79 @@ class WeightVersion:
     draft_params: Any = None           # speculative drafter tree (or None)
 
 
+def serves_codes(cfg) -> bool:
+    """True when a ``ServeConfig`` serves real integer codes
+    (``QuantizedTensor`` leaves): the layer stack is then unrolled, since
+    those leaves cannot be scanned over — standard for serving anyway."""
+    return bool(cfg.quantize_weights) and not cfg.dequantize_for_compute
+
+
+def unstacked(tree, model_cfg):
+    """``tree`` with a scanned layer stack (``stack/periods``) unrolled into
+    ``stack/list`` — the layout real-code serving quantizes and serves."""
+    from repro.models.transformer import n_periods, unstack_stack
+    if isinstance(tree, dict) and "periods" in tree.get("stack", {}):
+        tree = dict(tree)
+        tree["stack"] = unstack_stack(tree["stack"], n_periods(model_cfg))
+    return tree
+
+
 def make_weight_pipeline(model, cfg):
     """``(model', quantize_fn, prepare_fn)`` for a ``ServeConfig``.
 
-    ``model'`` is rebuilt with the layer stack unrolled for real-quantized
-    serving (QuantizedTensor leaves cannot be scanned over — standard for
-    serving anyway). ``quantize_fn`` maps an fp tree to
+    ``model'`` is rebuilt with the layer stack unrolled when real codes are
+    served (:func:`serves_codes`). ``quantize_fn`` maps an fp tree to
     ``(serving_tree, QuantReport | None)`` per the config (identity when
-    ``cfg.quantize_weights`` is None). ``prepare_fn`` normalizes an
-    *already-quantized* serving tree (a ``w_q``/``w_q4`` qdict restored from
-    a checkpoint) for ``model'`` — identity unless the stack was unrolled.
+    ``cfg.quantize_weights`` is None). ``prepare_fn`` turns a restored
+    quantized checkpoint (``QuantizedTensor`` leaves on the unrolled stack)
+    into ``model'``'s serving tree: as it is for real codes; for
+    fake-quant serving, each leaf dequantized into its fp layout and the
+    periods re-stacked — the tree quantize-on-load gives.
     """
     from repro.core.pipeline import quantize_tree
     from repro.models.model import build_model
-    from repro.models.transformer import n_periods, unstack_stack
 
     base_cfg = model.cfg
-    unroll = bool(cfg.quantize_weights) and not cfg.dequantize_for_compute
-    if unroll:
+    codes = serves_codes(cfg)
+    if codes:
         model = build_model(dataclasses.replace(base_cfg, scan_layers=False))
-
-    def _unstack(tree):
-        if isinstance(tree, dict) and "periods" in tree.get("stack", {}):
-            tree = dict(tree)
-            tree["stack"] = unstack_stack(tree["stack"], n_periods(base_cfg))
-        return tree
 
     def quantize_fn(fp_tree):
         if not cfg.quantize_weights:
             return fp_tree, None
-        if unroll:
-            fp_tree = _unstack(fp_tree)
+        if codes:
+            fp_tree = unstacked(fp_tree, base_cfg)
         return quantize_tree(fp_tree, method=cfg.quantize_weights,
                              bits=cfg.weight_bits,
                              dequantize=cfg.dequantize_for_compute)
 
-    return model, quantize_fn, (_unstack if unroll else (lambda t: t))
+    def prepare_fn(tree):
+        return tree if codes else _dequantized(tree, model)
+
+    return model, quantize_fn, prepare_fn
+
+
+def _dequantized(tree, model):
+    """The fp tree for ``model`` (its own stack layout) of a tree whose
+    ``QuantizedTensor`` leaves sit on the unrolled stack: each leaf goes
+    through the fake-quant restore ``quantize_tree(dequantize=True)``
+    applies, into its fp leaf's layout and dtype."""
+    from repro.core.pipeline import _restore_dense
+    from repro.models.transformer import stack_periods
+    from repro.quant.qtypes import QuantizedTensor, is_quantized_tree
+
+    if not is_quantized_tree(tree):
+        return tree
+    shapes = model.param_shapes()
+    tree = jax.tree_util.tree_map(
+        lambda x, leaf: _restore_dense(x.dequantize(leaf.dtype),
+                                       tuple(leaf.shape))
+        if isinstance(x, QuantizedTensor) else x,
+        tree, jax.eval_shape(lambda t: unstacked(t, model.cfg), shapes),
+        is_leaf=lambda x: isinstance(x, QuantizedTensor))
+    if "periods" in shapes["stack"]:
+        tree = dict(tree, stack=stack_periods(tree["stack"]))
+    return tree
 
 
 def make_draft_quantize_fn(model, cfg):
@@ -103,25 +140,19 @@ def make_draft_quantize_fn(model, cfg):
 
     The drafter is the same checkpoint quantized at ``cfg.draft_bits``
     (data-free, sub-second — SQuant makes draft models free), prepared
-    for the SAME model the target pipeline serves: the unroll decision
-    mirrors :func:`make_weight_pipeline` so both trees match the (possibly
-    scan-unrolled) serving stack. When the target serves fp
+    for the SAME model the target pipeline serves (:func:`serves_codes`
+    decides the stack layout for both). When the target serves fp
     (``quantize_weights`` None) the drafter still quantizes — the ladder
     needs a cheaper tree below the verifier — defaulting to 'squant'.
     """
     from repro.core.pipeline import quantize_tree
-    from repro.models.transformer import n_periods, unstack_stack
 
-    base_cfg = model.cfg
-    unroll = bool(cfg.quantize_weights) and not cfg.dequantize_for_compute
+    codes = serves_codes(cfg)
     method = cfg.quantize_weights or "squant"
 
     def draft_fn(fp_tree):
-        if unroll and isinstance(fp_tree, dict) \
-                and "periods" in fp_tree.get("stack", {}):
-            fp_tree = dict(fp_tree)
-            fp_tree["stack"] = unstack_stack(fp_tree["stack"],
-                                             n_periods(base_cfg))
+        if codes:
+            fp_tree = unstacked(fp_tree, model.cfg)
         tree, _ = quantize_tree(fp_tree, method=method, bits=cfg.draft_bits,
                                 dequantize=cfg.dequantize_for_compute)
         return tree
@@ -134,7 +165,7 @@ class WeightStore:
 
     Exactly one of ``fp_params`` / ``serving_params`` seeds version 1:
     ``fp_params`` goes through ``quantize_fn``; ``serving_params`` is an
-    already-serving-format tree (through ``prepare_fn``).
+    already-quantized tree (through ``prepare_fn``).
     """
 
     def __init__(self, quantize_fn: Optional[Callable] = None,

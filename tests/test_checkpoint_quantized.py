@@ -1,9 +1,10 @@
-"""Quantized checkpoint tests: native save/restore of serving-format
-``w_q``/``w_q4``/``w_scale`` trees (int4 kept packed on disk), quant
-metadata validation, torn-save semantics, and reshard-on-restore
-bit-exactness across 1/2/8-device meshes (quantize → save → restore on a
-different mesh size → serve must produce codes, scales, and generated
-tokens identical to the never-checkpointed in-memory path).
+"""Quantized checkpoint tests: native save/restore of ``QuantizedTensor``
+serving trees (int4 kept packed on disk), quant metadata validation,
+torn-save semantics, reshard-on-restore bit-exactness across 1/2/8-device
+meshes (quantize → save → restore on a different mesh size → serve must
+produce codes, scales, and generated tokens identical to the
+never-checkpointed in-memory path), and reloads that serve what
+quantize-on-load serves.
 """
 import dataclasses
 import json
@@ -14,11 +15,13 @@ import jax
 import numpy as np
 import pytest
 
-from repro.checkpoint.checkpointer import Checkpointer, CheckpointMetaError
+from repro.checkpoint.checkpointer import (Checkpointer, CheckpointMetaError,
+                                          quant_tree_meta)
 from repro.configs import get_config
 from repro.models.model import build_model
-from repro.quant.apply import (is_quantized_tree, quant_tree_meta,
-                               quantize_params_serving)
+from repro.quant.qtypes import is_quantized_tree
+from repro.serving.engine import ServeConfig
+from repro.serving.weights import make_weight_pipeline
 
 
 def _tiny(seed=0):
@@ -30,7 +33,17 @@ def _tiny(seed=0):
     return model, model.init(jax.random.PRNGKey(seed)), cfg
 
 
+def _quantized(model, params, bits):
+    """``(tree, quant_meta)``: what a real-code w``bits`` engine serves."""
+    _, quantize_fn, _ = make_weight_pipeline(model, ServeConfig(
+        quantize_weights="squant", weight_bits=bits,
+        dequantize_for_compute=False))
+    tree, report = quantize_fn(params)
+    return tree, quant_tree_meta(bits, "squant", 128, report=report)
+
+
 def _assert_trees_equal(a, b):
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
     fa = jax.tree_util.tree_flatten_with_path(a)[0]
     fb = jax.tree_util.tree_flatten_with_path(b)[0]
     assert len(fa) == len(fb)
@@ -44,7 +57,7 @@ def _assert_trees_equal(a, b):
 @pytest.mark.parametrize("bits", [8, 4])
 def test_quantized_roundtrip_bit_exact(tmp_path, bits):
     model, params, _ = _tiny()
-    qtree, meta = quantize_params_serving(params, bits, "squant")
+    qtree, meta = _quantized(model, params, bits)
     assert is_quantized_tree(qtree) and not is_quantized_tree(params)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(3, qtree, quant_meta=meta)
@@ -58,22 +71,24 @@ def test_quantized_roundtrip_bit_exact(tmp_path, bits):
 
 def test_int4_nibbles_stay_packed_on_disk(tmp_path):
     model, params, _ = _tiny()
-    qtree, meta = quantize_params_serving(params, 4, "squant")
+    qtree, meta = _quantized(model, params, 4)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(1, qtree, quant_meta=meta)
     data = np.load(str(tmp_path / "step_00000001" / "shard_00000.npz"))
-    q4_keys = [k for k in data.files if k.endswith("w_q4")]
-    assert q4_keys, "no packed int4 payload on disk"
-    for k in q4_keys:
-        assert data[k].dtype == np.int8            # two nibbles per byte
-        scale = data[k.replace("w_q4", "w_scale")]
+    qtensors = ck.read_meta(1)["qtensors"]
+    assert qtensors, "no packed int4 payload on disk"
+    for k, q in qtensors.items():
+        codes = data[f"params::{k}/data"]
+        scale = data[f"params::{k}/scale"]
+        assert q["bits"] == 4 and codes.dtype == np.int8  # two nibbles/byte
         # packed column count is half the logical in-dim
-        assert data[k].shape[-2] == scale.shape[-2]
+        assert codes.shape == (q["shape"][0], q["shape"][1] // 2)
+        assert codes.shape[-2] == scale.shape[-2]
 
 
 def test_restore_rejects_bits_and_method_mismatch(tmp_path):
     model, params, _ = _tiny()
-    qtree, meta = quantize_params_serving(params, 4, "squant")
+    qtree, meta = _quantized(model, params, 4)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(1, qtree, quant_meta=meta)
     with pytest.raises(CheckpointMetaError, match="mismatch"):
@@ -93,7 +108,7 @@ def test_restore_rejects_bits_and_method_mismatch(tmp_path):
 
 def test_training_restore_rejects_quantized_checkpoint(tmp_path):
     model, params, _ = _tiny()
-    qtree, meta = quantize_params_serving(params, 8, "squant")
+    qtree, meta = _quantized(model, params, 8)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(1, qtree, quant_meta=meta)
     with pytest.raises(CheckpointMetaError, match="restore_serving"):
@@ -102,7 +117,7 @@ def test_training_restore_rejects_quantized_checkpoint(tmp_path):
 
 def test_save_serving_requires_bits_and_method(tmp_path):
     model, params, _ = _tiny()
-    qtree, _ = quantize_params_serving(params, 8, "squant")
+    qtree, _ = _quantized(model, params, 8)
     ck = Checkpointer(str(tmp_path), async_save=False)
     with pytest.raises(ValueError, match="quant_meta missing"):
         ck.save_serving(1, qtree, quant_meta={"bits": 8})
@@ -118,7 +133,7 @@ def test_restore_skips_torn_and_corrupt_steps(tmp_path, kind):
         if kind == "fp":
             ck.save_serving(step, tree)
         else:
-            q, m = quantize_params_serving(tree, 8, "squant")
+            q, m = _quantized(model, tree, 8)
             ck.save_serving(step, q, quant_meta=m)
 
     save(1, params)
@@ -169,11 +184,10 @@ _RESHARD_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     from repro.configs import get_config
     from repro.models.model import build_model
-    from repro.quant.apply import quantize_params_serving
-    from repro.checkpoint.checkpointer import Checkpointer
+    from repro.checkpoint.checkpointer import Checkpointer, quant_tree_meta
     from repro.launch.mesh import make_quantize_mesh
     from repro.serving.engine import Request, ServeConfig, ServeEngine
-    from repro.serving.weights import WeightStore
+    from repro.serving.weights import WeightStore, make_weight_pipeline
 
     assert len(jax.devices()) == {devices}
     cfg = dataclasses.replace(get_config("granite-3-8b", reduced=True),
@@ -182,7 +196,10 @@ _RESHARD_SCRIPT = textwrap.dedent("""
                               d_ff=64, vocab=64)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    qtree, meta = quantize_params_serving(params, {bits}, "squant")
+    scfg = ServeConfig(max_batch=2, max_len=32, quantize_weights="squant",
+                       weight_bits={bits}, dequantize_for_compute=False)
+    qtree, report = make_weight_pipeline(model, scfg)[1](params)
+    meta = quant_tree_meta({bits}, "squant", 128, report=report)
     with tempfile.TemporaryDirectory() as tmp:
         ck = Checkpointer(tmp, async_save=False)
         ck.save_serving(1, qtree, quant_meta=meta)
@@ -192,13 +209,15 @@ _RESHARD_SCRIPT = textwrap.dedent("""
     fa = jax.tree_util.tree_flatten_with_path(qtree)[0]
     fb = jax.tree_util.tree_flatten_with_path(restored)[0]
     assert len(fa) == len(fb)
+    assert (jax.tree_util.tree_structure(qtree)
+            == jax.tree_util.tree_structure(restored))
     for (k1, a), (k2, b) in zip(fa, fb):
         assert jax.tree_util.keystr(k1) == jax.tree_util.keystr(k2)
         assert np.array_equal(np.asarray(a), np.asarray(b)), \\
             jax.tree_util.keystr(k1)
 
     def toks(tree):
-        eng = ServeEngine(model, cfg=ServeConfig(max_batch=2, max_len=32),
+        eng = ServeEngine(model, cfg=scfg,
                           store=WeightStore(serving_params=tree))
         return [c.tokens for c in eng.generate(
             [Request(prompt=[1, 2, 3], max_new_tokens=6, request_id=i)
@@ -231,7 +250,7 @@ def test_reshard_on_restore_inprocess(tmp_path):
     from repro.serving.weights import WeightStore
 
     model, params, _ = _tiny()
-    qtree, meta = quantize_params_serving(params, 4, "squant")
+    qtree, meta = _quantized(model, params, 4)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(1, qtree, quant_meta=meta)
     restored, _, _ = ck.restore_serving(
@@ -240,10 +259,57 @@ def test_reshard_on_restore_inprocess(tmp_path):
     _assert_trees_equal(qtree, restored)
 
     def toks(tree):
-        eng = ServeEngine(model, cfg=ServeConfig(max_batch=2, max_len=32),
-                          store=WeightStore(serving_params=tree))
+        eng = ServeEngine(model, cfg=ServeConfig(
+            max_batch=2, max_len=32, quantize_weights="squant",
+            weight_bits=4, dequantize_for_compute=False),
+            store=WeightStore(serving_params=tree))
         return [c.tokens for c in eng.generate(
             [Request(prompt=[1, 2, 3], max_new_tokens=6, request_id=i)
              for i in range(2)])]
 
     assert toks(qtree) == toks(restored)
+
+
+# ---------------------------------------------------------------------------
+# A quantized checkpoint serves what quantize-on-load serves
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,bits,fake_quant", [
+    ("granite-3-8b", 8, False), ("granite-3-8b", 4, False),
+    ("mixtral-8x7b", 8, False), ("mixtral-8x7b", 4, False),
+    ("moonshot-v1-16b-a3b", 8, False), ("moonshot-v1-16b-a3b", 4, False),
+    ("granite-3-8b", 8, True), ("granite-3-8b", 4, True)])
+def test_reloaded_checkpoint_serves_like_quantize_on_load(
+        tmp_path, monkeypatch, arch, bits, fake_quant):
+    """``launch.quantize --serving-ckpt`` writes a quantized checkpoint
+    from the seed-0 fp tree; an engine started on other weights stages it
+    through ``WeightStore.poll_checkpoints`` and serves the same tokens as
+    an engine that quantizes the seed-0 tree on load."""
+    import sys
+    from repro.launch import quantize, serve
+    from repro.serving.engine import Request, ServeEngine
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setattr(sys, "argv", [
+        "quantize", "--arch", arch, "--reduced", "--bits", str(bits),
+        "--out", str(tmp_path / "fq"), "--serving-ckpt", str(tmp_path / "sq"),
+        "--serving-step", "7"])
+    quantize.main()
+    cfg = get_config(arch, reduced=True)
+    model = build_model(dataclasses.replace(cfg, dtype="float32"))
+    scfg = ServeConfig(max_batch=2, max_len=32, quantize_weights="squant",
+                       weight_bits=bits, dequantize_for_compute=fake_quant)
+    reqs = [Request(prompt=[(7 * i + 3 * j) % cfg.vocab for j in range(5)],
+                    max_new_tokens=6, request_id=i) for i in range(2)]
+
+    on_load = ServeEngine(model, serve.init_params(model, seed=0), scfg)
+    want = [c.tokens for c in on_load.generate(reqs)]
+    on_load.close()
+    eng = ServeEngine(model, serve.init_params(model, seed=1), scfg)
+    ck = Checkpointer(str(tmp_path / "sq"), async_save=False)
+    assert eng.store.poll_checkpoints(
+        ck, expect={"quantize_weights": "squant", "weight_bits": bits}) == 7
+    got = eng.generate(reqs)
+    eng.close()
+    assert all(c.weights_version == 2 for c in got)
+    assert [c.tokens for c in got] == want

@@ -143,12 +143,14 @@ def test_serve_cli_fails_on_reload_error(tmp_path, monkeypatch, capsys):
     """A reload the weight store rejects (here a w4 checkpoint under a w8
     serve) makes the serve CLI exit non-zero, after printing the error."""
     import sys
-    from repro.checkpoint.checkpointer import Checkpointer
+    from repro.checkpoint.checkpointer import Checkpointer, quant_tree_meta
     from repro.launch import serve
-    from repro.quant.apply import quantize_params_serving
+    from repro.serving.weights import make_weight_pipeline
     model = serve.build_arch_model("granite-3-8b", reduced=True)
-    qtree, meta = quantize_params_serving(serve.init_params(model), 4,
-                                          "squant")
+    qtree, report = make_weight_pipeline(model, ServeConfig(
+        quantize_weights="squant", weight_bits=4,
+        dequantize_for_compute=False))[1](serve.init_params(model))
+    meta = quant_tree_meta(4, "squant", 128, report=report)
     Checkpointer(str(tmp_path), async_save=False).save_serving(
         1, qtree, quant_meta=meta)
     # keeps main() from pointing this process's compile cache at the repo

@@ -16,12 +16,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.checkpoint.checkpointer import Checkpointer
+from repro.checkpoint.checkpointer import Checkpointer, quant_tree_meta
 from repro.configs import get_config
 from repro.models.model import build_model
-from repro.quant.apply import quantize_params_serving
 from repro.serving.engine import Request, ServeConfig, ServeEngine
-from repro.serving.weights import WeightStore
+from repro.serving.weights import WeightStore, make_weight_pipeline
 
 
 def _tiny(seed=0):
@@ -31,6 +30,17 @@ def _tiny(seed=0):
                               vocab=64)
     model = build_model(cfg)
     return model, model.init(jax.random.PRNGKey(seed)), cfg
+
+
+def _codes_cfg(bits):
+    return ServeConfig(max_batch=2, max_len=32, quantize_weights="squant",
+                       weight_bits=bits, dequantize_for_compute=False)
+
+
+def _quantized(model, params, bits):
+    """``(tree, quant_meta)``: what a real-code w``bits`` engine serves."""
+    tree, report = make_weight_pipeline(model, _codes_cfg(bits))[1](params)
+    return tree, quant_tree_meta(bits, "squant", 128, report=report)
 
 
 def _reqs(n, max_new=4):
@@ -141,7 +151,7 @@ def test_watcher_quantizes_fp_checkpoints_on_the_fly(tmp_path):
 def test_watcher_loads_quantized_checkpoints_natively(tmp_path):
     model, params, _ = _tiny(0)
     _, params2, _ = _tiny(1)
-    qtree, meta = quantize_params_serving(params2, 8, "squant")
+    qtree, meta = _quantized(model, params2, 8)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(5, qtree, quant_meta=meta)
     eng = ServeEngine(model, params,
@@ -172,7 +182,7 @@ def test_watcher_skips_torn_and_corrupt_steps(tmp_path, kind):
         if kind == "fp":
             ck.save_serving(step, tree)
         else:
-            q, m = quantize_params_serving(tree, 8, "squant")
+            q, m = _quantized(model, tree, 8)
             ck.save_serving(step, q, quant_meta=m)
 
     save(1, params)
@@ -196,7 +206,7 @@ def test_watcher_skips_torn_and_corrupt_steps(tmp_path, kind):
 
 def test_watcher_rejects_meta_mismatch(tmp_path):
     model, params, _ = _tiny(0)
-    qtree, meta = quantize_params_serving(params, 4, "squant")
+    qtree, meta = _quantized(model, params, 4)
     ck = Checkpointer(str(tmp_path), async_save=False)
     ck.save_serving(1, qtree, quant_meta=meta)
     eng = ServeEngine(model, params,
@@ -282,10 +292,9 @@ def test_engine_from_prebuilt_qdict_store():
     """An externally staged serving tree (native quantized format) drives
     the engine without any fp params or quantize call."""
     model, params, _ = _tiny(0)
-    qtree, _ = quantize_params_serving(params, 8, "squant")
+    qtree, _ = _quantized(model, params, 8)
     store = WeightStore(serving_params=qtree, source="prequantized")
-    eng = ServeEngine(model, cfg=ServeConfig(max_batch=2, max_len=32),
-                      store=store)
+    eng = ServeEngine(model, cfg=_codes_cfg(8), store=store)
     out = eng.generate(_reqs(2))
     assert all(len(o.tokens) == 4 for o in out)
     assert eng.store.current.source == "prequantized"
